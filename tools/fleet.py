@@ -277,11 +277,10 @@ def main():
 
     result = {"lanes": args.lanes, "error": None}
     try:
-        from ibamr_tpu.utils.backend_guard import init_backend_with_retry
+        from ibamr_tpu.utils.backend_guard import auto_backend
 
-        jax, platform, backend_err = init_backend_with_retry(
-            retries=1, delay=2.0)
-        result["platform"] = platform
+        jax = auto_backend()
+        result["platform"] = jax.devices()[0].platform
         if args.x64:
             jax.config.update("jax_enable_x64", True)
         from ibamr_tpu.utils.hierarchy_driver import RunConfig

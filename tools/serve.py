@@ -46,12 +46,8 @@ def run_drill(args, force_cpu_backend: bool) -> dict:
         force_cpu()
         platform = "cpu"
     else:
-        from ibamr_tpu.utils.backend_guard import init_backend_with_retry
-        _jax, platform, err = init_backend_with_retry(retries=1,
-                                                      delay=2.0)
-        if err:
-            print(f"[serve] backend init degraded: {err}",
-                  file=sys.stderr)
+        from ibamr_tpu.utils.backend_guard import auto_backend
+        platform = auto_backend().devices()[0].platform
     from ibamr_tpu.serve import aot_cache
     aot_cache.enable_persistent_cache()
     from ibamr_tpu.serve.router import cold_warm_drill

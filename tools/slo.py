@@ -469,12 +469,8 @@ def run_drill_ledger(args, ledger_path: str) -> dict:
     """Run ``cold_warm_drill`` with a fresh attached ledger and flush
     the metric registry into it; returns the drill output."""
     if args.backend == "device":
-        from ibamr_tpu.utils.backend_guard import init_backend_with_retry
-        _jax, _platform, err = init_backend_with_retry(retries=1,
-                                                       delay=2.0)
-        if err:
-            print(f"[slo] backend init degraded: {err}",
-                  file=sys.stderr)
+        from ibamr_tpu.utils.backend_guard import auto_backend
+        auto_backend()
     else:
         from ibamr_tpu.utils.backend_guard import force_cpu
         force_cpu()
@@ -498,12 +494,8 @@ def run_soak_ledger(args, ledger_path: str) -> dict:
     and flush the metric registry into it; returns the traffic
     summary."""
     if args.backend == "device":
-        from ibamr_tpu.utils.backend_guard import init_backend_with_retry
-        _jax, _platform, err = init_backend_with_retry(retries=1,
-                                                       delay=2.0)
-        if err:
-            print(f"[slo] backend init degraded: {err}",
-                  file=sys.stderr)
+        from ibamr_tpu.utils.backend_guard import auto_backend
+        auto_backend()
     else:
         from ibamr_tpu.utils.backend_guard import force_cpu
         force_cpu()
@@ -528,12 +520,8 @@ def run_elastic_drill(args, directory: str) -> dict:
     (``<directory>/elastic_ledger.jsonl``) and raises on any broken
     invariant before the SLO layer even evaluates."""
     if args.backend == "device":
-        from ibamr_tpu.utils.backend_guard import init_backend_with_retry
-        _jax, _platform, err = init_backend_with_retry(retries=1,
-                                                       delay=2.0)
-        if err:
-            print(f"[slo] backend init degraded: {err}",
-                  file=sys.stderr)
+        from ibamr_tpu.utils.backend_guard import auto_backend
+        auto_backend()
     else:
         from ibamr_tpu.utils.backend_guard import force_cpu
         force_cpu()
@@ -553,12 +541,8 @@ def run_assim_drill(args, directory: str) -> dict:
     unquarantined member, lost cycle, retrace) before the SLO layer
     even evaluates."""
     if args.backend == "device":
-        from ibamr_tpu.utils.backend_guard import init_backend_with_retry
-        _jax, _platform, err = init_backend_with_retry(retries=1,
-                                                       delay=2.0)
-        if err:
-            print(f"[slo] backend init degraded: {err}",
-                  file=sys.stderr)
+        from ibamr_tpu.utils.backend_guard import auto_backend
+        auto_backend()
     else:
         from ibamr_tpu.utils.backend_guard import force_cpu
         force_cpu()

@@ -63,11 +63,8 @@ def _backend(force_cpu_backend: bool) -> str:
         from ibamr_tpu.utils.backend_guard import force_cpu
         force_cpu()
         return "cpu"
-    from ibamr_tpu.utils.backend_guard import init_backend_with_retry
-    _jax, platform, err = init_backend_with_retry(retries=1, delay=2.0)
-    if err:
-        print(f"[tune] backend init degraded: {err}", file=sys.stderr)
-    return platform
+    from ibamr_tpu.utils.backend_guard import auto_backend
+    return auto_backend().devices()[0].platform
 
 
 def _device_kind() -> str:
