@@ -656,14 +656,14 @@ def measure_artifact(name: str) -> dict:
 
     Returns the flat budget-comparable metric dict. Caller chooses the
     backend; the CI gate runs this in a ``JAX_PLATFORMS=cpu`` child."""
-    from jax.experimental import disable_x64
+    import jax
 
     from ibamr_tpu import obs
 
     art = ARTIFACTS[name]
     prev = obs.current()
     try:
-        with disable_x64():
+        with jax.enable_x64(False):
             fn, args, donate = art.build()
             census = graph_census(fn, args, donate_argnums=donate)
     finally:

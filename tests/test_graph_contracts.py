@@ -134,7 +134,7 @@ def test_first_wave_f64_fixes_stay_warning_free():
     x64-off config (the warning means silent truncation)."""
     from ibamr_tpu.solvers.spectral_plan import gaussian_filter_symbol
 
-    with jax.experimental.disable_x64():
+    with jax.enable_x64(False):
         with warnings.catch_warnings(record=True) as rec:
             warnings.simplefilter("always")
             gaussian_filter_symbol((16, 16), (1.0 / 16, 1.0 / 16),

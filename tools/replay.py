@@ -344,8 +344,7 @@ def _x64_scope(manifest):
     rec = manifest["fingerprint"].get("x64")
     if rec is None or bool(rec) == bool(jax.config.jax_enable_x64):
         return contextlib.nullcontext()
-    from jax.experimental import disable_x64, enable_x64
-    return enable_x64() if rec else disable_x64()
+    return jax.enable_x64(bool(rec))
 
 
 def _run_once(manifest, arrays, overrides, dt_scale, sharded=False):
