@@ -275,7 +275,7 @@ def build_shell_example(
         use_fast_interaction: Optional[bool] = None,
         dtype=None,
         input_db=None,
-        engine_fallback: bool = True,
+        engine_fallback: bool = False,
         spectral_dtype=None) -> Tuple[IBExplicitIntegrator,
                                       IBState]:
     """Assemble the ex4-equivalent simulation (3D periodic unit box).
@@ -297,10 +297,10 @@ def build_shell_example(
     scatter otherwise). The resolved name lands on ``ib.engine_name``
     for fingerprinting/cache keying.
 
-    ``engine_fallback`` (default True; knob ``IBMethod {
-    engine_fallback = FALSE }``): when the chosen engine fails to
-    build or compile, degrade down the registry fallback chain
-    (docs/RESILIENCE.md) with a warning instead of raising.
+    ``engine_fallback`` (default False; knob ``IBMethod {
+    engine_fallback = TRUE }``): a chosen engine that fails to build
+    or compile stops the run. Opted in, it degrades down the registry
+    fallback chain (docs/RESILIENCE.md) with a warning instead.
     """
     import jax.numpy as jnp
 
@@ -345,8 +345,8 @@ def build_shell_example(
             use_fast_interaction = {
                 "auto": None, "scatter": False, "mxu": True,
             }.get(eng, eng)
-        # IBMethod { engine_fallback = FALSE } pins the named engine:
-        # a build/compile failure raises instead of degrading
+        # IBMethod { engine_fallback = TRUE } opts into degrading down
+        # the fallback chain; by default a build/compile failure raises
         engine_fallback = ib_db.get_bool("engine_fallback",
                                          engine_fallback)
         sh = input_db.get_database_with_default("Shell")

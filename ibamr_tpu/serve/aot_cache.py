@@ -122,25 +122,23 @@ def step_fingerprint(integ, *, spec: Optional[dict] = None,
 
 def enable_persistent_cache(jax=None, directory: Optional[str] = None,
                             min_compile_secs: float = 2.0):
-    """Wire JAX's persistent compilation cache — the cross-process
-    layer: a scenario family's XLA backend compile happens once per
-    cluster ever. Directory: ``directory`` arg, else
-    ``$IBAMR_COMPILE_CACHE``, else ``<repo>/.jax_cache``. Returns the
-    cache dir, or None when unavailable (never fatal: serving without
-    the disk layer is slow, not wrong)."""
-    try:
-        if jax is None:
-            import jax
-        d = directory or os.environ.get(
-            "IBAMR_COMPILE_CACHE",
-            os.path.join(REPO_ROOT, ".jax_cache"))
+    """Wire JAX's persistent compilation cache — the ONE place this
+    repo places it. Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX
+    reads the variable itself and no directory is set in code (it wins
+    over ``directory``); otherwise ``directory`` (tests pass a
+    ``tmp_path``), else ``<checkout>/.jax_cache``. Returns the cache
+    dir. Failing to enable it raises: a chip run that silently pays
+    every compile again is an error, not a slow success."""
+    if jax is None:
+        import jax
+    d = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not d:
+        d = directory or os.path.join(REPO_ROOT, ".jax_cache")
         os.makedirs(d, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", d)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          float(min_compile_secs))
-        return d
-    except Exception:
-        return None
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      float(min_compile_secs))
+    return d
 
 
 def estimate_executable_bytes(executable) -> int:

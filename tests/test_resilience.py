@@ -343,7 +343,7 @@ def test_failed_engine_degrades_and_matches_fallback(monkeypatch):
     with pytest.warns(RuntimeWarning, match="degrading to 'packed_bf16'"):
         integ, state = build_shell_example(
             n_cells=16, n_lat=8, n_lon=8,
-            use_fast_interaction="hybrid_bf16")
+            use_fast_interaction="hybrid_bf16", engine_fallback=True)
     assert type(integ.ib.fast).__name__ == "PackedInteraction"
     assert integ.ib.fast.compute_dtype == jnp.bfloat16
 

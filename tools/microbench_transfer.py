@@ -52,11 +52,8 @@ def main():
     import jax
     import jax.numpy as jnp
 
-    d = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), ".jax_cache")
-    os.makedirs(d, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", d)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
+    from ibamr_tpu.serve.aot_cache import enable_persistent_cache
+    enable_persistent_cache(jax)
 
     from ibamr_tpu.grid import StaggeredGrid
     from ibamr_tpu.models.shell3d import make_spherical_shell
