@@ -285,7 +285,7 @@ def four_chips(args, jax, out):
     for path, leaf in jax.tree_util.tree_leaves_with_path(state4):
         devs = sorted(s.device.id for s in leaf.addressable_shards)
         per_dev = leaf.addressable_shards[0].data.nbytes
-        phase("leaf", name=jax.tree_util.keystr(path),
+        phase("leaf", leaf=jax.tree_util.keystr(path),
               shape=list(leaf.shape), sharding=str(leaf.sharding),
               devices=devs, bytes_per_device=per_dev)
         if tuple(leaf.shape) == gshape:
@@ -348,6 +348,7 @@ def main():
     shutil.rmtree(out, ignore_errors=True)
     os.makedirs(out)
     os.makedirs(os.path.dirname(PHASE_LOG), exist_ok=True)
+    open(PHASE_LOG, "w").close()
     phase("start", platform=dev.platform, device_kind=dev.device_kind,
           devices=len(jax.devices()), jax=jax.__version__,
           rehearse=args.rehearse, out=out)

@@ -481,7 +481,7 @@ def compact_summary(summary: dict) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# pruning (relay_watch archive step)
+# pruning (tools/prof.py archive step)
 # ---------------------------------------------------------------------------
 
 _RAW_SUFFIXES = (".trace.json.gz", ".trace.json", ".xplane.pb",

@@ -1,7 +1,7 @@
 """Spectral-plan layer: hash-cons-cached symbol tables + the k-space-
 resident fused fluid substep.
 
-Round-5 measurement (PERF.md, BENCH_TPU_NUMBERS.json rev 96498b2) put
+Round-5 measurement (PERF.md; rev 96498b2, capture file removed) put
 ``fluid_solve`` at 39.3 ms — the dominant flagship phase once the
 transfer-side levers landed. The remaining structural waste was not in
 the transforms themselves (the fused substep already runs ONE batched

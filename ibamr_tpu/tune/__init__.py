@@ -18,9 +18,9 @@ FFTW/ATLAS tradition:
   (:mod:`ibamr_tpu.models.engine_resolver`) consults: schema v1
   validation, shadowed-entry lint, atomic publication.
 
-``tools/tune.py`` is the CLI (search/show/publish/check);
-``tools/relay_watch.py`` runs ``search --publish`` on every healthy
-TPU window so the committed defaults stay device-measured.
+``tools/tune.py`` is the CLI (search/show/publish/check); run
+``search --publish`` on the chip so the committed defaults stay
+device-measured.
 """
 
 from ibamr_tpu.tune.db import (load_db, make_entry, make_provenance,

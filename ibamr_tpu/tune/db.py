@@ -53,7 +53,7 @@ _DOC = ("Measured-search tuning DB (ibamr_tpu/tune/, docs/TUNING.md): "
         "whose provenance.platform differs from the running backend "
         "are skipped). Validated by tools/tune.py check and the tier-1 "
         "gate in tests/test_tune.py; re-measured/re-published by "
-        "tools/relay_watch.py on every healthy TPU window.")
+        "tools/tune.py search --publish on the chip.")
 
 
 def new_db() -> dict:

@@ -1,0 +1,133 @@
+"""The main path's kernels compile for the chip (no chip attached).
+
+The TPU compiler is installed here and compiles for a DESCRIBED v5e
+(on-chip-measurement guide section 2): Mosaic refusals (tiling,
+VMEM), programs that do not fit HBM and unpartitionable kernels show
+up here at no chip time. A compile that passes is NOT a chip run.
+
+Rules this file keeps (one libtpu load per process, xdist-safe): the
+topology is described inside a module-scoped fixture, never at import
+/ collection; compiles run in the test's own process with the
+persistent compile cache off; everything TPU lives in THIS one file.
+
+Sizes: the transfer kernels compile at the flagship's real width
+(256^3, 316x316 = 99,856 markers) with the bucket pytree handed in as
+shapes, so only the kernel and its overlap-add compile. The whole
+``integ.step`` compiles at 64^3 (the 256^3 whole-step compile takes
+minutes and is made by hand; CHANGES.md PR 23 records it).
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from ibamr_tpu.grid import StaggeredGrid
+from ibamr_tpu.models.shell3d import (build_shell_example,
+                                      construct_transfer_engine,
+                                      make_spherical_shell)
+
+HBM_BYTES = 16e9          # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_persistent_cache():
+    # a described-chip executable is written to the persistent cache
+    # but cannot be read back without a chip; keep these compiles out
+    from jax.experimental.compilation_cache import compilation_cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+def _on(sharding, tree):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=sharding), tree)
+
+
+def _compiled_mode(fast):
+    """Steer a CPU-built engine out of Pallas interpret mode (the
+    classes choose it from ``jax.default_backend()``, which is the CPU
+    here): flip every ``interpret`` flag the engine carries."""
+    flipped = 0
+    for obj in (fast, getattr(fast, "_pal", None),
+                getattr(fast, "_spread", None)):
+        if obj is not None and hasattr(obj, "interpret"):
+            obj.interpret = False
+            flipped += 1
+    assert flipped, f"{type(fast).__name__} carries no interpret flag"
+    return fast
+
+
+_ENGINES = {}
+
+
+def _engine(name):
+    """Registry-built engine at the flagship geometry (the sizing the
+    run uses: suggest_chunks / suggest_cap from the real lattice)."""
+    if name not in _ENGINES:
+        grid = StaggeredGrid(n=(256,) * 3, x_lo=(0.0,) * 3,
+                             x_up=(1.0,) * 3)
+        verts = make_spherical_shell(316, 316, 0.25, (0.5, 0.5, 0.5),
+                                     1.0, aspect=1.2).vertices
+        assert verts.shape[0] == 99856
+        fast = _compiled_mode(
+            construct_transfer_engine(name, grid, verts, "IB_4"))
+        X = jax.ShapeDtypeStruct(verts.shape, jnp.float32)
+        _ENGINES[name] = (fast, X, jax.eval_shape(fast.buckets, X))
+    return _ENGINES[name]
+
+
+@pytest.mark.parametrize("name,op", [
+    ("pallas_packed", "spread"),
+    ("pallas_packed", "interp"),
+    ("hybrid_bf16", "spread"),
+    ("pallas", "spread"),
+    ("pallas", "interp"),
+])
+def test_transfer_kernel_compiles_at_256(one_chip, name, op):
+    fast, X, b = _engine(name)
+    assert X.shape[0] == 99856 and fast.grid.n[-1] == 256
+    if op == "spread":
+        def fn(F, X, b):
+            return fast.spread_vel(F, X, b=b)
+        lead = X                                   # forces: (N, 3)
+    else:
+        def fn(u, X, b):
+            return fast.interpolate_vel(u, X, b=b)
+        lead = tuple(jax.ShapeDtypeStruct(fast.grid.n, jnp.float32)
+                     for _ in range(3))
+    compiled = jax.jit(fn).lower(
+        *_on(one_chip, (lead, X, b))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_whole_step_packed_bf16_fits_one_chip(one_chip):
+    # the engine TUNING_DB.json names for the flagship on platform tpu
+    integ, state = build_shell_example(
+        n_cells=64, n_lat=79, n_lon=79,
+        use_fast_interaction="packed_bf16")
+    compiled = jax.jit(integ.step).lower(
+        _on(one_chip, state), 5e-5).compile()
+    ma = compiled.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             + ma.temp_size_in_bytes + ma.generated_code_size_in_bytes)
+    assert 0 < total < HBM_BYTES, ma

@@ -30,8 +30,8 @@ device:
   directory whose every step is damaged refuses to quarantine the
   last restorable candidate — fsck must never shorten a recovery
   chain the run-time fallback could still limp along;
-- exits ``0`` on a clean tree, ``1`` on corruption (so CI and
-  ``relay_watch`` can gate on it), ``2`` on usage errors.
+- exits ``0`` on a clean tree, ``1`` on corruption (so CI can
+  gate on it), ``2`` on usage errors.
 
 Usage::
 

@@ -28,8 +28,7 @@ metric the ratchet is DIRECTIONAL — ceilings only tighten down, floors
 like ``hidden_fraction``/``donated_args`` only tighten up; loosening a
 budget after an intentional structural change requires
 ``--tighten --clobber``), ``--json`` emits
-the machine-readable report (consumed by ``tools/relay_watch.py``'s
-on-healthy capture), ``--in-process`` skips the child processes (used
+the machine-readable report, ``--in-process`` skips the child processes (used
 by the test suite, which already isolates per-module).
 """
 

@@ -1,10 +1,10 @@
-"""Relay-independent HLO traffic/FLOP audit of the flagship step
+"""Chip-independent HLO traffic/FLOP audit of the flagship step
 (VERDICT round 4, "Next round" item 1b).
 
 ``jit(...).lower().compile()`` on the host-CPU backend builds the same
 HLO module structure the TPU backend compiles, and XLA's
 ``cost_analysis()`` / ``memory_analysis()`` report the module's
-bytes-accessed and FLOP totals — numbers that do NOT need the relay.
+bytes-accessed and FLOP totals — numbers that do NOT need the chip.
 This turns the transfer-engine claims ("occupancy packing lifts slot
 utilization so every weight operand shrinks by the same factor; bf16
 compression halves what remains") into measured per-engine byte

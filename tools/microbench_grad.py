@@ -23,8 +23,8 @@ import os
 import sys
 import time
 
-# importable regardless of caller cwd (the relay watcher invokes this
-# as a script; python puts tools/ on sys.path, not the repo root)
+# importable regardless of caller cwd (run as a script,
+# python puts tools/ on sys.path, not the repo root)
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
@@ -195,7 +195,7 @@ def main():
     ap.add_argument("--dt", type=float, default=5e-5)
     ap.add_argument("--json", action="store_true",
                     help="emit a machine-readable JSON line after the "
-                         "table (the relay watcher's capture format)")
+                         "table")
     args = ap.parse_args()
     out = run(n=args.n, reps=args.reps, dt=args.dt)
     if args.json:

@@ -19,8 +19,8 @@ import time
 
 import numpy as np
 
-# importable regardless of caller cwd (the relay watcher invokes this
-# as a script; python puts tools/ on sys.path, not the repo root)
+# importable regardless of caller cwd (run as a script,
+# python puts tools/ on sys.path, not the repo root)
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 

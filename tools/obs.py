@@ -1126,8 +1126,8 @@ def _is_ledger(path: str) -> bool:
 
 
 def _bench_payload(path: str) -> dict:
-    """Accept a raw ``bench.py`` JSON or a ``BENCH_r*.json`` wrapper
-    (the relay driver stores the parsed result under ``parsed``)."""
+    """Accept a raw ``bench.py`` JSON or a wrapper that stores the
+    parsed result under ``parsed``."""
     with open(path) as f:
         data = json.load(f)
     if isinstance(data, dict) and isinstance(data.get("parsed"), dict):

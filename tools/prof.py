@@ -9,7 +9,7 @@ The operator's side of ``ibamr_tpu/obs/deviceprof.py``:
 - ``show``: render a summary (span table, residual, roofline) without
   re-parsing the multi-MB trace.
 - ``check``: validate a ``prof_summary.json`` against the schema —
-  exit 2 on malformation, so automation (``relay_watch``) archives
+  exit 2 on malformation, so automation archives
   garbage loudly instead of silently.
 - ``diff``: compare two attributed summaries — capture dirs, summary
   files, or the summaries EMBEDDED in two bench JSONs — per span path
@@ -18,7 +18,7 @@ The operator's side of ``ibamr_tpu/obs/deviceprof.py``:
   ``--comm-tol-pct`` arms a dedicated, tighter gate on the ``comm_s``
   op-class alone (PR 16) — the fleet-mesh legs' health line — which
   is advisory (printed, never enforced) on CPU captures.
-- ``archive``: the relay_watch step — attribute if needed, validate,
+- ``archive``: attribute if needed, validate,
   and only then prune the raw multi-MB profiler outputs, keeping the
   compact summary; a malformed summary exits 2 and prunes nothing.
 
@@ -375,7 +375,7 @@ def cmd_diff(args) -> int:
 
 
 # ---------------------------------------------------------------------------
-# archive (relay_watch's fifth capture step)
+# archive
 # ---------------------------------------------------------------------------
 
 def cmd_archive(args) -> int:

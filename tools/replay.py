@@ -507,8 +507,7 @@ def replay(capsule_dir: str, overrides: dict | None = None,
 
 def newest_capsule(root: str) -> str | None:
     """Newest ``incidents/<step>`` capsule dir under a checkpoint root
-    (or an incidents dir itself). Used by relay_watch to attach a replay
-    pointer when it kills a stalled bench."""
+    (or an incidents dir itself)."""
     cand = root
     if os.path.isdir(os.path.join(root, "incidents")):
         cand = os.path.join(root, "incidents")

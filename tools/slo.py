@@ -931,7 +931,7 @@ def main(argv=None) -> int:
     c.add_argument("--backend", choices=("cpu", "device"),
                    default="cpu",
                    help="drill backend: forced host CPU (hermetic CI "
-                        "default) or the real device (relay captures)")
+                        "default) or the real device")
     c.add_argument("--n", type=int, default=8)
     c.add_argument("--n-lat", type=int, default=6)
     c.add_argument("--n-lon", type=int, default=8)
