@@ -202,8 +202,12 @@ def one_chip(args, jax, out):
     for k in ("volume", "ke"):
         check(diffs[k] <= 1e-5 * abs(recs[-1][k]),
               f"restart {k} differs by {diffs[k]}")
-    check(diffs["max_div"] <= 1e-6 + 1e-3 * recs[-1]["max_div"],
-          f"restart max_div differs by {diffs['max_div']}")
+    # max_div of a projected field IS f32 roundoff, of size
+    # eps * max|u| / dx (= eps * 0.5 / cfl_dt), and the chip's scatter
+    # order is not repeatable: hold the difference to that scale
+    div_tol = 8 * 1.2e-7 * 0.5 / recs[-1]["cfl_dt"]
+    check(diffs["max_div"] <= div_tol,
+          f"restart max_div differs by {diffs['max_div']} (> {div_tol})")
 
     # ---- the engine that ran is the engine the resolver names
     named = resolve_engine(integ.ins.grid.n, n_markers,

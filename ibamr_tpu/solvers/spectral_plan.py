@@ -61,6 +61,56 @@ Vel = Tuple[jnp.ndarray, ...]
 DIFFERENTIATE_COEFFS = False
 
 
+# -- transforms that are right on the chip ------------------------------------
+#
+# On a TPU v5e (libtpu 0.0.34, PR 23 chip runs) two spellings return
+# WRONG values at 256^3 while every piece of them agrees with numpy to
+# 4e-7 (64^3 and 128^3 are right, and so is the CPU at every size):
+#
+# - ONE rank-3 inverse real transform: 30% of max|x| off on 99% of the
+#   elements, different from call to call. A rank-1 inverse over the
+#   leading axis, then the rank-2 inverse real transform, is right —
+#   but only with a barrier between them: without it XLA merges the two
+#   back into the rank-3 op (same wrong bits).
+# - transforms BATCHED over the stacked fields (3 forward, 4 inverse)
+#   whose operand was computed and not a program argument: 35% off,
+#   the same wrong values whether spelled rank-3, rank-1+rank-2 or as
+#   rank-1 passes with barriers. One field at a time is right.
+#
+# They left the flagship with max|div u| = 80 after 20 steps (scatter
+# engine, one step: 62; through the per-field chained solves: 3e-4).
+# So on that backend, rank-3 transforms go one field at a time and the
+# inverse is split. Same transforms, same FLOPs, more launches. Other
+# backends and ranks keep the single batched call: their graphs, bits
+# and FFT budgets do not change.
+
+def _chip_rank3(s: Sequence[int]) -> bool:
+    return len(s) == 3 and jax.default_backend() == "tpu"
+
+
+def rfftn(x: jnp.ndarray, s: Sequence[int],
+          axes: Optional[Sequence[int]] = None) -> jnp.ndarray:
+    """``jnp.fft.rfftn`` over the trailing ``len(s)`` axes of a field
+    or of a stack of fields (see the note above)."""
+    if _chip_rank3(s) and x.ndim == 4:
+        return jnp.stack([jnp.fft.rfftn(jax.lax.optimization_barrier(c))
+                          for c in x])
+    return jnp.fft.rfftn(x, axes=axes)
+
+
+def irfftn(xh: jnp.ndarray, s: Sequence[int],
+           axes: Optional[Sequence[int]] = None) -> jnp.ndarray:
+    """``jnp.fft.irfftn`` over the trailing ``len(s)`` axes of a
+    spectrum or of a stack of spectra (see the note above)."""
+    if not _chip_rank3(s):
+        return jnp.fft.irfftn(xh, s=s, axes=axes)
+    if xh.ndim == 4:
+        return jnp.stack([irfftn(c, s) for c in xh])
+    barrier = jax.lax.optimization_barrier
+    y = barrier(jnp.fft.ifft(barrier(xh), axis=0))
+    return jnp.fft.irfftn(y, s=tuple(s[1:]), axes=(1, 2))
+
+
 @contextlib.contextmanager
 def plain_autodiff_substep():
     """Trace-scoped opt-out of the fused substep's custom VJP.
@@ -331,17 +381,17 @@ class SpectralPlan:
     def solve_poisson(self, rhs: jnp.ndarray) -> jnp.ndarray:
         """lap(p) = rhs; zero-mean solution (k=0 mode discarded)."""
         sym = self.sym
-        rhat = jnp.fft.rfftn(rhs)
+        rhat = rfftn(rhs, self.shape)
         sym_safe = jnp.where(sym == 0, 1.0, sym)
         phat = jnp.where(sym == 0, 0.0, rhat / sym_safe)
-        p = jnp.fft.irfftn(phat, s=self.shape)
+        p = irfftn(phat, s=self.shape)
         return p.astype(rhs.dtype)
 
     def solve_helmholtz(self, rhs: jnp.ndarray, alpha, beta) -> jnp.ndarray:
         """(alpha + beta lap) u = rhs (alpha + beta*lam != 0 required)."""
-        rhat = jnp.fft.rfftn(rhs)
+        rhat = rfftn(rhs, self.shape)
         uhat = rhat / (alpha + beta * self.sym)
-        u = jnp.fft.irfftn(uhat, s=self.shape)
+        u = irfftn(uhat, s=self.shape)
         return u.astype(rhs.dtype)
 
     def solve_stokes_saddle(self, f_u: Vel, f_p: jnp.ndarray,
@@ -364,8 +414,8 @@ class SpectralPlan:
         dim = self.dim
         rdtype = self.rdtype
         sym, D = self.sym, self.D
-        fh = jnp.fft.rfftn(jnp.stack(tuple(f_u) + (f_p,)),
-                           axes=self.axes)
+        fh = rfftn(jnp.stack(tuple(f_u) + (f_p,)), self.shape,
+                   axes=self.axes)
         A = (alpha - mu * sym).astype(rdtype)
         divf = None
         for d in range(dim):
@@ -378,7 +428,7 @@ class SpectralPlan:
             [jnp.where(A == 0, 0.0,
                        (fh[d] + jnp.conj(D[d]) * ph) / A_safe)
              for d in range(dim)] + [ph])
-        out = jnp.fft.irfftn(uh, s=self.shape, axes=self.axes)
+        out = irfftn(uh, s=self.shape, axes=self.axes)
         out = out.astype(rdtype)
         return tuple(out[d] for d in range(dim)), out[dim]
 
@@ -404,14 +454,14 @@ def _substep_raw(plan: "SpectralPlan", sdtype_name: str, rhs: Vel,
     if sdtype is not None:
         # bf16 transform operands, f32 twiddle/accumulation
         x = _round_real(x.astype(jnp.float32), sdtype)
-    uh = jnp.fft.rfftn(x, axes=plan.axes)
+    uh = rfftn(x, plan.shape, axes=plan.axes)
     outh = plan.kspace_algebra(uh, alpha, beta, (a, b),
                                f32=sdtype is not None,
                                filter_sym=filter_sym)
     if sdtype is not None:
         # split-real compression of the inverse-transform operand
         outh = _round_complex(outh, sdtype)
-    out = jnp.fft.irfftn(outh, s=plan.shape, axes=plan.axes)
+    out = irfftn(outh, s=plan.shape, axes=plan.axes)
     out = out.astype(plan.rdtype)
     return tuple(out[d] for d in range(plan.dim)), out[plan.dim]
 
@@ -443,13 +493,13 @@ def _substep_bwd(plan, sdtype_name, res, ct):
         # mirror the primal's operand compression on the cotangents so
         # the transposed transforms see the same storage precision
         c = _round_real(c, sdtype)
-    ch = jnp.fft.rfftn(c, axes=plan.axes)
+    ch = rfftn(c, plan.shape, axes=plan.axes)
     gh = plan.kspace_algebra_adjoint(ch, alpha, beta, (a, b),
                                      f32=sdtype is not None,
                                      filter_sym=filter_sym)
     if sdtype is not None:
         gh = _round_complex(gh, sdtype)
-    g = jnp.fft.irfftn(gh, s=plan.shape, axes=plan.axes)
+    g = irfftn(gh, s=plan.shape, axes=plan.axes)
     g = g.astype(plan.rdtype)
     rhs_ct = tuple(g[d] for d in range(plan.dim))
     # alpha/beta/pinc are treated as constants (see

@@ -557,7 +557,15 @@ def _load_step(directory: str, step: int, template: Any, sharding_fn):
         if sharding_fn is not None:
             new_leaves.append(sharding_fn(key, arr))
         elif hasattr(leaf, "sharding"):
-            new_leaves.append(jax.device_put(arr, leaf.sharding))
+            # an UNCOMMITTED template leaf (the fresh single-device
+            # state) must restore uncommitted: a committed argument
+            # lowers with sharding annotations, i.e. to a different
+            # program than the fresh run compiled — the restart would
+            # miss the compile cache and pay the whole compile again
+            # (130 s at the flagship, PR 23 chip run)
+            new_leaves.append(
+                jax.device_put(arr, leaf.sharding)
+                if getattr(leaf, "committed", True) else jnp.asarray(arr))
         else:
             new_leaves.append(arr)
     state = jax.tree_util.tree_unflatten(treedef, new_leaves)

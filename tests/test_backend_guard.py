@@ -61,10 +61,13 @@ def test_cache_dir_comes_from_env_when_set(monkeypatch, tmp_path,
     assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.5
 
 
-def test_cache_dir_defaults_to_checkout(monkeypatch, cache_config):
+def test_cache_dir_defaults_to_checkout(monkeypatch, tmp_path,
+                                        cache_config):
+    assert aot_cache.REPO_ROOT == REPO
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(aot_cache, "REPO_ROOT", str(tmp_path))
     d = aot_cache.enable_persistent_cache()
-    assert d == os.path.join(REPO, ".jax_cache")
+    assert d == str(tmp_path / ".jax_cache")
     assert jax.config.jax_compilation_cache_dir == d
     assert os.path.isdir(d)
 

@@ -281,3 +281,53 @@ def test_jitted_step_donation_ib():
     assert bool(jnp.all(jnp.isfinite(s3.X)))
     if hasattr(u_before, "is_deleted"):
         assert u_before.is_deleted()
+
+
+@pytest.mark.parametrize("lead,axes", [((), None), ((4,), (1, 2, 3))])
+def test_chip_transform_spelling_matches_single_call(monkeypatch, lead,
+                                                     axes):
+    """On the chip, rank-3 transforms go one field at a time and the
+    inverse is a rank-1 inverse, a barrier, and a rank-2 inverse real
+    transform (the single rank-3 inverse and the field-batched calls
+    are wrong at 256^3 on a v5e, PR 23). Same values as the single
+    call; off the chip the graph is untouched."""
+    from ibamr_tpu.analysis.graph_census import iter_eqns
+
+    s = (8, 6, 10)
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.standard_normal(lead + s))
+
+    def ffts(fn, arg):
+        eqns = [e for e, _ in iter_eqns(jax.make_jaxpr(fn)(arg).jaxpr)]
+        return ([e for e in eqns if e.primitive.name == "fft"],
+                [e.primitive.name for e in eqns])
+
+    def fwd():        # fresh callables per trace: no stale jaxpr
+        return lambda a: spectral_plan.rfftn(a, s, axes=axes)
+
+    def inv():
+        return lambda z: spectral_plan.irfftn(z, s=s, axes=axes)
+
+    xh = jnp.fft.rfftn(x, axes=axes)
+    for fn, arg in ((fwd(), x), (inv(), xh)):
+        f, names = ffts(fn, arg)
+        assert len(f) == 1 and "optimization_barrier" not in names
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n_fields = lead[0] if lead else 1
+    f, names = ffts(fwd(), x)
+    assert len(f) == n_fields
+    f_inv, names_inv = ffts(inv(), xh)
+    assert len(f_inv) == 2 * n_fields
+    assert "optimization_barrier" in names_inv
+    for e in f + f_inv:
+        assert e.invars[0].aval.ndim == 3          # one field at a time
+        assert (len(e.params["fft_lengths"]) < 3
+                or "IRFFT" not in str(e.params["fft_type"]))
+    np.testing.assert_allclose(np.asarray(fwd()(x)), np.asarray(xh),
+                               atol=1e-12)
+    np.testing.assert_allclose(np.asarray(inv()(xh)), np.asarray(x),
+                               atol=1e-13)
+    # rank 2 keeps the single call on every backend
+    x2 = jnp.asarray(rng.standard_normal((3, 8, 6)))
+    f, _ = ffts(lambda a: spectral_plan.rfftn(a, (8, 6), axes=(1, 2)), x2)
+    assert len(f) == 1

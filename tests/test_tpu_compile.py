@@ -10,11 +10,15 @@ topology is described inside a module-scoped fixture, never at import
 / collection; compiles run in the test's own process with the
 persistent compile cache off; everything TPU lives in THIS one file.
 
-Sizes: the transfer kernels compile at the flagship's real width
-(256^3, 316x316 = 99,856 markers) with the bucket pytree handed in as
-shapes, so only the kernel and its overlap-add compile. The whole
-``integ.step`` compiles at 64^3 (the 256^3 whole-step compile takes
-minutes and is made by hand; CHANGES.md PR 23 records it).
+Sizes: the interp kernels compile at the flagship's real width (256^3,
+316x316 = 99,856 markers; ~5 s each) with the bucket pytree handed in
+as shapes, so only the kernel compiles. The spread kernels with their
+overlap-add take ~88 s EACH at 256^3 (measured PR 23: pallas_packed,
+hybrid_bf16 and pallas all compiled there, ``tpu_custom_call``
+present), so tier-1 compiles them at the 64^3 flagship geometry
+(79x79 markers). The whole ``integ.step`` compiles at 64^3 too (the
+256^3 step and scan-chunk compiles take ~2 min each and are made by
+hand; CHANGES.md PR 23 records them).
 """
 
 import os
@@ -45,14 +49,17 @@ def one_chip():
 
 
 @pytest.fixture(scope="module", autouse=True)
-def _no_persistent_cache():
+def _chip_compile_mode():
     # a described-chip executable is written to the persistent cache
     # but cannot be read back without a chip; keep these compiles out
     from jax.experimental.compilation_cache import compilation_cache
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield
+    # production mode: conftest turns x64 on for the CPU convergence
+    # tests; the chip program is f32/int32 (Mosaic lowers no 64-bit)
+    with jax.enable_x64(False):
+        yield
     jax.config.update("jax_enable_compilation_cache", prev)
     compilation_cache.reset_cache()
 
@@ -77,35 +84,36 @@ def _compiled_mode(fast):
     return fast
 
 
+FLAGSHIP = {256: 316, 64: 79}     # grid extent -> shell lattice side
 _ENGINES = {}
 
 
-def _engine(name):
-    """Registry-built engine at the flagship geometry (the sizing the
-    run uses: suggest_chunks / suggest_cap from the real lattice)."""
-    if name not in _ENGINES:
-        grid = StaggeredGrid(n=(256,) * 3, x_lo=(0.0,) * 3,
+def _engine(name, n):
+    """Registry-built engine at a flagship geometry (the sizing the run
+    uses: suggest_chunks / suggest_cap from the real lattice)."""
+    if (name, n) not in _ENGINES:
+        grid = StaggeredGrid(n=(n,) * 3, x_lo=(0.0,) * 3,
                              x_up=(1.0,) * 3)
-        verts = make_spherical_shell(316, 316, 0.25, (0.5, 0.5, 0.5),
-                                     1.0, aspect=1.2).vertices
-        assert verts.shape[0] == 99856
+        verts = make_spherical_shell(FLAGSHIP[n], FLAGSHIP[n], 0.25,
+                                     (0.5, 0.5, 0.5), 1.0,
+                                     aspect=1.2).vertices
         fast = _compiled_mode(
             construct_transfer_engine(name, grid, verts, "IB_4"))
         X = jax.ShapeDtypeStruct(verts.shape, jnp.float32)
-        _ENGINES[name] = (fast, X, jax.eval_shape(fast.buckets, X))
-    return _ENGINES[name]
+        _ENGINES[name, n] = (fast, X, jax.eval_shape(fast.buckets, X))
+    return _ENGINES[name, n]
 
 
-@pytest.mark.parametrize("name,op", [
-    ("pallas_packed", "spread"),
-    ("pallas_packed", "interp"),
-    ("hybrid_bf16", "spread"),
-    ("pallas", "spread"),
-    ("pallas", "interp"),
+@pytest.mark.parametrize("name,op,n", [
+    ("pallas_packed", "interp", 256),
+    ("pallas", "interp", 256),
+    ("pallas_packed", "spread", 64),
+    ("hybrid_bf16", "spread", 64),
+    ("pallas", "spread", 64),
 ])
-def test_transfer_kernel_compiles_at_256(one_chip, name, op):
-    fast, X, b = _engine(name)
-    assert X.shape[0] == 99856 and fast.grid.n[-1] == 256
+def test_transfer_kernel_compiles(one_chip, name, op, n):
+    fast, X, b = _engine(name, n)
+    assert X.shape[0] == FLAGSHIP[n] ** 2 and fast.grid.n[-1] == n
     if op == "spread":
         def fn(F, X, b):
             return fast.spread_vel(F, X, b=b)

@@ -101,6 +101,30 @@ def test_checkpoint_roundtrip(tmp_path):
     assert float(restored.t) == pytest.approx(1.25)
 
 
+def test_restart_lowers_to_the_fresh_program(tmp_path):
+    """A state restored into a fresh (uncommitted) template must lower
+    to the same program the fresh run compiled, or the restart misses
+    the compile cache and pays the whole compile again (PR 23: 130 s at
+    the flagship). A committed template keeps its placement."""
+    import jax
+
+    state = _mkstate(0)
+    d = str(tmp_path / "ckpt")
+    save_checkpoint(d, state, step=1)
+    restored, _, _ = restore_checkpoint(d, _mkstate(99))
+    assert not any(leaf.committed
+                   for leaf in jax.tree_util.tree_leaves(restored))
+    step = jax.jit(lambda s: s._replace(u=s.u * 2.0))
+    assert step.lower(restored).as_text() == step.lower(state).as_text()
+
+    dev = jax.devices()[1]
+    placed = jax.tree_util.tree_map(lambda a: jax.device_put(a, dev),
+                                    _mkstate(99))
+    restored, _, _ = restore_checkpoint(d, placed)
+    assert all(leaf.committed and leaf.devices() == {dev}
+               for leaf in jax.tree_util.tree_leaves(restored))
+
+
 def test_checkpoint_prune(tmp_path):
     d = str(tmp_path / "ckpt")
     s = _mkstate(1)

@@ -47,8 +47,6 @@ def main():
                          "stall risk)")
     args = ap.parse_args()
 
-    import os
-
     import jax
     import jax.numpy as jnp
 
