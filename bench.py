@@ -840,8 +840,6 @@ def run_stage(jax, n: int, n_lat: int, n_lon: int, steps: int,
     step_raw, _dn = aot_cache.step_callable(integ, donate=True,
                                             with_stats=True)
 
-    hard_sync = jax.block_until_ready
-
     from ibamr_tpu.utils.timers import profile_trace
 
     def timed_run(capture_dir=""):
@@ -849,7 +847,7 @@ def run_stage(jax, n: int, n_lat: int, n_lon: int, steps: int,
         t_c0 = time.perf_counter()
         for _ in range(max(warmup, 1)):
             state, _ = step(state, dt)
-        hard_sync(state)
+        jax.block_until_ready(state)
         compile_s = time.perf_counter() - t_c0
 
         # accumulate refresh hits as a device scalar (no per-step sync;
@@ -867,7 +865,7 @@ def run_stage(jax, n: int, n_lat: int, n_lon: int, steps: int,
                 if rh is not None:
                     rh = rh.astype(jax.numpy.int32)
                     hit_acc = rh if hit_acc is None else hit_acc + rh
-            hard_sync(state)
+            jax.block_until_ready(state)
             elapsed = time.perf_counter() - t0
         if hit_acc is not None:
             hit_acc = int(jax.device_get(hit_acc))

@@ -24,6 +24,8 @@ import sys
 import time
 import warnings
 
+import numpy as np
+
 REPO = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, REPO)
 EX4 = os.path.join(REPO, "examples", "IB", "explicit", "ex4")
@@ -38,7 +40,7 @@ PHASE_LOG = os.path.join(REPO, "chiprun_out", "chip_smoke.jsonl")
 TOL_BF16, TOL_F32 = 8e-3, 1e-4
 # sharded vs one device, 10 steps: f32 roundoff times steps (pressure is
 # the projection's Lagrange multiplier, one order more sensitive)
-TOL_SHARDED = {"u": 1e-4, "X": 1e-4, "p": 1e-3}
+TOL_SHARDED = {"u": 1e-4, "U": 1e-4, "X": 1e-4, "p": 1e-3}
 
 
 def phase(name, **kv):
@@ -135,10 +137,21 @@ def check_metrics(recs, vol0):
 
 
 def rel_diff(a, b):
-    import jax.numpy as jnp
+    """max|a - b| / max|b| on the host (gathers a sharded array)."""
+    a, b = np.asarray(a), np.asarray(b)
+    return float(np.max(np.abs(a - b))) / max(float(np.max(np.abs(b))),
+                                              1e-30)
 
-    scale = float(jnp.max(jnp.abs(b)))
-    return float(jnp.max(jnp.abs(a - b))) / max(scale, 1e-30)
+
+def smoke_input(args, out, name, **cadence):
+    """Write the smoke's input file; returns (path, parsed db, dt)."""
+    from ibamr_tpu.utils import parse_input_file
+
+    inp = os.path.join(out, name)
+    write_input(inp, out, rehearse=args.rehearse, **cadence)
+    db = parse_input_file(inp)
+    return inp, db, db.get_database(
+        "INSStaggeredHierarchyIntegrator").get_float("dt")
 
 
 def cache_entries(d):
@@ -157,14 +170,12 @@ def one_chip(args, jax, out):
     from ibamr_tpu.models.engine_resolver import resolve_engine
     from ibamr_tpu.models.shell3d import build_shell_example, shell_volume
     from ibamr_tpu.ops.delta import get_kernel
-    from ibamr_tpu.utils import parse_input_file
 
     check(len(jax.devices()) == 1,
           f"{len(jax.devices())} devices visible: the one-chip smoke "
           "needs exactly one (use --chips 4 for the sharded path)")
-    inp = os.path.join(out, "input3d.smoke")
-    write_input(inp, out, num_steps=40, viz=20, restart=20,
-                rehearse=args.rehearse)
+    inp, db, dt = smoke_input(args, out, "input3d.smoke",
+                              num_steps=40, viz=20, restart=20)
     mod, seen = load_main()
 
     # ---- pass 1: 40 steps, dumps and checkpoints at 20 and 40
@@ -191,7 +202,8 @@ def one_chip(args, jax, out):
     _, wall2, chunks2 = run_main(
         mod, ["main.py", inp, os.path.join(out, "restart"), "20"],
         os.path.join(out, "ledger_pass2.jsonl"))
-    restore_s, (_, restored_step, _) = seen["restore"][0]
+    restore_s, (_, restored_step, _) = seen["restore"].pop()
+    del seen["build"][1:]     # drop pass 2's integrator and states
     check(restored_step == 20, f"restored step {restored_step}")
     again = read_metrics(out)[-1]
     check(again["step"] == 40, f"restart ended at step {again['step']}")
@@ -227,20 +239,22 @@ def one_chip(args, jax, out):
 
     # ---- one step of the same state: resolved engine vs XLA scatter
     ref, _ = build_shell_example(
-        input_db=parse_input_file(inp), dtype=jnp.float32,
+        input_db=db, dtype=jnp.float32,
         use_fast_interaction=False, engine_fallback=False)
-    dt = parse_input_file(inp).get_database(
-        "INSStaggeredHierarchyIntegrator").get_float("dt")
     t0 = time.perf_counter()
     a = jax.block_until_ready(jax.jit(integ.step)(state0, dt))
     b = jax.block_until_ready(jax.jit(ref.step)(state0, dt))
-    du = max(rel_diff(x, y) for x, y in zip(a.ins.u, b.ins.u))
-    dX = rel_diff(a.X - state0.X, b.X - state0.X)
+    # u carries the spread, U (marker velocity) the interp; X moves by
+    # dt*U, below one ulp of X after a single step, so it is compared
+    # as a position, not as a displacement
+    diffs = {"u": max(rel_diff(x, y) for x, y in zip(a.ins.u, b.ins.u)),
+             "U": rel_diff(a.U, b.U), "X": rel_diff(a.X, b.X)}
     tol = TOL_BF16 if named.endswith("bf16") else TOL_F32
-    phase("scatter_vs_engine", engine=named, rel_diff_u=du,
-          rel_diff_dX=dX, tol=tol, seconds=time.perf_counter() - t0)
-    check(du < tol and dX < tol,
-          f"{named} vs scatter: u {du}, dX {dX} (tol {tol})")
+    tols = {"u": tol, "U": 2 * tol, "X": tol}   # U: spread, then interp
+    phase("scatter_vs_engine", engine=named, rel_diff=diffs, tol=tols,
+          seconds=time.perf_counter() - t0)
+    check(all(diffs[k] < tols[k] for k in diffs),
+          f"{named} vs scatter: {diffs} (tol {tols})")
     phase("memory", peak_bytes_in_use=peak_bytes(jax.devices()[0]))
 
 
@@ -249,14 +263,12 @@ def four_chips(args, jax, out):
 
     from ibamr_tpu.models.shell3d import build_shell_example
     from ibamr_tpu.parallel import mesh as pmesh
-    from ibamr_tpu.utils import parse_input_file
     from ibamr_tpu.utils.hierarchy_driver import HierarchyDriver, RunConfig
 
     check(len(jax.devices()) == 4,
           f"--chips 4 needs 4 devices, found {len(jax.devices())}")
-    inp = os.path.join(out, "input3d.smoke4")
-    write_input(inp, out, num_steps=10, viz=10, restart=0,
-                rehearse=args.rehearse)
+    inp, db, dt = smoke_input(args, out, "input3d.smoke4",
+                              num_steps=10, viz=10, restart=0)
     mod, seen = load_main()
 
     # learn (not steer) whether the S2 marker facade engaged
@@ -301,25 +313,21 @@ def four_chips(args, jax, out):
     # ---- what it is compared with: 10 steps on ONE device, exact-f32
     # scatter transfers (the S2 engine's local arithmetic), same driver
     ref, s1 = build_shell_example(
-        input_db=parse_input_file(inp), dtype=jnp.float32,
+        input_db=db, dtype=jnp.float32,
         use_fast_interaction=False, engine_fallback=False)
     step1 = jax.jit(lambda s, d: ref.step(s, d))
     t0 = time.perf_counter()
     state1 = jax.block_until_ready(HierarchyDriver(
-        ref, RunConfig(dt=5e-5, num_steps=10, health_interval=10),
+        ref, RunConfig(dt=dt, num_steps=10, health_interval=10),
         step_fn=step1).run(s1))
     one_s = time.perf_counter() - t0
     check({d.id for d in state1.X.devices()} == {jax.devices()[0].id},
           "one-device reference is not on one device")
-    gather = jax.device_get
-    diffs = {
-        "u": max(rel_diff(jnp.asarray(gather(x)), jnp.asarray(gather(y)))
-                 for x, y in zip(state4.ins.u, state1.ins.u)),
-        "p": rel_diff(jnp.asarray(gather(state4.ins.p)),
-                      jnp.asarray(gather(state1.ins.p))),
-        "X": rel_diff(jnp.asarray(gather(state4.X)) - gather(s1.X),
-                      jnp.asarray(gather(state1.X)) - gather(s1.X)),
-    }
+    diffs = {"u": max(rel_diff(x, y)
+                      for x, y in zip(state4.ins.u, state1.ins.u)),
+             "p": rel_diff(state4.ins.p, state1.ins.p),
+             "X": rel_diff(state4.X, state1.X),
+             "U": rel_diff(state4.U, state1.U)}
     phase("sharded_vs_one_device", rel_diff=diffs, tol=TOL_SHARDED,
           one_device_s=one_s,
           peak_bytes_in_use=[peak_bytes(d) for d in jax.devices()])

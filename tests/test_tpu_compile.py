@@ -21,6 +21,7 @@ present), so tier-1 compiles them at the 64^3 flagship geometry
 hand; CHANGES.md PR 23 records them).
 """
 
+import functools
 import os
 
 import jax
@@ -85,23 +86,18 @@ def _compiled_mode(fast):
 
 
 FLAGSHIP = {256: 316, 64: 79}     # grid extent -> shell lattice side
-_ENGINES = {}
-
-
+@functools.lru_cache(maxsize=None)
 def _engine(name, n):
     """Registry-built engine at a flagship geometry (the sizing the run
     uses: suggest_chunks / suggest_cap from the real lattice)."""
-    if (name, n) not in _ENGINES:
-        grid = StaggeredGrid(n=(n,) * 3, x_lo=(0.0,) * 3,
-                             x_up=(1.0,) * 3)
-        verts = make_spherical_shell(FLAGSHIP[n], FLAGSHIP[n], 0.25,
-                                     (0.5, 0.5, 0.5), 1.0,
-                                     aspect=1.2).vertices
-        fast = _compiled_mode(
-            construct_transfer_engine(name, grid, verts, "IB_4"))
-        X = jax.ShapeDtypeStruct(verts.shape, jnp.float32)
-        _ENGINES[name, n] = (fast, X, jax.eval_shape(fast.buckets, X))
-    return _ENGINES[name, n]
+    grid = StaggeredGrid(n=(n,) * 3, x_lo=(0.0,) * 3, x_up=(1.0,) * 3)
+    verts = make_spherical_shell(FLAGSHIP[n], FLAGSHIP[n], 0.25,
+                                 (0.5, 0.5, 0.5), 1.0,
+                                 aspect=1.2).vertices
+    fast = _compiled_mode(
+        construct_transfer_engine(name, grid, verts, "IB_4"))
+    X = jax.ShapeDtypeStruct(verts.shape, jnp.float32)
+    return fast, X, jax.eval_shape(fast.buckets, X)
 
 
 @pytest.mark.parametrize("name,op,n", [

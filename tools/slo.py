@@ -465,15 +465,21 @@ def tighten_contract(slis: dict, drill_cfg: dict) -> dict:
     }
 
 
+def _init_backend(args) -> None:
+    """Forced host CPU (the hermetic default) or the real device — which
+    raises where there is none."""
+    from ibamr_tpu.utils import backend_guard
+
+    if args.backend == "device":
+        backend_guard.auto_backend()
+    else:
+        backend_guard.force_cpu()
+
+
 def run_drill_ledger(args, ledger_path: str) -> dict:
     """Run ``cold_warm_drill`` with a fresh attached ledger and flush
     the metric registry into it; returns the drill output."""
-    if args.backend == "device":
-        from ibamr_tpu.utils.backend_guard import auto_backend
-        auto_backend()
-    else:
-        from ibamr_tpu.utils.backend_guard import force_cpu
-        force_cpu()
+    _init_backend(args)
     from ibamr_tpu import obs as _obs
     from ibamr_tpu.serve.router import cold_warm_drill
 
@@ -493,12 +499,7 @@ def run_soak_ledger(args, ledger_path: str) -> dict:
     """Run the bounded open-loop soak with a fresh attached ledger
     and flush the metric registry into it; returns the traffic
     summary."""
-    if args.backend == "device":
-        from ibamr_tpu.utils.backend_guard import auto_backend
-        auto_backend()
-    else:
-        from ibamr_tpu.utils.backend_guard import force_cpu
-        force_cpu()
+    _init_backend(args)
     from ibamr_tpu import obs as _obs
     from ibamr_tpu.serve.loadgen import soak_drill
 
@@ -519,12 +520,7 @@ def run_elastic_drill(args, directory: str) -> dict:
     drill owns its own attached ledger
     (``<directory>/elastic_ledger.jsonl``) and raises on any broken
     invariant before the SLO layer even evaluates."""
-    if args.backend == "device":
-        from ibamr_tpu.utils.backend_guard import auto_backend
-        auto_backend()
-    else:
-        from ibamr_tpu.utils.backend_guard import force_cpu
-        force_cpu()
+    _init_backend(args)
     from tools.fault_injection import run_elastic_smoke
 
     return run_elastic_smoke(directory,
@@ -540,12 +536,7 @@ def run_assim_drill(args, directory: str) -> dict:
     and raises on any broken invariant (unrejected bad obs,
     unquarantined member, lost cycle, retrace) before the SLO layer
     even evaluates."""
-    if args.backend == "device":
-        from ibamr_tpu.utils.backend_guard import auto_backend
-        auto_backend()
-    else:
-        from ibamr_tpu.utils.backend_guard import force_cpu
-        force_cpu()
+    _init_backend(args)
     from tools.fault_injection import run_assim_smoke
 
     return run_assim_smoke(directory,
