@@ -785,12 +785,7 @@ def run_stage(jax, n: int, n_lat: int, n_lon: int, steps: int,
     trace-viewer JSON export caps at 1e6 events and a multi-second
     XLA compile floods it with python-tracer events, truncating the
     device-op events attribution needs (measured: an 8 s in-capture
-    compile left 25 op events of a 4-step run). The capture also gets
-    the ``census_counts.json`` roofline sidecar: the PR-8 byte/flop
-    census of one step jaxpr plus the exact number of step launches
-    captured, so ``tools/prof.py`` can turn attributed seconds into
-    achieved GB/s — traced while the step function is still in hand
-    (trace only, no extra compile)."""
+    compile left 25 op events of a 4-step run)."""
     from ibamr_tpu.models.shell3d import build_shell_example
 
     integ, state = build_shell_example(
@@ -825,8 +820,7 @@ def run_stage(jax, n: int, n_lat: int, n_lon: int, steps: int,
     # the AOT cache (one compile per fingerprint+aval family, shared
     # with the warm-pool router); fast_opts changes constants baked
     # into the graph without changing input avals, so it must be in
-    # the key. The raw python callable stays in hand for the census
-    # (a Compiled executable cannot be re-traced).
+    # the key.
     from ibamr_tpu.serve import aot_cache
 
     cache_before = aot_cache.executable_cache_stats()
@@ -837,8 +831,6 @@ def run_stage(jax, n: int, n_lat: int, n_lon: int, steps: int,
         label=f"bench:n{n}")
     aot_s = time.perf_counter() - t_aot
     cache_after = aot_cache.executable_cache_stats()
-    step_raw, _dn = aot_cache.step_callable(integ, donate=True,
-                                            with_stats=True)
 
     from ibamr_tpu.utils.timers import profile_trace
 
@@ -852,9 +844,8 @@ def run_stage(jax, n: int, n_lat: int, n_lon: int, steps: int,
 
         # accumulate refresh hits as a device scalar (no per-step sync;
         # a host round-trip per step would poison the timing); the
-        # profile capture brackets EXACTLY these `steps` launches (the
-        # census sidecar's executions count) — trace start/stop sit
-        # outside the timed window
+        # profile capture brackets EXACTLY these `steps` launches —
+        # trace start/stop sit outside the timed window
         hit_acc = None
         elapsed = 0.0
         with profile_trace(capture_dir, stage=profile_stage):
@@ -903,24 +894,6 @@ def run_stage(jax, n: int, n_lat: int, n_lon: int, steps: int,
         # cheap re-gather, falls paid a full re-pack (drift bound blown)
         out["refresh_hits"] = refresh_hits
         out["repack_falls"] = steps - refresh_hits
-    if profile_dir:
-        # roofline sidecar beside the capture; never let a census
-        # hiccup (an exotic engine's trace failing) cost the stage
-        try:
-            from ibamr_tpu.obs import deviceprof
-            from ibamr_tpu.obs.roofline import census_sidecar
-
-            census = census_sidecar(
-                lambda s: step_raw(s, dt)[0], (state,),
-                label=profile_stage or f"n{n}",
-                executions=steps, n=n, markers=n_markers)
-            os.makedirs(profile_dir, exist_ok=True)
-            with open(os.path.join(profile_dir,
-                                   deviceprof.CENSUS_NAME), "w") as f:
-                json.dump(census, f, indent=1, sort_keys=True)
-        except Exception as e:  # noqa: BLE001
-            log(f"[bench] census sidecar failed for n={n}: "
-                f"{type(e).__name__}: {e}")
     return out
 
 

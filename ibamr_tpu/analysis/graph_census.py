@@ -267,8 +267,7 @@ def collective_census(jaxpr) -> dict:
     execution (for ``psum``/``ppermute``/``pbroadcast`` this equals
     the input payload; for ``all_gather`` it is the gathered result,
     ``axis_size`` times the input). Inside a ``shard_map`` body avals
-    are per-shard, so the numbers read as per-device traffic — the
-    operand the roofline join divides by ``comm_s``.
+    are per-shard, so the numbers read as per-device traffic.
     """
     out = {"collective_prims": 0, "collective_bytes": 0}
     for p in _COLLECTIVE_PRIMS:

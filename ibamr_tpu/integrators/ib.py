@@ -245,10 +245,18 @@ class IBExplicitIntegrator:
         def ctx_at(X):
             return prep(X, state.mask) if prep is not None else None
 
+        # Phase names (jax.named_scope: metadata only, no op added): the
+        # compiled step says which phase owns each instruction, and
+        # obs/deviceprof reads device time back by these names. No
+        # component may match an op class of perfbench/tracereduce.py.
+        scope = jax.named_scope
+
         # structure prediction to the half step
-        ctx_n = ctx_at(X_n)
-        U_n = ib.interpolate_velocity(u_n, grid, X_n, state.mask,
-                                      ctx=ctx_n)
+        with scope("ib/prep"):
+            ctx_n = ctx_at(X_n)
+        with scope("ib/interp"):
+            U_n = ib.interpolate_velocity(u_n, grid, X_n, state.mask,
+                                          ctx=ctx_n)
         refresh_hit = None
         if self.scheme == "midpoint":
             X_half = X_n + 0.5 * dt * U_n
@@ -259,27 +267,34 @@ class IBExplicitIntegrator:
             refresh = getattr(ib, "refresh", None)
             ctx_h = None
             if refresh is not None and ctx_n is not None:
-                ctx_h, refresh_hit = refresh(ctx_n, X_half, state.mask)
+                with scope("ib/refresh"):
+                    ctx_h, refresh_hit = refresh(ctx_n, X_half,
+                                                 state.mask)
             if ctx_h is None:
-                ctx_h = ctx_at(X_half)
+                with scope("ib/prep"):
+                    ctx_h = ctx_at(X_half)
         else:
             X_half = X_n
             ctx_h = ctx_n
 
         # Lagrangian force at the half step, spread to the grid
         t_half = state.ins.t + 0.5 * dt
-        F_half = ib.compute_force(X_half, U_n, t_half)
-        f_eul = ib.spread_force(F_half, grid, X_half, state.mask,
-                                ctx=ctx_h)
+        with scope("ib/force"):
+            F_half = ib.compute_force(X_half, U_n, t_half)
+        with scope("ib/spread"):
+            f_eul = ib.spread_force(F_half, grid, X_half, state.mask,
+                                    ctx=ctx_h)
 
         # fluid solve with the IB body force
-        ins_new = self.ins.step(state.ins, dt, f=f_eul)
+        with scope("fluid"):
+            ins_new = self.ins.step(state.ins, dt, f=f_eul)
 
         # corrector: move markers with the midpoint velocity
         if self.scheme == "midpoint":
             u_half = tuple(0.5 * (a + b) for a, b in zip(u_n, ins_new.u))
-            U_half = ib.interpolate_velocity(u_half, grid, X_half,
-                                             state.mask, ctx=ctx_h)
+            with scope("ib/interp"):
+                U_half = ib.interpolate_velocity(u_half, grid, X_half,
+                                                 state.mask, ctx=ctx_h)
             X_new = X_n + dt * U_half
             U_out = U_half
         else:

@@ -25,10 +25,16 @@ host-side work rides the existing one-transfer-per-chunk sync points.
 PR 10 adds the read-back half: :mod:`ibamr_tpu.obs.deviceprof` parses
 ``jax.profiler`` captures and attributes device-lane op time back to
 span paths (the ledger's ``device_time`` record / ``prof_summary.json``
-artifact), and :mod:`ibamr_tpu.obs.roofline` joins that time with the
-PR-8 graph-census byte/flop counts into achieved-bandwidth numbers.
-Both are offline, stdlib-only, and imported lazily here — attaching a
+artifact). It is offline and imported lazily here — attaching a
 ledger to a run never pays for the trace parser.
+
+PR 25: every closed span also lands in a bounded in-memory ring
+(:func:`spans`) and on the profiler's timeline
+(``jax.profiler.TraceAnnotation``); one ``jax.monitoring`` listener
+records compiles and cache reads as spans; the run loop registers the
+chunk programs it calls (:func:`programs`) so that ``deviceprof`` can
+read their compiled text afterwards and join the chip's ``%fusion.N``
+events to the phase names inside the step (``ib/prep`` ... ``fluid``).
 
 PR 15 adds pod scope: ``RunLedger(..., proc=...)`` routes each process
 of a multi-host run to its own ``ledger-<proc>.jsonl`` shard (same
@@ -49,6 +55,7 @@ from ibamr_tpu.obs.bus import (  # noqa: F401
     RunLedger,
     attach,
     chunk_boundary,
+    clear_spans,
     counter,
     current,
     current_trace,
@@ -63,14 +70,17 @@ from ibamr_tpu.obs.bus import (  # noqa: F401
     metrics_snapshot,
     new_trace_id,
     peek_gauge,
+    programs,
     quantiles_from_counts,
     read_ledger,
     record_trace_ids,
+    register_program,
     reset_metrics,
     run_id_from_fingerprint,
     sample_memory_watermarks,
     shard_path,
     span,
+    spans,
     trace_scope,
 )
 from ibamr_tpu.obs.export import (  # noqa: F401

@@ -21,13 +21,16 @@ never interleave bytes.
 from __future__ import annotations
 
 import hashlib
+import itertools
 import json
 import math
 import os
 import re
+import sys
 import threading
 import time
 from bisect import bisect_right
+from collections import deque
 from contextlib import contextmanager
 from typing import Any, Dict, Optional, Tuple
 
@@ -602,36 +605,83 @@ def record_trace_ids(rec: dict) -> Tuple[str, ...]:
 # spans
 # ---------------------------------------------------------------------------
 
+# Every closed span lands in a bounded in-memory ring, ledger or not:
+# ``{"id", "parent", "name", "path", "t0", "t1", "attrs"}`` with t0/t1
+# on ``time.perf_counter()``. Oldest entries drop first.
+SPAN_RING_SIZE = 4096
+_RING: "deque[dict]" = deque(maxlen=SPAN_RING_SIZE)
+_SPAN_IDS = itertools.count(1)
+
 
 def _stack() -> list:
+    """Thread-local stack of open spans: ``[name, id, attrs]``."""
     st = getattr(_TLS, "stack", None)
     if st is None:
         st = _TLS.stack = []
     return st
 
 
+def spans() -> list:
+    """The closed spans still in the ring, oldest first."""
+    return list(_RING)
+
+
+def clear_spans() -> None:
+    _RING.clear()
+
+
+def _close_span(name: str, path: str, sid: int, parent: Optional[int],
+                depth: int, t0: float, t1: float, attrs: dict,
+                err: Optional[str] = None) -> None:
+    """One closed span into the ring and, when one is attached, the
+    ledger (kind ``span``; the sink PR 9 defined)."""
+    rec = {"id": sid, "parent": parent, "name": name, "path": path,
+           "t0": t0, "t1": t1, "attrs": attrs}
+    if err is not None:
+        rec["error"] = err
+    _RING.append(rec)
+    led = _CURRENT
+    if led is not None:
+        payload = {"name": name, "path": path, "depth": depth,
+                   "dur_s": round(t1 - t0, 9)}
+        if attrs:
+            payload["attrs"] = attrs
+        if err is not None:
+            payload["error"] = err
+        _stamp_trace(payload)
+        led.append("span", payload)
+
+
 @contextmanager
 def span(name: str, block_on=None, **attrs):
     """One nested wall-clock span.
 
-    Enters ``jax.named_scope`` with the leaf name so the phase also
-    lands in on-chip profiler traces; on exit optionally blocks on
-    ``block_on`` (a pytree of arrays — the async-dispatch discipline
-    from ``utils/timers.py``) BEFORE reading the clock, then closes
-    the span into the current ledger (kind ``span``, with the full
-    slash ``path`` so readers rebuild the tree without matching
-    open/close pairs). Without an attached ledger the cost is two
-    clock reads and a list push/pop."""
+    Enters ``jax.named_scope`` with the leaf name and
+    ``jax.profiler.TraceAnnotation`` with the full path, so under any
+    profiler capture the span sits on the timeline beside the device
+    operations; on exit optionally blocks on ``block_on`` (a pytree of
+    arrays — the async-dispatch discipline from ``utils/timers.py``)
+    BEFORE reading the clock, then closes the span into the ring
+    (:func:`spans`) and, when one is attached, the current ledger (kind
+    ``span``, with the full slash ``path`` so readers rebuild the tree
+    without matching open/close pairs). Without a ledger and without a
+    capture the cost is a few clock reads and one append. Host side
+    only, at chunk granularity: never call it inside traced code."""
     import jax
 
+    _listen_for_compiles(jax)
+    name = str(name)
     st = _stack()
-    st.append(str(name))
-    path = "/".join(st)
+    parent = st[-1][1] if st else None
+    sid = next(_SPAN_IDS)
+    st.append((name, sid, attrs))
+    path = "/".join(e[0] for e in st)
     depth = len(st) - 1
     t0 = time.perf_counter()
     err = None
     try:
-        with jax.named_scope(str(name).split("::")[-1].split("/")[-1]):
+        with jax.profiler.TraceAnnotation(path), \
+                jax.named_scope(name.split("::")[-1].split("/")[-1]):
             yield
     except BaseException as e:
         err = type(e).__name__
@@ -642,18 +692,98 @@ def span(name: str, block_on=None, **attrs):
                 jax.block_until_ready(block_on)
             except Exception:
                 pass
-        dur = time.perf_counter() - t0
+        t1 = time.perf_counter()
         st.pop()
-        led = _CURRENT
-        if led is not None:
-            payload = {"name": str(name), "path": path, "depth": depth,
-                       "dur_s": round(dur, 9)}
-            if attrs:
-                payload["attrs"] = attrs
-            if err is not None:
-                payload["error"] = err
-            _stamp_trace(payload)
-            led.append("span", payload)
+        _close_span(name, path, sid, parent, depth, t0, t1, attrs, err)
+
+
+# ---------------------------------------------------------------------------
+# compiles: one jax.monitoring listener -> spans + counters
+# ---------------------------------------------------------------------------
+
+_COMPILE_EVENTS = {
+    "/jax/core/compile/backend_compile_duration": "compile/backend",
+    "/jax/compilation_cache/cache_retrieval_time_sec":
+        "compile/cache_read",
+}
+_listening = False
+
+
+def _on_duration(event: str, secs: float, **_kw) -> None:
+    name = _COMPILE_EVENTS.get(event)
+    if name is None:
+        return
+    t1 = time.perf_counter()
+    if name == "compile/backend":
+        counter("compile_events_total").inc()
+        counter("compile_seconds_total").inc(float(secs))
+    else:
+        counter("compile_cache_reads_total").inc()
+    # a child of whatever span the compiling thread is in; ``step`` and
+    # ``chunk`` come down from the nearest span that carries them, so a
+    # compile after a run's first chunk shows with the step it hit
+    st = _stack()
+    attrs = {}
+    for key in ("step", "chunk"):
+        for _, _, a in reversed(st):
+            if key in a:
+                attrs[key] = a[key]
+                break
+    path = "/".join([e[0] for e in st] + [name])
+    _close_span(name, path, next(_SPAN_IDS), st[-1][1] if st else None,
+                len(st), t1 - float(secs), t1, attrs)
+
+
+def _listen_for_compiles(jax) -> None:
+    global _listening
+    if _listening:
+        return
+    _listening = True
+    import jax.monitoring
+
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+# a program has imported jax by now and gets the listener before its
+# first compile; an offline tool (tools/prof.py) has not, and this
+# module stays free of jax until a span opens
+if "jax" in sys.modules:
+    _listen_for_compiles(sys.modules["jax"])
+
+
+# ---------------------------------------------------------------------------
+# compiled programs the run loop has called (for obs/deviceprof's
+# read-back of their text; nothing here reads it)
+# ---------------------------------------------------------------------------
+
+_PROGRAMS: "deque[dict]" = deque(maxlen=64)
+
+
+def register_program(name: str, fn, args, **attrs) -> None:
+    """Keep what reading ``fn``'s compiled text again needs: the jitted
+    callable (the jit cache pins it anyway) and the abstract shapes of
+    its array arguments (python scalars as they are) — no device
+    buffer. ``obs.deviceprof.program_names`` lowers from these only
+    when someone asks (``tools/prof.py attribute``, a metric reader)."""
+    import jax
+
+    def abstract(x):
+        if not (hasattr(x, "shape") and hasattr(x, "dtype")):
+            return x
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype,
+            sharding=x.sharding if getattr(x, "committed", False)
+            else None,
+            weak_type=bool(getattr(x, "weak_type", False)))
+
+    _PROGRAMS.append({"name": str(name), "fn": fn,
+                      "args": jax.tree_util.tree_map(abstract, args),
+                      "t": time.perf_counter(), "attrs": attrs})
+
+
+def programs() -> list:
+    """The registered programs, in the order they were first called."""
+    return list(_PROGRAMS)
 
 
 # ---------------------------------------------------------------------------

@@ -12,8 +12,18 @@ each op's time to a span path, producing the per-span
 (``tools/obs.py summary --device``) and the ``prof_summary.json``
 artifact ``tools/prof.py diff`` gates perf drift on.
 
-Attribution is LAYERED, because the two backends annotate differently:
+Attribution is LAYERED, because the backends annotate differently:
 
+0. **phase** — this chip's trace names an operation by its HLO
+   instruction (``%fusion.3518``) and carries no scope at all. The
+   phase comes from the instruction's ``op_name`` in the compiled
+   program's text (:func:`names_from_hlo`; the program keeps what
+   reading that text again needs, ``obs.programs()``): the deepest
+   known phase (:data:`PHASES`, the ``jax.named_scope`` names inside
+   the step) on that path; data movement the compiler placed takes
+   the phase of the value it moves. Idle gaps between operations are
+   named by the program span (``obs.span`` enters ``TraceAnnotation``)
+   that covers most of each (:func:`attribute_planes`).
 1. **scope prefix** — TPU/GPU op events carry the framework op path
    (``tf_op``/``op_name`` args, e.g. ``jit(step)/interp/sin``) whose
    components are exactly the ``jax.named_scope`` names ``obs.span``
@@ -34,8 +44,10 @@ Anything left — no scope, no module — lands in an EXPLICIT
 summary schema (:func:`validate_summary`), so a parser bug that drops
 time fails the schema check instead of silently flattering a capture.
 
-Everything here is offline and host-side: stdlib only, no jax import,
-usable on a machine that never saw the accelerator.
+Everything here is offline and host-side: stdlib only, no jax import
+(but for :func:`program_names`, which lowers a live program, and
+:func:`load_planes` on an ``.xplane.pb``), usable on a machine that
+never saw the accelerator.
 """
 
 from __future__ import annotations
@@ -50,7 +62,33 @@ from typing import Dict, Iterable, List, Optional, Tuple
 
 PROF_SCHEMA = 1
 SUMMARY_NAME = "prof_summary.json"
-CENSUS_NAME = "census_counts.json"
+OP_NAMES_NAME = "op_names.json"
+
+# The phases of the compiled step, as sequences of ``jax.named_scope``
+# names (opened in integrators/ib.py, ops/interaction_packed.py and
+# solvers/spectral_plan.py). A phase matches an ``op_name`` when its
+# scopes appear on the path in order; the deepest match wins.
+PHASES = (("ib/prep",), ("ib/interp",), ("ib/refresh",),
+          ("ib/refresh", "repack"), ("ib/force",), ("ib/spread",),
+          ("fluid",), ("fluid", "transforms"))
+# host annotations that are the program's own spans (obs.span paths)
+PROGRAM_SPAN_RE = re.compile(r"(^|/)(driver|checkpoint|setup|compile)/")
+_DEVICE_PLANE_RE = re.compile(r"^/device:(TPU|GPU):\d+$")
+_OPS_LINE = "XLA Ops"
+# one instruction: name, opcode (lower case, where dtypes are followed
+# by ``[`` and layout tiles ``T(``/``S(`` start upper case), operands
+_INST_RE = re.compile(r'^\s*(?:ROOT )?%?([\w.\-]+) = .*?'
+                      r'\b([a-z][\w\-]*)\(([^)]*)\)')
+_OP_NAME_RE = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+_OPERAND_RE = re.compile(r'%([\w.\-]+)')
+# data movement the compiler places; control flow never inherits a phase
+_MOVE_OPCODES = frozenset((
+    "copy", "copy-start", "copy-done", "slice", "slice-start",
+    "slice-done", "reshape", "transpose", "bitcast", "broadcast", "pad",
+    "concatenate"))
+_NO_INHERIT = frozenset((
+    "while", "conditional", "call", "parameter", "tuple",
+    "get-tuple-element", "constant"))
 
 # trace-viewer process names that mark an accelerator timeline
 _DEVICE_PROC_RE = re.compile(r"/device:|^TPU|^GPU", re.IGNORECASE)
@@ -60,7 +98,7 @@ _DEVICE_PROC_RE = re.compile(r"/device:|^TPU|^GPU", re.IGNORECASE)
 _OP_LANE_RE = re.compile(r"XLA Ops|TensorFlow Ops", re.IGNORECASE)
 # args keys that can carry a slash-separated framework scope path
 _SCOPE_ARG_KEYS = ("tf_op", "op_name", "long_name", "name", "scope")
-# op-class buckets for the roofline join: FFT ops, contractions, and
+# op-class buckets: FFT ops, contractions, and
 # (PR 15) collectives.  A device op is comm when its HLO opcode is a
 # collective (sync or async -start/-done halves) OR its framework scope
 # path passes through a ``comm`` component — the named scope the
@@ -75,6 +113,9 @@ _COMM_OP_RE = re.compile(
     r"reduce-scatter|collective-broadcast)(-start|-done)?(\.|$)",
     re.IGNORECASE)
 _COMM_SCOPE = "comm"
+_FFT_PATH_RE = re.compile(r"\bi?r?fft\b", re.IGNORECASE)
+_DOT_PATH_RE = re.compile(r"(^|/)(dot_general|dot|convolution)\b",
+                          re.IGNORECASE)
 
 
 # ---------------------------------------------------------------------------
@@ -230,9 +271,17 @@ def span_leaf_map(span_paths: Iterable[str]) -> Dict[str, str]:
 
 
 def _resolve(event: dict, leaf_map: Dict[str, str],
-             module_map: Dict[str, str]):
-    """(key, via) for one op event — ``via`` in {"scope", "module",
-    "module-name"} — or (None, None) when nothing identifies it."""
+             module_map: Dict[str, str],
+             phases: Optional[Dict[str, str]] = None):
+    """(key, via) for one op event — ``via`` in {"phase", "scope",
+    "module", "module-name"} — or (None, None) when nothing identifies
+    it."""
+    if phases:
+        phase = phases.get(short_name(str(
+            (event.get("args") or {}).get("hlo_op")
+            or event.get("name") or "")))
+        if phase:
+            return phase, "phase"
     comps = _scope_components(event)
     for comp in reversed(comps):
         leaf = _norm_component(comp)
@@ -254,14 +303,15 @@ def _resolve(event: dict, leaf_map: Dict[str, str],
 def attribute_events(events: List[dict],
                      span_paths: Iterable[str] = (),
                      module_map: Optional[Dict[str, str]] = None,
-                     max_ops: int = 16) -> dict:
+                     max_ops: int = 16,
+                     phases: Optional[Dict[str, str]] = None) -> dict:
     """Attribute device-op events to span paths.
 
     Returns the core of a :data:`SUMMARY_NAME` document; every second
     of device-lane time lands either in ``spans`` (attributed — via
     scope prefix, module match, or module identity) or in the explicit
     ``unattributed`` breakdown. ``op_classes`` tallies
-    FFT/contraction/collective op time for the roofline join; the
+    FFT/contraction/collective op time; the
     classes partition ``total_device_s`` exactly (``other_s`` is the
     remainder), independent of the span accounting identity."""
     leaf_map = span_leaf_map(span_paths)
@@ -286,7 +336,7 @@ def attribute_events(events: List[dict],
             fft_s += dur
         elif _DOT_OP_RE.search(opname):
             dot_s += dur
-        key, via = _resolve(e, leaf_map, module_map)
+        key, via = _resolve(e, leaf_map, module_map, phases)
         if key is None:
             unattributed[opname] = unattributed.get(opname, 0.0) + dur
             continue
@@ -334,38 +384,372 @@ def spans_from_ledger(ledger_path: str) -> List[str]:
 def attribute_capture(capture_dir: str,
                       span_paths: Iterable[str] = (),
                       module_map: Optional[Dict[str, str]] = None,
-                      ledger: Optional[str] = None) -> dict:
+                      ledger: Optional[str] = None,
+                      names: Optional[Tuple[Dict[str, str],
+                                            Dict[str, str]]] = None,
+                      executions: Optional[int] = None) -> dict:
     """Parse + attribute every trace file in ``capture_dir`` into one
     :data:`SUMMARY_NAME` document. ``ledger`` (a ``ledger.jsonl`` path
-    or its directory) contributes its recorded span paths; the
-    ``census_counts.json`` sidecar, when present (bench writes it at
-    capture time), is joined into a roofline block."""
+    or its directory) contributes its recorded span paths.
+    ``names`` (``({instruction: op_name}, {instruction: phase})`` of
+    the compiled programs, :func:`programs_names`; default: the
+    capture's :data:`OP_NAMES_NAME` sidecar, which
+    ``utils.timers.profile_trace`` writes) turns on the phase layer,
+    and with an ``.xplane.pb`` in the capture the whole reduction then
+    goes through :func:`attribute_planes` (self times, idle gaps).
+    ``executions`` (step or chunk launches under the capture) lets
+    ``tools/prof.py diff`` compare per execution."""
     paths = list(span_paths)
     if ledger:
         if os.path.isdir(ledger):
             ledger = os.path.join(ledger, "ledger.jsonl")
         paths.extend(spans_from_ledger(ledger))
-    files = find_trace_files(capture_dir)
-    events: List[dict] = []
-    lanes: List[dict] = []
-    for f in files:
-        ev, ln = device_op_events(load_trace(f))
-        events.extend(ev)
-        lanes.extend(ln)
-    summary = attribute_events(events, paths, module_map)
+    op_names, phases = names or read_names(capture_dir) or (None, None)
+    xplanes = sorted(glob.glob(os.path.join(capture_dir, "**",
+                                            "*.xplane.pb"),
+                               recursive=True))
+    summary = None
+    if phases and xplanes:
+        try:
+            summary = attribute_planes(load_planes(xplanes[-1]), phases,
+                                       op_names)
+            files, lanes = xplanes[-1:], summary.pop("lanes")
+        except ValueError:
+            pass            # a CPU capture has no device plane
+    if summary is None:
+        files = find_trace_files(capture_dir)
+        events: List[dict] = []
+        lanes: List[dict] = []
+        for f in files:
+            ev, ln = device_op_events(load_trace(f))
+            events.extend(ev)
+            lanes.extend(ln)
+        summary = attribute_events(events, paths, module_map,
+                                   phases=phases)
     summary.update(schema=PROF_SCHEMA,
                    capture_dir=os.path.abspath(capture_dir),
                    trace_files=len(files), lanes=lanes,
-                   capture_bytes=capture_bytes(capture_dir))
-    census = read_census(capture_dir)
-    summary["census"] = census
-    if census:
-        from ibamr_tpu.obs.roofline import roofline_join
-
-        summary["roofline"] = roofline_join(summary, census)
-    else:
-        summary["roofline"] = None
+                   capture_bytes=capture_bytes(capture_dir),
+                   executions=executions)
     return summary
+
+
+# ---------------------------------------------------------------------------
+# phases: instruction -> op_name -> phase
+# ---------------------------------------------------------------------------
+
+def short_name(name: str) -> str:
+    """``%fusion.3518 = f32[...] fusion(...)`` -> ``fusion.3518``."""
+    return name.split(" = ")[0].lstrip("%")
+
+
+def phase_of(op_name: str, phases=PHASES) -> Optional[str]:
+    """The deepest known phase on an ``op_name`` path, or None."""
+    padded = "/" + op_name + "/"
+    best = None
+    for seq in phases:
+        pos = 0
+        for scope in seq:
+            pos = padded.find("/" + scope + "/", pos)
+            if pos < 0:
+                break
+            pos += len(scope) + 1
+        else:
+            if best is None or len(seq) > len(best):
+                best = seq
+    return "/".join(best) if best else None
+
+
+def phase_map(op_names: Dict[str, str], phases=PHASES) -> Dict[str, str]:
+    """``{instruction: phase}`` for the instructions whose own
+    ``op_name`` carries one."""
+    out = {}
+    for inst, op_name in op_names.items():
+        ph = phase_of(op_name, phases)
+        if ph:
+            out[inst] = ph
+    return out
+
+
+def names_from_hlo(text: str, phases=PHASES
+                   ) -> Tuple[Dict[str, str], Dict[str, str]]:
+    """``({instruction: op_name}, {instruction: phase})`` of a compiled
+    program's text. The phase is the one on the instruction's own
+    ``op_name``; data movement the compiler placed (an instruction
+    with no metadata, or a copy / reshape / transpose / slice ... whose
+    path names no phase: the chip's ``copy-done``, ``slice-done`` and
+    relayouts, 5% of the busy time at 128^3) takes the phase of the
+    value it moves: of its first operand that has one, else of its
+    first user that has one. Compute outside every scope (the marker
+    and midpoint updates, the health flag) and control flow inherit
+    nothing, and nothing is inherited across a loop's boundary."""
+    op_names: Dict[str, str] = {}
+    order: List[Tuple[str, str, List[str]]] = []
+    for ln in text.splitlines():
+        m = _INST_RE.match(ln)
+        if not m:
+            continue
+        inst, opcode, operands = m.groups()
+        meta = _OP_NAME_RE.search(ln, m.end())
+        if meta:
+            op_names.setdefault(inst, meta.group(1))
+        order.append((inst, opcode, _OPERAND_RE.findall(operands)))
+    out = phase_map(op_names, phases)
+    moves = [(inst, ops) for inst, opcode, ops in order
+             if inst not in out and opcode not in _NO_INHERIT
+             and (inst not in op_names or opcode in _MOVE_OPCODES)]
+    for inst, ops in moves:                 # from what it moves ...
+        for o in ops:
+            if o in out:
+                out[inst] = out[o]
+                break
+    first_user: Dict[str, str] = {}
+    for inst, _, ops in order:
+        for o in ops:
+            first_user.setdefault(o, inst)
+    for inst, _ in reversed(moves):         # ... or to where it goes
+        user = first_user.get(inst)
+        if inst not in out and user in out:
+            out[inst] = out[user]
+    return op_names, out
+
+
+def program_names(prog: dict) -> Tuple[Dict[str, str], Dict[str, str]]:
+    """:func:`names_from_hlo` of one program of ``obs.programs()``:
+    lowers it again from its abstract arguments and reads the compiled
+    text (the jit's own memo, or a persistent-cache read). Seconds of
+    host time at full size, minutes where it has to compile: only for
+    someone who asks.
+
+    This jax's cache key leaves op metadata out (``strip-debuginfo``),
+    so an executable compiled before a scope existed is served for a
+    program that now carries it (measured on the chip, PR 25: the
+    parent's 128^3 entry came with the machine). Where the compiled
+    text lacks a phase the lowering has, the program is compiled once
+    more, past the cache: under a key that takes the metadata in (a
+    miss), with nothing written back (the shared cache is small, and
+    an entry more evicts a program some run's set-up counts on), and
+    with an inert compiler option that gets past the lowering's memo of
+    its first executable. The instruction names of the two compiles
+    agree: the key pins the computation and the compiler."""
+    import jax
+
+    lowered = prog["fn"].lower(*prog["args"])
+    names = names_from_hlo(lowered.compile().as_text())
+    want = {phase_of(m) for m in re.findall(
+        r'loc\("([^"]*)"', lowered.as_text(debug_info=True))} - {None}
+    if want - set(names[1].values()):
+        past_cache = {
+            "jax_compilation_cache_include_metadata_in_key": True,
+            "jax_persistent_cache_min_compile_time_secs": float("inf")}
+        was = {k: getattr(jax.config, k) for k in past_cache}
+        for k, v in past_cache.items():
+            jax.config.update(k, v)
+        try:
+            names = names_from_hlo(lowered.compile(compiler_options={
+                "xla_dump_disable_metadata": False}).as_text())
+        finally:
+            for k, v in was.items():
+                jax.config.update(k, v)
+    return names
+
+
+def programs_names(progs: Optional[Iterable[dict]] = None
+                   ) -> Tuple[Dict[str, str], Dict[str, str]]:
+    """One ``({instruction: op_name}, {instruction: phase})`` over the
+    registered programs, in the order they were first called; where two
+    programs name one instruction the later one wins."""
+    if progs is None:
+        from ibamr_tpu.obs.bus import programs
+
+        progs = programs()
+    op_names: Dict[str, str] = {}
+    phases: Dict[str, str] = {}
+    for prog in progs:
+        names, ph = program_names(prog)
+        op_names.update(names)
+        phases.update(ph)
+    return op_names, phases
+
+
+def write_names(capture_dir: str, names=None) -> str:
+    """Land the :data:`OP_NAMES_NAME` sidecar beside a capture, so that
+    ``tools/prof.py attribute`` can name phases offline."""
+    op_names, phases = names or programs_names()
+    path = os.path.join(capture_dir, OP_NAMES_NAME)
+    with open(path, "w") as f:
+        json.dump({"op_names": op_names, "phases": phases}, f)
+    return path
+
+
+def read_names(capture_dir: str):
+    try:
+        with open(os.path.join(capture_dir, OP_NAMES_NAME)) as f:
+            data = json.load(f)
+        return data["op_names"], data["phases"]
+    except (OSError, ValueError, KeyError, TypeError):
+        return None
+
+
+# ---------------------------------------------------------------------------
+# the chip's trace: planes of (name, start, duration) events
+# ---------------------------------------------------------------------------
+
+def load_planes(path: str) -> dict:
+    """``{"planes": [{"name", "lines": [{"name", "events": [[name,
+    start_ns, dur_ns], ...]}]}]}`` from an ``.xplane.pb`` (through
+    ``jax.profiler.ProfileData``; of the host planes only the program's
+    spans are kept) or from such a dict saved as ``.json``."""
+    if path.endswith(".json"):
+        with open(path) as f:
+            return json.load(f)
+    from jax.profiler import ProfileData
+
+    planes = []
+    for plane in ProfileData.from_file(path).planes:
+        device = bool(_DEVICE_PLANE_RE.match(plane.name))
+        lines = []
+        for line in plane.lines:
+            evs = [[e.name, int(e.start_ns), int(e.duration_ns)]
+                   for e in line.events
+                   if device or PROGRAM_SPAN_RE.search(e.name)]
+            if evs:
+                lines.append({"name": line.name, "events": evs})
+        if lines:
+            planes.append({"name": plane.name, "lines": lines})
+    return {"planes": planes}
+
+
+def self_times(events) -> List[Tuple[str, int]]:
+    """``[(name, self_ns)]`` of one line's events: an event that starts
+    inside another is its child (a ``while`` spans its body's
+    operations) and its time comes off the parent's, so the self times
+    add up to the time in which anything ran."""
+    out, stack = [], []           # stack of [name, end, self]
+    for name, s, d in sorted(events, key=lambda e: (e[1], -e[2])):
+        e = s + d
+        while stack and s >= stack[-1][1]:
+            top = stack.pop()
+            out.append((top[0], top[2]))
+        if stack:
+            e = min(e, stack[-1][1])      # a child is cut to its parent
+            stack[-1][2] -= max(0, e - s)
+        stack.append([name, e, max(0, e - s)])
+    while stack:
+        top = stack.pop()
+        out.append((top[0], top[2]))
+    return out
+
+
+def attribute_planes(trace: dict, phases: Dict[str, str],
+                     op_names: Optional[Dict[str, str]] = None,
+                     span_re=PROGRAM_SPAN_RE, max_ops: int = 16) -> dict:
+    """Attribute the busiest device plane of a planes dict: each
+    operation's SELF time to its instruction's phase (``phases``,
+    :func:`phase_map`), the rest to the explicit ``unattributed``
+    breakdown, and each idle gap between operations to the host span
+    matching ``span_re`` that covers most of it (the innermost of
+    those that cover nearly as much). ``total_device_s`` is the time
+    in which an operation ran, so ``attributed_s + unattributed_s ==
+    total_device_s`` holds."""
+    devices = []
+    for p in trace["planes"]:
+        if not _DEVICE_PLANE_RE.match(p["name"]):
+            continue
+        ops = [ev for ln in p["lines"] if ln["name"] == _OPS_LINE
+               for ev in ln["events"]]
+        if ops:
+            selfs = self_times(ops)
+            devices.append((sum(ns for _, ns in selfs), p["name"], ops,
+                            selfs))
+    if not devices:
+        raise ValueError(f"no {_OPS_LINE!r} events on a device plane: "
+                         f"{[p['name'] for p in trace['planes']]}")
+    total_ns, plane, ops, selfs = max(devices, key=lambda d: d[0])
+    op_names = op_names or {}
+    spans: Dict[str, dict] = {}
+    unattributed: Dict[str, float] = {}
+    classes = {"fft_s": 0.0, "dot_s": 0.0, "comm_s": 0.0}
+    attributed = 0
+    for name, ns in selfs:
+        inst = short_name(name)
+        dur = ns / 1e9
+        op_name = op_names.get(inst, "")
+        # the class by the primitive path where the program's text gave
+        # one (the chip fuses a transform into ``fusion.N`` whose path
+        # ends ``jit(fft)``), by the instruction's own name otherwise
+        if _COMM_OP_RE.search(inst) or f"/{_COMM_SCOPE}/" in op_name:
+            classes["comm_s"] += dur
+        elif _FFT_PATH_RE.search(op_name) or _FFT_OP_RE.search(inst):
+            classes["fft_s"] += dur
+        elif _DOT_PATH_RE.search(op_name) or _DOT_OP_RE.search(inst):
+            classes["dot_s"] += dur
+        phase = phases.get(inst)
+        if phase is None:
+            unattributed[inst] = unattributed.get(inst, 0.0) + dur
+            continue
+        attributed += ns
+        node = spans.setdefault(phase, {"device_s": 0.0, "events": 0,
+                                        "via": {"phase": 0}, "ops": {}})
+        node["device_s"] += dur
+        node["events"] += 1
+        node["via"]["phase"] += 1
+        node["ops"][inst] = node["ops"].get(inst, 0.0) + dur
+    for node in spans.values():
+        node["device_s"] = round(node["device_s"], 9)
+        top = sorted(node["ops"].items(), key=lambda kv: -kv[1])
+        node["ops"] = {k: round(v, 9) for k, v in top[:max_ops]}
+    # idle gaps on that device, each under the host span covering most
+    host = sorted((ev for p in trace["planes"]
+                   if not _DEVICE_PLANE_RE.match(p["name"])
+                   for ln in p["lines"] for ev in ln["events"]
+                   if span_re.search(ev[0])), key=lambda ev: ev[1])
+    merged: List[List[int]] = []
+    for s0, e0 in sorted((s0, s0 + d) for _, s0, d in ops):
+        if merged and s0 <= merged[-1][1]:
+            merged[-1][1] = max(merged[-1][1], e0)
+        else:
+            merged.append([s0, e0])
+    gaps: Dict[str, float] = {}
+    live: List[list] = []         # host spans that can still cover a gap
+    nxt = 0
+    for (_, e0), (s1, _) in zip(merged[:-1], merged[1:]):
+        while nxt < len(host) and host[nxt][1] < s1:
+            live.append(host[nxt])
+            nxt += 1
+        live = [h for h in live if h[1] + h[2] > e0]
+        # the span that covers most of the gap; spans nest, and a parent
+        # covers whatever its child does, so among those that cover
+        # nearly all of it the innermost (shortest) one names it
+        cover = [(min(s1, hs + hd) - max(e0, hs), hd, nm)
+                 for nm, hs, hd in live]
+        cover = [c for c in cover if c[0] > 0]
+        best = "unattributed"
+        if cover:
+            most = max(c[0] for c in cover)
+            best = min(c[1:] for c in cover if c[0] >= 0.9 * most)[1]
+        gaps[best] = gaps.get(best, 0.0) + (s1 - e0) / 1e9
+    total = total_ns / 1e9
+    named = classes["fft_s"] + classes["dot_s"] + classes["comm_s"]
+    return {
+        "total_device_s": round(total, 9),
+        "attributed_s": round(attributed / 1e9, 9),
+        "unattributed_s": round((total_ns - attributed) / 1e9, 9),
+        "fraction_attributed": round(attributed / total_ns, 6)
+        if total_ns else 1.0,
+        "spans": spans,
+        "unattributed": {
+            k: round(v, 9)
+            for k, v in sorted(unattributed.items(),
+                               key=lambda kv: -kv[1])[:max_ops]},
+        "op_classes": {**{k: round(v, 9) for k, v in classes.items()},
+                       "other_s": round(total - named, 9)},
+        "window_s": round((merged[-1][1] - merged[0][0]) / 1e9, 9),
+        "idle_gaps": {k: round(v, 9) for k, v in
+                      sorted(gaps.items(), key=lambda kv: -kv[1])},
+        "lanes": [{"process": plane, "thread": _OPS_LINE,
+                   "events": len(ops), "busy_s": round(total, 9)}],
+    }
 
 
 # ---------------------------------------------------------------------------
@@ -394,18 +778,6 @@ def write_summary(capture_dir: str, summary: dict) -> str:
 def read_summary(path: str) -> dict:
     with open(summary_path(path)) as f:
         return json.load(f)
-
-
-def read_census(capture_dir_or_path: str) -> Optional[dict]:
-    path = capture_dir_or_path
-    if os.path.isdir(path):
-        path = os.path.join(path, CENSUS_NAME)
-    try:
-        with open(path) as f:
-            data = json.load(f)
-        return data if isinstance(data, dict) else None
-    except (OSError, ValueError):
-        return None
 
 
 def _num(v) -> bool:
@@ -474,9 +846,7 @@ def compact_summary(summary: dict) -> dict:
                   for k, v in (summary.get("spans") or {}).items()},
         "unattributed": summary.get("unattributed") or {},
         "op_classes": summary.get("op_classes"),
-        "census": {k: v for k, v in (summary.get("census") or {}).items()
-                   if k in ("label", "n", "executions")} or None,
-        "roofline": summary.get("roofline"),
+        "executions": summary.get("executions"),
     }
 
 
@@ -493,7 +863,7 @@ _RAW_SUFFIXES = (".trace.json.gz", ".trace.json", ".xplane.pb",
 def prune_raw_traces(capture_dir: str) -> int:
     """Delete the raw multi-MB profiler outputs under ``capture_dir``
     (the ``plugins/profile`` tree), keeping the compact
-    ``prof_summary.json`` / ``census_counts.json``. Returns bytes
+    ``prof_summary.json`` / ``op_names.json``. Returns bytes
     freed. Callers MUST validate the summary first — ``tools/prof.py
     archive`` refuses to prune when :func:`validate_summary` fails."""
     freed = 0

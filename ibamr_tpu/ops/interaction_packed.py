@@ -187,6 +187,7 @@ def default_overflow_cap(N: int) -> int:
         math.log2(max(N // 8, 1))))))
 
 
+@jax.named_scope("pack")
 def pack_markers(geom: BucketGeometry, grid: StaggeredGrid,
                  X: jnp.ndarray, weights: Optional[jnp.ndarray] = None,
                  nchunks: int = 1024,
@@ -288,11 +289,14 @@ def refresh_packed(geom: BucketGeometry, grid: StaggeredGrid,
     Xb = jnp.take(X, inv[:-1], axis=0, mode="fill",
                   fill_value=0).reshape(Q, c, dim)
 
-    return jax.lax.cond(
-        hit,
-        lambda: b._replace(Xb=Xb),
-        lambda: pack_markers(geom, grid, X, weights, nchunks=Q,
-                             overflow_cap=ocap)), hit
+    def repack():
+        # scope inside the FALSE branch only: device time under
+        # ``repack`` is time spent falling back (0 = always hit)
+        with jax.named_scope("repack"):
+            return pack_markers(geom, grid, X, weights, nchunks=Q,
+                                overflow_cap=ocap)
+
+    return jax.lax.cond(hit, lambda: b._replace(Xb=Xb), repack), hit
 
 
 def _spread_raw(geom: BucketGeometry, grid: StaggeredGrid,
@@ -309,8 +313,9 @@ def _spread_raw(geom: BucketGeometry, grid: StaggeredGrid,
     B = int(np.prod(geom.nblk))
     T = jax.ops.segment_sum(Tq, b.tile_of_chunk, num_segments=B,
                             indices_are_sorted=True)
-    out = _overlap_add(geom, grid, T.reshape(
-        (B,) + tuple(geom.width) + (grid.n[grid.dim - 1],)))
+    with jax.named_scope("overlap_add"):
+        out = _overlap_add(geom, grid, T.reshape(
+            (B,) + tuple(geom.width) + (grid.n[grid.dim - 1],)))
     return spread_overflow_fallbacks(out, b, F, X, grid, centering,
                                      kernel)
 

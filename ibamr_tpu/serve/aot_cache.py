@@ -487,9 +487,8 @@ def clear_executable_cache() -> None:
 def step_callable(integ, *, donate: bool = True,
                   with_stats: bool = False):
     """The exact python callable + donate_argnums the cache lowers for
-    an integrator step. The bench census traces THIS callable (a
-    ``jax.stages.Compiled`` cannot be re-traced), so the roofline
-    sidecar always describes the same graph the cache serves."""
+    an integrator step (a ``jax.stages.Compiled`` cannot be
+    re-traced, so whoever needs the graph again traces THIS)."""
     base = integ.step_with_stats if with_stats else integ.step
     return base, ((0,) if donate else ())
 

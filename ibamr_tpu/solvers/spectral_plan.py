@@ -454,14 +454,18 @@ def _substep_raw(plan: "SpectralPlan", sdtype_name: str, rhs: Vel,
     if sdtype is not None:
         # bf16 transform operands, f32 twiddle/accumulation
         x = _round_real(x.astype(jnp.float32), sdtype)
-    uh = rfftn(x, plan.shape, axes=plan.axes)
+    # ``transforms``: the transform calls and nothing else, whatever
+    # implements them (obs/deviceprof reads fluid.transform_ms by it)
+    with jax.named_scope("transforms"):
+        uh = rfftn(x, plan.shape, axes=plan.axes)
     outh = plan.kspace_algebra(uh, alpha, beta, (a, b),
                                f32=sdtype is not None,
                                filter_sym=filter_sym)
     if sdtype is not None:
         # split-real compression of the inverse-transform operand
         outh = _round_complex(outh, sdtype)
-    out = irfftn(outh, s=plan.shape, axes=plan.axes)
+    with jax.named_scope("transforms"):
+        out = irfftn(outh, s=plan.shape, axes=plan.axes)
     out = out.astype(plan.rdtype)
     return tuple(out[d] for d in range(plan.dim)), out[plan.dim]
 
@@ -493,13 +497,15 @@ def _substep_bwd(plan, sdtype_name, res, ct):
         # mirror the primal's operand compression on the cotangents so
         # the transposed transforms see the same storage precision
         c = _round_real(c, sdtype)
-    ch = rfftn(c, plan.shape, axes=plan.axes)
+    with jax.named_scope("transforms"):
+        ch = rfftn(c, plan.shape, axes=plan.axes)
     gh = plan.kspace_algebra_adjoint(ch, alpha, beta, (a, b),
                                      f32=sdtype is not None,
                                      filter_sym=filter_sym)
     if sdtype is not None:
         gh = _round_complex(gh, sdtype)
-    g = irfftn(gh, s=plan.shape, axes=plan.axes)
+    with jax.named_scope("transforms"):
+        g = irfftn(gh, s=plan.shape, axes=plan.axes)
     g = g.astype(plan.rdtype)
     rhs_ct = tuple(g[d] for d in range(plan.dim))
     # alpha/beta/pinc are treated as constants (see

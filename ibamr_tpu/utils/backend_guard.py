@@ -43,7 +43,12 @@ def auto_backend():
 
     import jax
 
-    platform = jax.devices()[0].platform
+    from ibamr_tpu import obs
+
+    # the first jax.devices() is the process reaching the chip: 9-22 s
+    # of every set-up on the benchmark's machines
+    with obs.span("setup/backend_init"):
+        platform = jax.devices()[0].platform
     if platform != "tpu":
         raise RuntimeError(
             f"no TPU: jax found platform {platform!r}. Set "

@@ -39,6 +39,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ibamr_tpu import obs as _obs
+
 
 def _esc(s: str) -> str:
     # escape the path separator so dict keys containing '/' cannot collide
@@ -273,9 +275,13 @@ def save_checkpoint(directory: str, state: Any, step: int,
     ``lanes`` (fleet runs) records per-lane leaf CRCs in the sidecar so
     :func:`restore_lane` can salvage healthy lanes from a step whose
     file is damaged elsewhere."""
-    return _write_arrays(directory, _gather_arrays(state),
-                         state_schema(state), step, metadata, keep,
-                         lanes=lanes)
+    # device->host copy and file write, apart (spans in obs's ring and
+    # on a profiler capture's timeline)
+    with _obs.span("checkpoint/fetch", step=step):
+        arrays = _gather_arrays(state)
+    with _obs.span("checkpoint/commit", step=step):
+        return _write_arrays(directory, arrays, state_schema(state),
+                             step, metadata, keep, lanes=lanes)
 
 
 def _all_steps(directory: str) -> list:
@@ -410,7 +416,6 @@ class AsyncCheckpointWriter:
         # attempts.
         import time as _time
 
-        from ibamr_tpu import obs as _obs
         t0 = _time.perf_counter()
         try:
             try:
@@ -429,7 +434,6 @@ class AsyncCheckpointWriter:
         """Gather and enqueue one checkpoint write. Returns the write
         future, or ``None`` when the save was shed under
         ``overflow="drop"`` backlog."""
-        from ibamr_tpu import obs as _obs
         self._raise_finished()
         if self.queue_depth() >= self.max_pending:
             if self.overflow == "drop":
@@ -492,6 +496,11 @@ def restore_checkpoint(directory: str, template: Any,
 
     Returns (state, step, metadata).
     """
+    with _obs.span("checkpoint/restore", step=step):
+        return _restore_checkpoint(directory, template, step, sharding_fn)
+
+
+def _restore_checkpoint(directory, template, step, sharding_fn):
     if step is not None:
         fname = os.path.join(directory, f"restore.{step:08d}.npz")
         if not os.path.exists(fname):

@@ -21,6 +21,7 @@ the restart chain.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Any, Callable, Optional
@@ -309,6 +310,7 @@ class HierarchyDriver:
         # benign re-trace of a known signature leaves unchanged.
         self.trace_counts = {}
         self._trace_sigs = {}
+        self._called = set()        # chunk lengths called so far
         # ---- fleet (lane-batched) mode -------------------------------
         # lanes=B runs B independent scenarios through ONE vmapped
         # chunk: state leaves carry a leading lane axis, dt becomes a
@@ -543,6 +545,7 @@ class HierarchyDriver:
         cadences = [i for i in (cfg.viz_dump_interval,
                                 cfg.restart_interval,
                                 cfg.regrid_interval) if i]
+        ordinal = 0                     # chunk ordinal within this run
         while step < cfg.num_steps:
             if cfg.cfl is not None:
                 # float() keeps dt a weak-typed Python scalar whichever
@@ -571,21 +574,34 @@ class HierarchyDriver:
                                        cfg=cfg, alive=snap_alive)
             t0 = time.perf_counter()
             # the chunk span brackets dispatch AND the one-per-chunk
-            # host sync below; with a run ledger attached it closes
-            # into the ledger (kind "span"), else it costs two clock
-            # reads. Telemetry never reaches inside the jitted chunk —
-            # the *_telemetry graph contracts pin zero in-scan host
-            # transfers with the bus armed.
-            with _obs.span("driver/chunk", step=step, length=n):
-                if self.timer is not None:
-                    with self.timer.scope(self.timer_name):
-                        state, health = self._chunk(n)(state,
-                                                       *chunk_args)
-                        # one device sync per chunk (inside the scope):
-                        # the finite bool or the fused vitals vector
-                        health = np.asarray(health)
-                else:
-                    state, health = self._chunk(n)(state, *chunk_args)
+            # host sync; its children split the two. Every span closes
+            # into obs's ring (and the run ledger when one is attached)
+            # and sits on a profiler capture's timeline. Telemetry never
+            # reaches inside the jitted chunk — the *_telemetry graph
+            # contracts pin zero in-scan host transfers with the bus
+            # armed. The TimerManager scope (itself an obs.span) wraps
+            # the same interval from outside.
+            first_call = n not in self._called
+            timed = (self.timer.scope(self.timer_name)
+                     if self.timer is not None
+                     else contextlib.nullcontext())
+            with timed, _obs.span("driver/chunk", step=step, length=n,
+                                  chunk=ordinal):
+                fn = self._chunk(n)
+                if first_call:
+                    # what obs/deviceprof needs to read this program's
+                    # compiled text after the run: shapes, no buffers
+                    # (taken before a donating call deletes them)
+                    self._called.add(n)
+                    _obs.register_program(f"driver/chunk[{n}]",
+                                          self._chunks[n],
+                                          (state,) + chunk_args, steps=n)
+                with _obs.span("dispatch", step=step, chunk=ordinal,
+                               first_call=first_call):
+                    state, health = fn(state, *chunk_args)
+                with _obs.span("sync", step=step, chunk=ordinal):
+                    # one device sync per chunk: the finite bool or the
+                    # fused vitals vector
                     health = np.asarray(health)
             self.last_chunk_wall_s = time.perf_counter() - t0
             _CHUNKS_TOTAL.inc()
@@ -622,20 +638,30 @@ class HierarchyDriver:
             step += n
 
             if self.metrics_fn is not None:
-                rec = self.metrics_fn(state, step)
+                with _obs.span("driver/metrics_fn", step=step,
+                               chunk=ordinal):
+                    rec = self.metrics_fn(state, step)
                 if rec:
                     self.history.append(rec)
             if (cfg.viz_dump_interval and self.viz_fn is not None
                     and step % cfg.viz_dump_interval == 0):
-                self.viz_fn(state, step)
+                with _obs.span("driver/viz_fn", step=step, chunk=ordinal):
+                    self.viz_fn(state, step)
             if (cfg.restart_interval and self.checkpoint_fn is not None
                     and step % cfg.restart_interval == 0):
-                self.checkpoint_fn(state, step)
+                with _obs.span("driver/checkpoint_fn", step=step,
+                               chunk=ordinal):
+                    self.checkpoint_fn(state, step)
             if (cfg.regrid_interval and self.regrid_fn is not None
                     and step % cfg.regrid_interval == 0):
-                state = self.regrid_fn(state, step)
+                with _obs.span("driver/regrid_fn", step=step,
+                               chunk=ordinal):
+                    state = self.regrid_fn(state, step)
+            ordinal += 1
         # always visualize the final configuration, aligned or not
         if (cfg.viz_dump_interval and self.viz_fn is not None
                 and step % cfg.viz_dump_interval != 0):
-            self.viz_fn(state, step)
+            with _obs.span("driver/viz_fn", step=step,
+                           chunk=max(ordinal - 1, 0)):
+                self.viz_fn(state, step)
         return state

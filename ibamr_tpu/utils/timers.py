@@ -120,13 +120,23 @@ def profile_trace(log_dir: Optional[str], stage: Optional[str] = None):
     ``profile`` ledger record lands when the trace closes — so
     ``tools/obs.py tail`` shows a profile landing live, and the ledger
     names the capture dir ``tools/prof.py attribute`` should be
-    pointed at. Telemetry-off runs pay only the span's no-op path."""
+    pointed at. Telemetry-off runs pay only the span's no-op path.
+
+    When the capture closes cleanly and the run loop has called chunk
+    programs (``obs.programs()``), their instruction -> ``op_name`` and
+    instruction -> phase maps land beside the capture
+    (``op_names.json``): the chip's trace
+    names operations ``%fusion.N`` only, and ``tools/prof.py
+    attribute`` names the step's phases from this map, offline. That
+    reads each program's compiled text once more (``deviceprof
+    .program_names``): the price of having asked for a profile."""
     if not log_dir:
         yield
         return
     import jax.profiler as _prof
 
     from ibamr_tpu import obs
+    from ibamr_tpu.obs import deviceprof
 
     with obs.span("profile_trace", capture_dir=str(log_dir),
                   stage=stage):
@@ -136,3 +146,5 @@ def profile_trace(log_dir: Optional[str], stage: Optional[str] = None):
         finally:
             _prof.stop_trace()
             obs.emit("profile", capture_dir=str(log_dir), stage=stage)
+        if obs.programs():
+            deviceprof.write_names(log_dir)

@@ -1,0 +1,8 @@
+"""driver layer: wall time inside the window under the program's
+``driver/chunk/dispatch`` spans (the call of the chunk program), per step.
+Source: program_span.  Moves: step_ms."""
+from perfbench.obsread import span_ms_per_step
+
+
+def read(ctx):
+    return span_ms_per_step(ctx, "driver/chunk/dispatch")

@@ -1,0 +1,9 @@
+"""driver layer: wall time inside the window under the program's
+``driver/chunk/sync`` spans (the one host sync per chunk:
+the host's wait for the device), per step.
+Source: program_span.  Moves: step_ms."""
+from perfbench.obsread import span_ms_per_step
+
+
+def read(ctx):
+    return span_ms_per_step(ctx, "driver/chunk/sync")

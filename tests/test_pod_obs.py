@@ -1,7 +1,7 @@
 """Pod-scope observability tests (PR 15): the collective/overlap
 censuses on tiny hand-built shard_map programs and synthetic HLO, the
-``comm_s`` device-op class with its accounting invariants, the comm
-roofline join, per-process ledger shards, and the merge machinery —
+``comm_s`` device-op class with its accounting invariants,
+per-process ledger shards, and the merge machinery —
 deterministic (seq, proc) interleave, torn-tail tolerance, same-run
 checking, and the no-double-counted-counters fleet rollup.
 
@@ -28,7 +28,6 @@ from ibamr_tpu.analysis.graph_census import (collective_census,
 from ibamr_tpu.obs import deviceprof
 from ibamr_tpu.obs.merge import (find_shards, fleet_counters,
                                  fleet_prometheus_text, merge_ledgers)
-from ibamr_tpu.obs.roofline import census_sidecar, roofline_join
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -242,45 +241,6 @@ def test_real_sharded_capture_reports_comm_class(tmp_path):
     assert "comm_s" in s["op_classes"]
     assert s["op_classes"]["comm_s"] >= 0.0
     assert deviceprof.validate_summary(s) == []
-
-
-# ---------------------------------------------------------------------------
-# roofline: the comm join
-# ---------------------------------------------------------------------------
-
-def test_roofline_comm_join_subtracts_pbroadcast():
-    summary = {"total_device_s": 0.004,
-               "op_classes": {"fft_s": 0.0, "dot_s": 0.0,
-                              "comm_s": 0.001, "other_s": 0.003}}
-    census = {"executions": 2, "collective_bytes": 2_000_000,
-              "pbroadcast_bytes": 500_000, "collective_prims": 10}
-    r = roofline_join(summary, census)
-    assert r["comm"]["bytes_per_execution"] == 1_500_000
-    assert r["comm"]["device_s_per_execution"] == pytest.approx(5e-4)
-    assert r["comm"]["achieved_gb_per_s"] == pytest.approx(3.0)
-    assert r["comm"]["collective_prims"] == 10
-    assert r["fraction_of_step_accounted"] == pytest.approx(0.25)
-
-
-def test_roofline_comm_absent_without_comm_time():
-    r = roofline_join(
-        {"total_device_s": 0.004,
-         "op_classes": {"fft_s": 0.0, "dot_s": 0.0, "comm_s": 0.0}},
-        {"executions": 2, "collective_bytes": 1000,
-         "pbroadcast_bytes": 0})
-    assert r["comm"] is None
-
-
-def test_census_sidecar_includes_collective_counts():
-    mesh = _mesh1d()
-    perm = [(i, (i + 1) % 8) for i in range(8)]
-    f = shard_map(lambda x: jax.lax.ppermute(x, "x", perm=perm),
-                  mesh, in_specs=P("x"), out_specs=P("x"),
-                  check_rep=False)
-    side = census_sidecar(f, (jnp.zeros((64, 4), jnp.float32),),
-                          label="halo", executions=4)
-    assert side["ppermute_prims"] == 1
-    assert side["collective_bytes"] == side["ppermute_bytes"] > 0
 
 
 # ---------------------------------------------------------------------------

@@ -1,12 +1,15 @@
-"""Device-profile attribution, roofline, and drift gate (PR 10).
+"""Device-profile attribution and drift gate (PR 10).
 
 The operator's side of ``ibamr_tpu/obs/deviceprof.py``:
 
-- ``attribute``: parse the trace-viewer JSON inside one
-  ``jax.profiler`` capture dir, attribute device-lane op time to span
-  paths (joining a run ledger's recorded spans when given), and land
+- ``attribute``: parse one ``jax.profiler`` capture dir, attribute
+  device-lane op time to the step's phases (a chip capture with its
+  ``op_names.json`` sidecar, which ``utils.timers.profile_trace``
+  writes: ``%fusion.N`` -> ``op_name`` -> ``ib/prep`` ... ``fluid``;
+  idle gaps to the program span covering each) or to span paths
+  (joining a run ledger's recorded spans when given), and land
   ``prof_summary.json`` next to the capture.
-- ``show``: render a summary (span table, residual, roofline) without
+- ``show``: render a summary (span table, residual, idle gaps) without
   re-parsing the multi-MB trace.
 - ``check``: validate a ``prof_summary.json`` against the schema —
   exit 2 on malformation, so automation archives
@@ -47,7 +50,6 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 from ibamr_tpu.obs import deviceprof  # noqa: E402
-from ibamr_tpu.obs.roofline import render_roofline  # noqa: E402
 
 # drift bands (mirroring graph_audit's clean/improved/regressed): a
 # span drifts only when BOTH the relative band and the absolute floor
@@ -110,8 +112,12 @@ def render_summary(summary: dict) -> list:
         lines.append(f"  {name:<{width}} {_fmt_s(unatt[name]):>10}")
     if not unatt:
         lines.append("  (none)")
-    lines.append("roofline:")
-    lines.extend(render_roofline(summary.get("roofline")))
+    gaps = summary.get("idle_gaps")
+    if gaps is not None:
+        lines.append(f"idle gaps by program span (window "
+                     f"{_fmt_s(summary.get('window_s'))}):")
+        for name in sorted(gaps, key=lambda k: -gaps[k]):
+            lines.append(f"  {name:<{width}} {_fmt_s(gaps[name]):>10}")
     return lines
 
 
@@ -120,7 +126,8 @@ def cmd_attribute(args) -> int:
         args.capture_dir,
         span_paths=args.span or (),
         module_map=_parse_module_map(args.module_map),
-        ledger=args.ledger or None)
+        ledger=args.ledger or None,
+        executions=args.executions)
     probs = deviceprof.validate_summary(summary)
     if probs:
         for p in probs:
@@ -219,28 +226,25 @@ def load_summaries(path: str) -> dict:
     a bench JSON with embedded ``profiles[*].summary`` entries."""
     if os.path.isdir(path) or path.endswith(deviceprof.SUMMARY_NAME):
         s = deviceprof.read_summary(path)
-        label = ((s.get("census") or {}).get("label")
-                 or os.path.basename(os.path.normpath(
-                     s.get("capture_dir") or path)))
+        label = os.path.basename(os.path.normpath(
+            s.get("capture_dir") or path))
         return {label: s}
     data = _bench_payload(path)
     if data.get("schema") == deviceprof.PROF_SCHEMA \
             and "total_device_s" in data:
-        return {(data.get("census") or {}).get("label") or path: data}
+        return {path: data}
     out = {}
     for entry in data.get("profiles") or []:
         if isinstance(entry, dict) and isinstance(entry.get("summary"),
                                                   dict):
-            out[entry.get("stage")
-                or (entry.get("summary").get("census") or {}).get("label")
-                or entry.get("dir", "?")] = entry["summary"]
+            out[entry.get("stage") or entry.get("dir", "?")] = \
+                entry["summary"]
     return out
 
 
 def _per_exec(summary: dict, seconds: float) -> float:
-    execs = ((summary.get("roofline") or {}).get("executions")
-             or (summary.get("census") or {}).get("executions") or 0)
-    return seconds / execs if execs and execs > 0 else seconds
+    execs = summary.get("executions") or 0
+    return seconds / execs if execs > 0 else seconds
 
 
 def _cpu_capture(summary: dict) -> bool:
@@ -416,7 +420,7 @@ def cmd_archive(args) -> int:
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
-        description="device-profile attribution / roofline / drift gate")
+        description="device-profile attribution / drift gate")
     sub = ap.add_subparsers(dest="cmd", required=True)
 
     a = sub.add_parser("attribute", help="parse a capture dir into "
@@ -430,6 +434,9 @@ def main(argv=None) -> int:
                         "(repeatable)")
     a.add_argument("--module-map", default="",
                    help="hlo_module=span/path overrides, comma-sep")
+    a.add_argument("--executions", type=int, default=None,
+                   help="step/chunk launches under the capture; "
+                        "`diff` then compares per execution")
     a.add_argument("--json", action="store_true",
                    help="print the compact summary as JSON")
     a.set_defaults(fn=cmd_attribute)
