@@ -45,7 +45,10 @@ def main(argv):
           f"devices={len(jax.devices())} engine={integ.ib.engine_name} "
           f"spectral_dtype={integ.ins.spectral_dtype or 'f32'}", flush=True)
 
-    # shard over all devices when more than one is visible
+    # shard over all devices when more than one is visible; on one
+    # device the driver takes the integrator itself (step_fn=None), so
+    # it can carry the packed marker layout through each chunk
+    step_fn = None
     if len(jax.devices()) > 1:
         from ibamr_tpu.parallel import make_mesh, make_sharded_ib_step
         from ibamr_tpu.parallel.mesh import place_state
@@ -54,8 +57,6 @@ def main(argv):
         state = place_state(state, integ.ins.grid, mesh)
         step_fn = make_sharded_ib_step(integ, mesh)
         print(f"sharding over mesh {dict(mesh.shape)}")
-    else:
-        step_fn = jax.jit(lambda s, d: integ.step(s, d))
 
     start_step = 0
     if len(argv) > 3:

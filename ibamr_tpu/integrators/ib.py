@@ -230,20 +230,74 @@ class IBExplicitIntegrator:
         """``step`` plus a per-step stats dict: ``refresh_hit`` is a
         traced bool when the transfer engine took the slot-preserving
         half-step refresh path (False = the drift bound forced a full
-        re-pack), or None when the engine has no refresh. The stats
-        ride beside the state — the IBState pytree is unchanged, so
+        re-pack), or None when the engine has no refresh. The stats are
+        a second return value — the IBState pytree is unchanged, so
         checkpoints, sharding specs and lax.scan carriers are
-        untouched."""
+        untouched. One bucket prep per step: the context at X_n is
+        built from scratch (``step_carried`` is the form that keeps
+        it)."""
+        # strategies may expose a per-position transfer context (marker
+        # buckets for the MXU path) shared across calls at the same X
+        with jax.named_scope("ib/prep"):
+            ctx_n = self._prepare(state.X, state.mask)
+        new_state, _, refresh_hit = self._advance(state, dt, ctx_n)
+        return new_state, {"refresh_hit": refresh_hit}
+
+    # -- the carried form: the transfer context outlives the step ------------
+    def init_carry(self, state: IBState):
+        """The transfer context a chunk of steps carries through its
+        scan: one bucket prep at ``state.X`` (the ``ib/prep`` scope),
+        or None where nothing can be carried — the strategy has no
+        ``prepare``/``refresh``, or its engine answers a refresh with
+        no context (``scatter``, ``mxu*``, ``packed3*``, ``pallas``)."""
+        refresh = getattr(self.ib, "refresh", None)
+        if refresh is None:
+            return None
+        # asked abstractly, so an engine without a refresh path leaves
+        # no dead bucket prep in the traced program
+        ctx, _ = jax.eval_shape(
+            lambda X, m: refresh(self._prepare(X, m), X, m),
+            state.X, state.mask)
+        if ctx is None:
+            return None
+        with jax.named_scope("ib/prep"):
+            return self._prepare(state.X, state.mask)
+
+    def step_carried(self, state: IBState, ctx, dt: float):
+        """``step`` with the transfer context carried in and out:
+        ``(state, ctx, stats)``. The context at X_n is a refresh of
+        ``ctx`` (whatever positions it was last gathered at) instead of
+        a bucket prep; the context returned is the one the step ended
+        with, re-packed where a drift bound fell, so a fall is paid
+        once and not by every later step. ``stats`` counts the
+        ``refreshes`` of the step and how many of them fell back to a
+        full re-pack (``falls``). With ``ctx=None`` (see
+        :meth:`init_carry`) this is ``step``. A caller threads ``ctx``
+        through ``lax.scan`` beside the state
+        (``HierarchyDriver._chunk``); ``vmap``, ``grad`` and the sharded
+        step keep ``step``, whose one prep a step needs no ``cond``."""
+        if ctx is None:
+            return self.step(state, dt), None, {"refreshes": 0, "falls": 0}
+        with jax.named_scope("ib/refresh"):
+            ctx_n, hit_n = self.ib.refresh(ctx, state.X, state.mask)
+        new_state, ctx_out, hit_h = self._advance(state, dt, ctx_n)
+        hits = [h for h in (hit_n, hit_h) if h is not None]
+        falls = sum(jnp.logical_not(h).astype(jnp.int32) for h in hits)
+        return new_state, ctx_out, {"refreshes": len(hits), "falls": falls}
+
+    def _prepare(self, X, mask):
+        prep = getattr(self.ib, "prepare", None)
+        return prep(X, mask) if prep is not None else None
+
+    def _advance(self, state: IBState, dt: float, ctx_n):
+        """One step given the transfer context at X_n. Returns the new
+        state, the context the step ended with (at X_half for the
+        midpoint scheme) and the half-step ``refresh_hit`` (None where
+        no refresh ran)."""
         grid = self.ins.grid
         ib = self.ib
         u_n = state.ins.u
         X_n = state.X
-        # strategies may expose a per-position transfer context (marker
-        # buckets for the MXU path) shared across calls at the same X
-        prep = getattr(ib, "prepare", None)
-
-        def ctx_at(X):
-            return prep(X, state.mask) if prep is not None else None
 
         # Phase names (jax.named_scope: metadata only, no op added): the
         # compiled step says which phase owns each instruction, and
@@ -252,8 +306,6 @@ class IBExplicitIntegrator:
         scope = jax.named_scope
 
         # structure prediction to the half step
-        with scope("ib/prep"):
-            ctx_n = ctx_at(X_n)
         with scope("ib/interp"):
             U_n = ib.interpolate_velocity(u_n, grid, X_n, state.mask,
                                           ctx=ctx_n)
@@ -261,8 +313,8 @@ class IBExplicitIntegrator:
         if self.scheme == "midpoint":
             X_half = X_n + 0.5 * dt * U_n
             # half-step context: slot-preserving refresh of ctx_n when
-            # the strategy supports it (one bucket_prep per step — the
-            # round-5 measured 14.6 ms x2 tax), full re-prepare
+            # the strategy supports it (the round-5 measured 14.6 ms
+            # bucket_prep tax is not paid twice), full re-prepare
             # otherwise
             refresh = getattr(ib, "refresh", None)
             ctx_h = None
@@ -272,7 +324,7 @@ class IBExplicitIntegrator:
                                                  state.mask)
             if ctx_h is None:
                 with scope("ib/prep"):
-                    ctx_h = ctx_at(X_half)
+                    ctx_h = self._prepare(X_half, state.mask)
         else:
             X_half = X_n
             ctx_h = ctx_n
@@ -302,7 +354,7 @@ class IBExplicitIntegrator:
             U_out = U_n
 
         return (IBState(ins=ins_new, X=X_new, U=U_out, mask=state.mask),
-                {"refresh_hit": refresh_hit})
+                ctx_h, refresh_hit)
 
     # -- diagnostics ---------------------------------------------------------
     def total_marker_force(self, state: IBState) -> jnp.ndarray:

@@ -98,6 +98,7 @@ class PackedBuckets(NamedTuple):
     Xb: jnp.ndarray               # (Q, c, dim)
     wb: jnp.ndarray               # (Q, c) marker weights (0 = empty slot)
     slot_of_marker: jnp.ndarray   # (N,) flat slot or Q*c (overflowed)
+    marker_of_slot: jnp.ndarray   # (Q*c,) its inverse; N = empty slot
     w_overflow: jnp.ndarray       # (N,)
     o_idx: jnp.ndarray            # (ocap,)
     o_w: jnp.ndarray              # (ocap,)
@@ -131,7 +132,7 @@ def chunk_pack_core(bid: jnp.ndarray, X: jnp.ndarray,
     [0, B), pack markers into ``Q`` chunks of ``c`` slots allocated
     compactly in tile order. Returns
     (Xb, wb, slot_of_marker, w_overflow, o_idx, o_w, n_over,
-    exceeded, tile_of_chunk)."""
+    exceeded, tile_of_chunk, marker_of_slot)."""
     N, dim = X.shape
     order = jnp.argsort(bid)
     bid_s = bid[order]
@@ -156,21 +157,22 @@ def chunk_pack_core(bid: jnp.ndarray, X: jnp.ndarray,
                             side="right").astype(jnp.int32) - 1)
     tid = jnp.clip(tid, 0, B - 1)
 
-    # slot -> sorted-marker position (pure gathers; every slot of an
-    # allocated chunk maps to start[tile] + offset-in-tile, empty
-    # slots gather a zero fill). Bitwise-identical layout to the old
-    # scatter construction.
+    # slot -> marker (pure gathers; every slot of an allocated chunk
+    # maps to sorted position start[tile] + offset-in-tile, an empty
+    # slot to N and so to a zero fill). Position-independent, so it is
+    # part of the layout: a refresh re-gathers X through it.
     q_c = jnp.arange(Q * c, dtype=jnp.int32) // c       # chunk of slot
     r = jnp.arange(Q * c, dtype=jnp.int32) % c          # rank in chunk
     t_of_slot = tid[q_c]
     off_in_tile = (q_c - base[t_of_slot]) * c + r
     valid = (off_in_tile >= 0) & (off_in_tile < counts[t_of_slot])
     src = jnp.where(valid, start[t_of_slot] + off_in_tile, N)
-    X_s = X[order]
-    w_s = weights[order]
-    Xb = jnp.take(X_s, src, axis=0, mode="fill",
+    marker_of_slot = jnp.take(order.astype(jnp.int32), src, mode="fill",
+                              fill_value=N)
+    Xb = jnp.take(X, marker_of_slot, axis=0, mode="fill",
                   fill_value=0).reshape(Q, c, dim)
-    wb = jnp.take(w_s, src, mode="fill", fill_value=0).reshape(Q, c)
+    wb = jnp.take(weights, marker_of_slot, mode="fill",
+                  fill_value=0).reshape(Q, c)
 
     from ibamr_tpu.ops.interaction_fast import compact_overflow
     (slot_of_marker, w_overflow, o_idx, o_w, n_over,
@@ -178,7 +180,7 @@ def chunk_pack_core(bid: jnp.ndarray, X: jnp.ndarray,
                                   overflow_cap)
 
     return (Xb, wb, slot_of_marker, w_overflow, o_idx, o_w, n_over,
-            exceeded, tid)
+            exceeded, tid, marker_of_slot)
 
 
 def default_overflow_cap(N: int) -> int:
@@ -210,8 +212,8 @@ def pack_markers(geom: BucketGeometry, grid: StaggeredGrid,
     B = int(np.prod(geom.nblk))
 
     (Xb, wb, slot_of_marker, w_overflow, o_idx, o_w, n_over,
-     exceeded, tid) = chunk_pack_core(bid, X, weights, Q, geom.cap, B,
-                                      overflow_cap)
+     exceeded, tid, marker_of_slot) = chunk_pack_core(
+         bid, X, weights, Q, geom.cap, B, overflow_cap)
     x0 = []
     for d in range(dim - 1):
         ids = tid
@@ -219,6 +221,7 @@ def pack_markers(geom: BucketGeometry, grid: StaggeredGrid,
             ids = ids // geom.nblk[a]
         x0.append((ids % geom.nblk[d]) * geom.tile[d])
     return PackedBuckets(Xb=Xb, wb=wb, slot_of_marker=slot_of_marker,
+                         marker_of_slot=marker_of_slot,
                          w_overflow=w_overflow, o_idx=o_idx, o_w=o_w,
                          any_overflow=n_over > 0, exceeded=exceeded,
                          x0=tuple(x0), tile_of_chunk=tid)
@@ -262,32 +265,28 @@ def refresh_packed(geom: BucketGeometry, grid: StaggeredGrid,
     Q, c = b.Xb.shape[0], b.Xb.shape[1]
     ocap = b.o_idx.shape[0]
     s = geom.support
-    slot = b.slot_of_marker
-    chunk_of_marker = jnp.minimum(slot // c, Q - 1)
 
-    # drift-bound check per blocked axis, against the ASSIGNED chunk's
-    # pack-time tile origin (overflow/inactive markers are exempt:
-    # their transfers never read the packed layout)
-    ok = jnp.ones((N,), dtype=bool)
+    # one gather of the new positions into the pack-time slots, through
+    # the slot -> marker map the pack kept. Everything else in the
+    # layout (weights, overflow lists, chunk->tile map) is
+    # position-independent and carries over.
+    Xb = jnp.take(X, b.marker_of_slot, axis=0, mode="fill",
+                  fill_value=0).reshape(Q, c, dim)
+
+    # drift-bound check per blocked axis, slot by slot against the
+    # chunk's pack-time tile origin (elementwise: no per-marker lookup).
+    # Empty slots and inactive markers carry weight 0 and are exempt, as
+    # overflowed markers are by having no slot: their transfers never
+    # read the packed layout.
+    ok = jnp.ones((Q, c), dtype=bool)
     for d in range(dim - 1):
-        x0 = b.x0[d][chunk_of_marker]
+        x0 = b.x0[d][:, None]
         for off in (0.5, 0.0):      # cell- and face-centered origins
-            xi = (X[:, d] - grid.x_lo[d]) / grid.dx[d] - off
+            xi = (Xb[..., d] - grid.x_lo[d]) / grid.dx[d] - off
             j0 = jnp.floor(xi - 0.5 * s).astype(jnp.int32) + 1
             r = jnp.mod(j0 - (x0 - 1), grid.n[d])
             ok &= r <= geom.tile[d] + 1
-    ok |= (slot >= Q * c) | (weights == 0)
-    hit = jnp.all(ok)
-
-    # slot -> marker inverse (one N-sized scatter; duplicates only at
-    # the discarded overflow sentinel Q*c), then one gather of the new
-    # positions into the pack-time slots. Everything else in the
-    # layout (weights, overflow lists, chunk->tile map) is
-    # position-independent and carries over.
-    inv = jnp.full((Q * c + 1,), N, dtype=jnp.int32).at[slot].set(
-        jnp.arange(N, dtype=jnp.int32))
-    Xb = jnp.take(X, inv[:-1], axis=0, mode="fill",
-                  fill_value=0).reshape(Q, c, dim)
+    hit = jnp.all(ok | (b.wb == 0))
 
     def repack():
         # scope inside the FALSE branch only: device time under

@@ -167,8 +167,8 @@ def pack_markers3(geom: BucketGeometry3, grid: StaggeredGrid,
     B = int(np.prod(geom.nblk))
 
     (Xb, wb, slot_of_marker, w_overflow, o_idx, o_w, n_over,
-     exceeded, tid) = chunk_pack_core(bid, X, weights, Q, geom.cap, B,
-                                      overflow_cap)
+     exceeded, tid, _) = chunk_pack_core(bid, X, weights, Q, geom.cap,
+                                         B, overflow_cap)
     x0 = []
     for d in range(dim):
         ids = tid

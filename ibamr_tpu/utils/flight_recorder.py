@@ -451,7 +451,10 @@ class FlightRecorder:
         compiled chunk (cold path: incidents are rare by construction).
         For a lane capsule the digest is of the LANE'S slice of the
         fleet re-execution — bitwise what a B=1 replay must reproduce.
-        None when re-execution itself fails."""
+        The vitals are the chunk's health vector as it crosses to the
+        host: a chunk that carries its transfer context ends it with
+        its ``[refreshes, falls]`` tally. None when re-execution itself
+        fails."""
         try:
             import jax.numpy as jnp
 

@@ -39,6 +39,11 @@ _STEPS_TOTAL = _obs.counter("driver_steps_total")
 _CHUNK_WALL = _obs.histogram("driver_chunk_wall_seconds")
 _obs.describe("driver_chunk_wall_seconds",
               "Per-chunk wall time including the post-chunk sync.")
+_REFRESHES_TOTAL = _obs.counter("transfer_refreshes_total")
+_FALLS_TOTAL = _obs.counter("transfer_repack_falls_total")
+_obs.describe("transfer_repack_falls_total",
+              "Refreshes of a carried marker layout that fell back to a "
+              "full re-pack (a drift bound was broken).")
 
 
 class SimulationDiverged(RuntimeError):
@@ -170,6 +175,43 @@ def checkpointed_step(step, remat: str):
         step, policy=getattr(jax.checkpoint_policies, policy_name))
 
 
+def offers_carry(integ) -> bool:
+    """Does ``integ`` have the carried form of its step
+    (``init_carry`` / ``step_carried``, see :func:`scan_steps`)?"""
+    return hasattr(integ, "init_carry") and hasattr(integ, "step_carried")
+
+
+def scan_steps(step, state, dt, n: int, carried=None):
+    """``n`` steps under one ``lax.scan``: ``(state, tally)``.
+
+    Plain form (``carried=None``): the scan of ``step(state, dt)``;
+    ``tally`` is None. Carried form: ``carried`` is an integrator with
+    ``init_carry``/``step_carried``; its context (a packed marker
+    layout, or None where its engine keeps none) is built once, before
+    the scan, and rides the scan beside the state, and ``tally`` is the
+    int32 ``[refreshes, falls]`` of the ``n`` steps. The driver's chunk
+    and the replay of a recorded chunk (``tools/replay.py``) both come
+    through here, so a replay lowers the program the run compiled."""
+    if carried is None:
+        def body(s, _):
+            return step(s, dt), None
+
+        out, _ = jax.lax.scan(body, state, None, length=n)
+        return out, None
+
+    def body(c, _):
+        s, ctx, tally = c
+        s, ctx, stats = carried.step_carried(s, ctx, dt)
+        return (s, ctx, tally + jnp.stack(
+            [jnp.asarray(stats[k], jnp.int32)
+             for k in ("refreshes", "falls")])), None
+
+    (out, _, tally), _ = jax.lax.scan(
+        body, (state, carried.init_carry(state),
+               jnp.zeros((2,), jnp.int32)), None, length=n)
+    return out, tally
+
+
 @dataclasses.dataclass
 class RunConfig:
     """Cadences mirror the reference input-file vocabulary."""
@@ -234,7 +276,16 @@ class HierarchyDriver:
 
     ``integ`` needs ``step(state, dt) -> state`` (every integrator in
     the framework); optionally ``cfl_dt(state, cfl)`` when
-    ``cfg.cfl`` is set. Callbacks (all optional):
+    ``cfg.cfl`` is set. An integrator that also offers the carried
+    form (``init_carry(state) -> ctx`` and ``step_carried(state, ctx,
+    dt) -> (state, ctx, stats)``: ``IBExplicitIntegrator``) has its
+    context threaded through the chunk's scan — one marker-layout pack
+    per chunk, not one per step — unless the caller chose the step
+    itself (``step_fn``), lanes or remat: those chunks scan ``step``.
+    The carried chunk's refresh and fall counts leave with the health
+    value in the one sync per chunk and land on
+    ``transfer_refreshes_total`` / ``transfer_repack_falls_total`` and
+    a ``driver/chunk/refresh`` span. Callbacks (all optional):
 
     - ``viz_fn(state, step)`` at the viz cadence;
     - ``metrics_fn(state, step) -> dict`` after every chunk (logged by
@@ -294,6 +345,12 @@ class HierarchyDriver:
         self.history = []
         self._base_step = (step_fn if step_fn is not None
                            else integ.step)
+        # thread the integrator's carried context through the scan:
+        # only where the step is the integrator's own and runs as is
+        # (vmap would run both branches of the refresh's cond, remat
+        # and a caller's step_fn wrap ``step``)
+        self._carried = (step_fn is None and lanes is None
+                         and cfg.remat is None and offers_carry(integ))
         # one compiled chunk per distinct length (a handful at most:
         # cadence-aligned lengths repeat) — no masked-tail waste
         self._chunks = {}
@@ -363,6 +420,7 @@ class HierarchyDriver:
             sigs = self._trace_sigs
             probe = self.health_probe
             lanes = self.lanes
+            integ = self.integ if self._carried else None
             if lanes is not None:
                 self._chunks[n] = self._build_fleet_chunk(n)
                 return self._chunks[n]
@@ -381,16 +439,20 @@ class HierarchyDriver:
                 sigs.setdefault(n, set()).add(sig)
                 counts[n] = len(sigs[n])
 
-                def body(s, _):
-                    return base_step(s, dt), None
-
-                out, _ = jax.lax.scan(body, state, None, length=n)
+                out, tally = scan_steps(base_step, state, dt, n,
+                                        carried=integ)
                 # the vitals vector replaces the single finite bool at
                 # the SAME one-transfer-per-chunk cost: both fuse into
                 # the scan's output and cross to the host once
-                if probe is not None:
-                    return out, probe.measure(out, dt)
-                return out, _finite_flag(out)
+                health = (probe.measure(out, dt) if probe is not None
+                          else _finite_flag(out))
+                if tally is not None:
+                    # ... and a carried chunk's counts ride behind it:
+                    # health[0] stays the finite flag either way
+                    health = jnp.concatenate(
+                        [jnp.ravel(health).astype(jnp.float32),
+                         tally.astype(jnp.float32)])
+                return out, health
 
             # whole-chunk buffer donation: the input state's buffers are
             # reused for the output (velocity/pressure update in place
@@ -603,6 +665,16 @@ class HierarchyDriver:
                     # one device sync per chunk: the finite bool or the
                     # fused vitals vector
                     health = np.asarray(health)
+                if self._carried:
+                    # the carried chunk's tally, off the tail of what
+                    # the sync brought: counters and an event span
+                    refreshes, falls = (int(v) for v in health[-2:])
+                    health = health[:-2]
+                    _REFRESHES_TOTAL.inc(refreshes)
+                    _FALLS_TOTAL.inc(falls)
+                    with _obs.span("refresh", step=step, chunk=ordinal,
+                                   refreshes=refreshes, falls=falls):
+                        pass
             self.last_chunk_wall_s = time.perf_counter() - t0
             _CHUNKS_TOTAL.inc()
             _STEPS_TOTAL.inc(n)
@@ -618,8 +690,7 @@ class HierarchyDriver:
                 # BEFORE any cadence callback sees a poisoned lane
                 self._triage_fleet(state, health, step + n)
             else:
-                finite = bool(health[0] >= 1.0) if probe is not None \
-                    else bool(health)
+                finite = bool(health.reshape(-1)[0] >= 1.0)
                 if not finite:
                     raise SimulationDiverged(step + n,
                                              _bad_leaf_names(state))

@@ -23,6 +23,7 @@ load-bearing claims pinned here:
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from ibamr_tpu.grid import StaggeredGrid
 from ibamr_tpu.ops import interaction
@@ -229,3 +230,151 @@ def test_refresh_fallback_matches_pack_under_jit():
     assert _bitwise_equal(b_j, pack_markers(eng.geom, g, Xd, None,
                                             nchunks=eng.nchunks,
                                             overflow_cap=eng.overflow_cap))
+
+
+# -- the carried form: the layout outlives the step --------------------------
+
+def _shell(engine="packed"):
+    from ibamr_tpu.models.shell3d import build_shell_example
+
+    return build_shell_example(n_cells=16, n_lat=24, n_lon=24, radius=0.25,
+                               use_fast_interaction=engine)
+
+
+def _carried_steps(integ, state, dt, k, ctx="init"):
+    """k carried steps, one jitted call each; the stats of every step."""
+    if ctx == "init":
+        ctx = jax.jit(integ.init_carry)(state)
+    step = jax.jit(integ.step_carried)
+    stats = []
+    for _ in range(k):
+        state, ctx, st = step(state, ctx, dt)
+        stats.append({k_: int(v) for k_, v in st.items()})
+    return state, ctx, stats
+
+
+def _plain_steps(integ, state, dt, k):
+    step = jax.jit(integ.step)
+    for _ in range(k):
+        state = step(state, dt)
+    return state
+
+
+def _moving(state, speed):
+    """The state with a uniform fluid velocity along x: markers drift
+    ``speed * dt`` a step."""
+    u = (jnp.full_like(state.ins.u[0], speed),) + tuple(state.ins.u[1:])
+    return state._replace(ins=state.ins._replace(u=u))
+
+
+def _max_gap(a, b):
+    return max(float(jnp.max(jnp.abs(x - y))) for x, y in
+               zip(jax.tree_util.tree_leaves(a),
+                   jax.tree_util.tree_leaves(b)))
+
+
+def test_carried_steps_bitwise_equal_steps_while_no_tile_changes():
+    # (a) at rest the shell moves ~1e-6 cells a step: every refresh is
+    # bitwise the re-pack, so k carried steps ARE k steps
+    integ, state = _shell()
+    got, ctx, stats = _carried_steps(integ, state, 1e-4, 4)
+    assert stats == [{"refreshes": 2, "falls": 0}] * 4
+    assert _bitwise_equal(got, _plain_steps(integ, state, 1e-4, 4))
+    # the layout handed on is the one a pack at the last X_half gives
+    assert bool(jnp.array_equal(
+        ctx.slot_of_marker,
+        integ.ib.prepare(got.X, got.mask).slot_of_marker))
+
+
+def test_carried_steps_equal_steps_to_roundoff_across_tile_changes():
+    # (a) 0.3 cells a step for 5 steps: markers cross tile boundaries,
+    # the carried layout keeps them in their old chunks (or re-packs
+    # once a bound falls), and only the summation order differs
+    integ, state = _shell()
+    state = _moving(state, 1.0)
+    dt = 0.3 / 16
+    got, _, stats = _carried_steps(integ, state, dt, 5)
+    ref = _plain_steps(integ, state, dt, 5)
+    assert sum(s["falls"] for s in stats) >= 1
+    assert float(jnp.max(jnp.abs(ref.X - state.X))) > 1.0 / 16
+    eps = float(jnp.finfo(ref.X.dtype).eps)
+    assert _max_gap(got, ref) < 1e4 * eps
+
+
+def test_carried_fall_is_counted_once_and_the_new_layout_is_kept():
+    # (b) a layout packed 3.2 cells away from where the markers are:
+    # the refresh at X_n falls, the re-packed layout is what the step
+    # hands on, and the next step hits on it
+    integ, state = _shell()
+    stale = jax.jit(integ.init_carry)(state)
+    moved = state._replace(X=state.X + 3.2 / 16)
+    got, ctx, stats = _carried_steps(integ, moved, 1e-4, 2, ctx=stale)
+    assert stats == [{"refreshes": 2, "falls": 1},
+                     {"refreshes": 2, "falls": 0}]
+    assert not bool(jnp.array_equal(ctx.slot_of_marker,
+                                    stale.slot_of_marker))
+    # a fall re-packs at X_n, as ``step`` does: bitwise the same step
+    assert _bitwise_equal(got, _plain_steps(integ, moved, 1e-4, 2))
+
+
+def _sorts(jaxpr, in_scan=False, in_cond=False, out=None):
+    """Where the ``sort`` primitives of a jaxpr sit: counts by
+    (inside a scan body, inside a cond branch)."""
+    out = {} if out is None else out
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name == "sort":
+            out[(in_scan, in_cond)] = out.get((in_scan, in_cond), 0) + 1
+        for v in eqn.params.values():
+            subs = v if isinstance(v, (list, tuple)) else [v]
+            for sub in subs:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    _sorts(inner, in_scan or name == "scan",
+                           in_cond or name == "cond", out)
+    return out
+
+
+def test_chunk_pays_one_bucket_prep_per_chunk():
+    # (c) the structural pin beside the one-prep-per-step pin: the
+    # driver's chunk sorts once before its scan, and inside the scan
+    # body only under a cond (the refreshes' fallback)
+    from ibamr_tpu.utils.hierarchy_driver import HierarchyDriver, RunConfig
+
+    integ, state = _shell()
+    cfg = RunConfig(dt=1e-4, num_steps=4, health_interval=4)
+    for kw, expect in (({}, {(False, False): 1, (True, True): 2}),
+                       ({"step_fn": integ.step},
+                        {(True, False): 1, (True, True): 1})):
+        chunk = HierarchyDriver(integ, cfg, **kw)._chunk(4)
+        assert _sorts(jax.make_jaxpr(chunk)(state, 1e-4).jaxpr) == expect
+
+
+@pytest.mark.parametrize("engine", [False, True], ids=["scatter", "mxu"])
+def test_engine_without_refresh_carries_nothing(engine):
+    # (d) no layout to keep: ctx is None and the carried step IS step
+    integ, state = _shell(engine)
+    assert jax.jit(integ.init_carry)(state) is None
+    got, ctx, stats = _carried_steps(integ, state, 1e-4, 2, ctx=None)
+    assert ctx is None
+    assert stats == [{"refreshes": 0, "falls": 0}] * 2
+    assert _bitwise_equal(got, _plain_steps(integ, state, 1e-4, 2))
+
+
+def test_carried_masked_markers_stay_exempt_from_the_drift_bound():
+    # (e) inactive markers (weight 0) parked far from where the layout
+    # was packed must not trip the bound, on any step
+    integ, state = _shell()
+    rng = np.random.default_rng(7)
+    mask = jnp.asarray(rng.random(state.X.shape[0]) > 0.25,
+                       dtype=state.mask.dtype)
+    state = state._replace(mask=mask)
+    ctx = jax.jit(integ.init_carry)(state)
+    parked = state._replace(
+        X=jnp.where(mask[:, None] > 0, state.X, state.X + 5.0 / 16))
+    got, _, stats = _carried_steps(integ, parked, 1e-4, 3, ctx=ctx)
+    assert stats == [{"refreshes": 2, "falls": 0}] * 3
+    # ``step`` packs the parked markers into other chunks: the same
+    # sums in another order
+    ref = _plain_steps(integ, parked, 1e-4, 3)
+    assert _max_gap(got, ref) < 1e3 * float(jnp.finfo(ref.X.dtype).eps)

@@ -95,3 +95,123 @@ def test_cfl_dt_recompute_no_retrace():
     # _cache_size() == 0 after ~280 in-process tests) and the count
     # must survive that.
     assert drv.trace_counts[10] == 1
+
+
+# -- the carried chunk: an integrator's context rides the scan ---------------
+
+def _shell():
+    from ibamr_tpu.models.shell3d import build_shell_example
+
+    return build_shell_example(n_cells=16, n_lat=24, n_lon=24, radius=0.25,
+                               use_fast_interaction="packed")
+
+
+def _packs_before_the_scan(chunk, *args):
+    """Marker-layout packs (``sort`` primitives) a chunk runs outside
+    its scan: one where the layout is carried, none where every step
+    packs its own."""
+    def sorts(jaxpr):
+        n = 0
+        for e in jaxpr.eqns:
+            n += e.primitive.name == "sort"
+            if e.primitive.name != "scan":
+                for v in e.params.values():
+                    inner = getattr(v, "jaxpr", v)
+                    if hasattr(inner, "eqns"):
+                        n += sorts(inner)
+        return n
+
+    return sorts(jax.make_jaxpr(chunk)(*args).jaxpr)
+
+
+def _equal(a, b):
+    return all(np.array_equal(np.asarray(x), np.asarray(y)) for x, y in
+               zip(jax.tree_util.tree_leaves(a),
+                   jax.tree_util.tree_leaves(b)))
+
+
+def test_only_the_plain_solo_chunk_threads_the_carry():
+    # (f) step_fn=None carries the integrator's context; lanes, remat
+    # and a caller's own step keep scanning ``step``
+    integ, state = _shell()
+
+    def driver(remat=None, **kw):
+        cfg = RunConfig(dt=1e-4, num_steps=4, health_interval=2,
+                        remat=remat)
+        return HierarchyDriver(integ, cfg, **kw)
+
+    drv = driver()
+    assert drv._carried
+    assert _packs_before_the_scan(drv._chunk(2), state, 1e-4) == 1
+    for other in (driver(step_fn=integ.step), driver(remat="full")):
+        assert not other._carried
+        assert _packs_before_the_scan(other._chunk(2), state, 1e-4) == 0
+    fleet = driver(lanes=2)
+    assert not fleet._carried
+    stacked = jax.tree_util.tree_map(lambda l: jnp.stack([l, l]), state)
+    assert _packs_before_the_scan(fleet._chunk(2), stacked,
+                                  jnp.full((2,), 1e-4),
+                                  jnp.ones((2,), bool)) == 0
+
+    # the callable the benchmark's spies and the serving cache hold on
+    # to: a jit object with .lower, (state, dt) -> (state, health), and
+    # health[0] the finite flag
+    chunk = drv._chunk(2)
+    assert chunk.lower(state, 1e-4).compile() is not None
+    out, health = chunk(state, 1e-4)
+    assert isinstance(out, type(state))
+    assert np.asarray(health).tolist() == [1.0, 4.0, 0.0]
+
+
+def test_chunked_run_equals_checkpoint_restart_bit_for_bit(tmp_path):
+    # (g) a chunk packs from state.X at its start, so it depends on
+    # nothing but the state: [n, n] == n, save, restore, n
+    from ibamr_tpu.utils.checkpoint import (restore_checkpoint,
+                                            save_checkpoint)
+
+    integ, state = _shell()
+    n = 3
+    through = HierarchyDriver(
+        integ, RunConfig(dt=1e-4, num_steps=2 * n,
+                         health_interval=n)).run(state)
+    half = HierarchyDriver(
+        integ, RunConfig(dt=1e-4, num_steps=n,
+                         health_interval=n)).run(state)
+    save_checkpoint(str(tmp_path), half, n)
+    restored, step, _ = restore_checkpoint(str(tmp_path), state, step=n)
+    assert step == n and _equal(restored, half)
+    resumed = HierarchyDriver(
+        integ, RunConfig(dt=1e-4, num_steps=2 * n,
+                         health_interval=n)).run(restored, start_step=n)
+    assert _equal(resumed, through)
+
+
+def test_falls_reach_the_counter_and_the_refresh_span():
+    # (h) markers swept 0.3 cells a step break the layout's half-cell
+    # bound inside a chunk: the falls leave with the chunk's one sync
+    from ibamr_tpu import obs
+
+    integ, state = _shell()
+    u = (jnp.full_like(state.ins.u[0], 1.0),) + tuple(state.ins.u[1:])
+    state = state._replace(ins=state.ins._replace(u=u))
+    cfg = RunConfig(dt=0.3 / 16, num_steps=6, health_interval=3)
+    falls0 = obs.counter("transfer_repack_falls_total").value
+    refreshes0 = obs.counter("transfer_refreshes_total").value
+    obs.clear_spans()
+    out = HierarchyDriver(integ, cfg).run(state)
+    spans = [s for s in obs.spans() if s["path"] == "driver/chunk/refresh"]
+    assert [(s["attrs"]["step"], s["attrs"]["chunk"],
+             s["attrs"]["refreshes"]) for s in spans] == [(0, 0, 6),
+                                                          (3, 1, 6)]
+    falls = sum(s["attrs"]["falls"] for s in spans)
+    assert falls >= 2                   # each chunk outruns its pack
+    assert obs.counter("transfer_repack_falls_total").value \
+        == falls0 + falls
+    assert obs.counter("transfer_refreshes_total").value \
+        == refreshes0 + 12
+    # and the carried run is the per-step run to roundoff
+    ref = HierarchyDriver(integ, cfg, step_fn=integ.step).run(state)
+    gap = max(float(jnp.max(jnp.abs(a - b))) for a, b in
+              zip(jax.tree_util.tree_leaves(out),
+                  jax.tree_util.tree_leaves(ref)))
+    assert gap < 1e4 * float(jnp.finfo(ref.X.dtype).eps)

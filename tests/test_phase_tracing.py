@@ -172,9 +172,11 @@ def test_run_loop_spans_and_ring():
     for c in chunks:
         kids = [s for s in ring if s["parent"] == c["id"]
                 and not s["name"].startswith("compile/")]
-        assert [k["name"] for k in kids] == ["dispatch", "sync"]
+        # ``refresh``: the carried chunk's counts, closed after the sync
+        assert [k["name"] for k in kids] == ["dispatch", "sync", "refresh"]
         assert [k["path"] for k in kids] == ["driver/chunk/dispatch",
-                                             "driver/chunk/sync"]
+                                             "driver/chunk/sync",
+                                             "driver/chunk/refresh"]
         for k in kids:
             assert k["attrs"]["chunk"] == c["attrs"]["chunk"]
             assert c["t0"] <= k["t0"] <= k["t1"] <= c["t1"]

@@ -124,13 +124,25 @@ def test_transfer_kernel_compiles(one_chip, name, op, n):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_whole_step_packed_bf16_fits_one_chip(one_chip):
-    # the engine TUNING_DB.json names for the flagship on platform tpu
+@pytest.mark.parametrize("program", ["step", "carried_chunk"])
+def test_whole_step_packed_bf16_fits_one_chip(one_chip, program):
+    # the engine TUNING_DB.json names for the flagship on platform tpu:
+    # the step alone, and the driver's chunk that carries the packed
+    # marker layout through its scan (one pack before the loop, two
+    # refreshes with their cond fallback inside it)
     integ, state = build_shell_example(
         n_cells=64, n_lat=79, n_lon=79,
         use_fast_interaction="packed_bf16")
-    compiled = jax.jit(integ.step).lower(
-        _on(one_chip, state), 5e-5).compile()
+    fn = jax.jit(integ.step)
+    if program == "carried_chunk":
+        from ibamr_tpu.utils.hierarchy_driver import (HierarchyDriver,
+                                                      RunConfig)
+
+        drv = HierarchyDriver(integ, RunConfig(dt=5e-5, num_steps=2,
+                                               health_interval=2))
+        assert drv._carried
+        fn = drv._chunk(2)
+    compiled = fn.lower(_on(one_chip, state), 5e-5).compile()
     ma = compiled.memory_analysis()
     total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
              + ma.temp_size_in_bytes + ma.generated_code_size_in_bytes)

@@ -226,20 +226,22 @@ def execute_chunk(integ, state, dt: float, length: int, step_wrap=None,
     """Re-execute the failing chunk: the same jitted
     ``lax.scan(step, ...)`` the driver compiled, minus the cadence
     machinery. ``step_fn`` substitutes a prebuilt step (the sharded
-    one) for ``integ.step``. Returns the post-chunk state."""
+    one) for ``integ.step``. Where the run scanned the integrator's own
+    step with nothing re-armed around it, the driver carried its
+    transfer context through the scan, and so does this. Returns the
+    post-chunk state."""
     import jax
 
-    step = integ.step if step_fn is None else step_fn
-    if step_wrap is not None:
-        step = step_wrap(step)
+    from ibamr_tpu.utils.hierarchy_driver import offers_carry, scan_steps
+
+    plain = integ.step if step_fn is None else step_fn
+    step = plain if step_wrap is None else step_wrap(plain)
+    carried = (integ if step_fn is None and step is plain
+               and offers_carry(integ) else None)
 
     @jax.jit
     def chunk(s, dt_):
-        def body(x, _):
-            return step(x, dt_), None
-
-        out, _ = jax.lax.scan(body, s, None, length=length)
-        return out
+        return scan_steps(step, s, dt_, length, carried=carried)[0]
 
     return chunk(state, dt)
 
