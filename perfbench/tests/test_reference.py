@@ -99,20 +99,20 @@ def test_step_against_program_scatter_path(small, tmp_path):
     step = jax.jit(integ.step)
     for _ in range(3):
         s = step(s, 5e-5)
-    s_in = harness.to_host(s)
+    adapter = harness.load_adapter(os.path.join(
+        ROOT, "perfbench", "adapters", "ib_shell.py"))
+    s_in = harness.to_host(adapter, s)
     for _ in range(5):
         s = step(s, 5e-5)
     ref = ib_shell.ShellReference(db)
     r_in = ib_shell.state_from_arrays(s_in)
     r_out = ref.advance(r_in, 5)
-    got = harness.compare(r_out, harness.to_host(s), r_in)
+    got = adapter.compare(r_out, harness.to_host(adapter, s), r_in)
     assert got["du"] < 2e-4 and got["p"] < 1e-4 and got["U"] < 1e-4, got
     low = ib_shell.ShellReference(db, lowp="bf16")
     l_out = low.advance(r_in, 5)
     low.close()
-    bad = harness.compare(
-        r_out, {**{f"u{d}": l_out.u[d] for d in range(3)},
-                "p": l_out.p, "U": l_out.U, "X": l_out.X}, r_in)
+    bad = adapter.compare(r_out, adapter.arrays_from(l_out), r_in)
     for key, least in (("du", 1e-2), ("p", 1e-3), ("U", 5e-4)):
         assert bad[key] > least and bad[key] > 10 * got[key], (key, bad)
     ref.close()
