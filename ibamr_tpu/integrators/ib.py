@@ -337,9 +337,8 @@ class IBExplicitIntegrator:
             f_eul = ib.spread_force(F_half, grid, X_half, state.mask,
                                     ctx=ctx_h)
 
-        # fluid solve with the IB body force
-        with scope("fluid"):
-            ins_new = self.ins.step(state.ins, dt, f=f_eul)
+        # fluid solve with the IB body force (it opens ``fluid`` itself)
+        ins_new = self.ins.step(state.ins, dt, f=f_eul)
 
         # corrector: move markers with the midpoint velocity
         if self.scheme == "midpoint":

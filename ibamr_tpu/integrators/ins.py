@@ -194,32 +194,45 @@ class INSStaggeredIntegrator:
         optional cell-centered divergence source (internal fluid
         sources/sinks — the IBStandardSourceGen analog, P14), imposed as
         div u^{n+1} = q by the projection."""
+        # Phase names (jax.named_scope: metadata only): the fluid solve
+        # names its own phases, so that a program without an IB wrapper
+        # carries them too. ``transforms`` is opened by the fused substep
+        # directly under ``fluid``; no named phase may sit between them
+        # (obs/deviceprof.phase_of takes the longest sequence).
+        with jax.named_scope("fluid"):
+            return self._step(state, dt, f, q)
+
+    def _step(self, state, dt, f, q):
+        """:meth:`step` under its ``fluid`` scope."""
+        scope = jax.named_scope
         g = self.grid
         rho, mu = self.rho, self.mu
         dx = g.dx
         u, p = state.u, state.p
 
         # 1. convective extrapolation (AB2; Euler on the first step)
-        if self._convective is None:
-            n_star = tuple(jnp.zeros_like(c) for c in u)
-            n_curr = n_star
-        else:
-            n_curr = self._convective(u, dx)
-            c1 = jnp.where(state.k == 0, 1.0, 1.5).astype(self.dtype)
-            c2 = jnp.where(state.k == 0, 0.0, -0.5).astype(self.dtype)
-            n_star = tuple(c1 * a + c2 * b
-                           for a, b in zip(n_curr, state.n_prev))
+        with scope("convect"):
+            if self._convective is None:
+                n_star = tuple(jnp.zeros_like(c) for c in u)
+                n_curr = n_star
+            else:
+                n_curr = self._convective(u, dx)
+                c1 = jnp.where(state.k == 0, 1.0, 1.5).astype(self.dtype)
+                c2 = jnp.where(state.k == 0, 0.0, -0.5).astype(self.dtype)
+                n_star = tuple(c1 * a + c2 * b
+                               for a, b in zip(n_curr, state.n_prev))
 
         # 2. semi-implicit viscous solve for u*
-        lap_u = self.laplacian_vel(u, dx)
-        gp = self.pressure_gradient(p, dx)
-        rhs = []
-        for d in range(g.dim):
-            r = (rho / dt) * u[d] + 0.5 * mu * lap_u[d] \
-                - rho * n_star[d] - gp[d]
-            if f is not None:
-                r = r + f[d]
-            rhs.append(r)
+        with scope("rhs"):
+            lap_u = self.laplacian_vel(u, dx)
+            gp = self.pressure_gradient(p, dx)
+            rhs = []
+            for d in range(g.dim):
+                r = (rho / dt) * u[d] + 0.5 * mu * lap_u[d] \
+                    - rho * n_star[d] - gp[d]
+                if f is not None:
+                    r = r + f[d]
+                rhs.append(r)
         # the fused path is only valid while the solver seams are the
         # stock periodic-FFT ones — a custom helmholtz_vel_solve /
         # project override (pencil solvers, user plugins) must win
@@ -269,6 +282,21 @@ class INSStaggeredIntegrator:
     def kinetic_energy(self, state: INSState) -> jnp.ndarray:
         ke = sum(jnp.sum(jnp.square(c)) for c in state.u)
         return 0.5 * self.rho * ke * self.grid.cell_volume
+
+    def enstrophy(self, state: INSState) -> jnp.ndarray:
+        """0.5 * integral of |curl u|^2 (3D), with the compact MAC curl:
+        each vorticity component at its own edges, from face differences
+        one cell apart. For a discretely divergence-free periodic u this
+        is the discrete viscous dissipation over mu: d/dt kinetic_energy
+        = -2 mu enstrophy is what the MAC Laplacian itself balances."""
+        u, dx = state.u, self.grid.dx
+
+        def dm(f, axis):
+            return (f - jnp.roll(f, 1, axis)) / dx[axis]
+
+        w2 = sum(jnp.sum(jnp.square(dm(u[b], a) - dm(u[a], b)))
+                 for a, b in ((1, 2), (2, 0), (0, 1)))
+        return 0.5 * w2 * self.grid.cell_volume
 
     def max_divergence(self, state: INSState) -> jnp.ndarray:
         return jnp.max(jnp.abs(stencils.divergence(state.u, self.grid.dx)))

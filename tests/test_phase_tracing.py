@@ -399,9 +399,12 @@ PHASE_METRICS = {
     "transfer.spread_ms": "ib/spread",
     "transfer.repack_ms": "ib/refresh/repack",
     "fluid.solve_ms": "fluid", "fluid.transform_ms": "fluid/transforms",
-    "device.unphased_ms": "unphased"}
-TOP_LEVEL = [m for m, p in PHASE_METRICS.items()
-             if p not in ("ib/refresh/repack", "fluid/transforms")]
+    "device.unphased_ms": "unphased",
+    # PR 28: the fluid solve's own phases, listed for tg_256.advance only
+    "fluid.convect_ms": "fluid/convect", "fluid.rhs_ms": "fluid/rhs"}
+FLUID_ONLY = ("fluid.convect_ms", "fluid.rhs_ms", "fluid.algebra_ms",
+              "fluid.convect_roofline")
+TOP_LEVEL = [m for m, p in PHASE_METRICS.items() if "/" not in p[3:]]
 _PRE = "jit(chunk)/while/body/closed_call/"
 HAND_OP_NAMES = {
     "fusion.1": _PRE + "ib/prep/pack/sort",
@@ -412,7 +415,9 @@ HAND_OP_NAMES = {
     "fusion.6": _PRE + "fluid/mul",
     "fft.7": _PRE + "fluid/transforms/jit(fft)/fft",
     "fusion.8": _PRE + "add",
-    "fusion.9": _PRE + "ib/interp/dot_general"}
+    "fusion.9": _PRE + "ib/interp/dot_general",
+    "fusion.10": _PRE + "fluid/convect/select_n",
+    "fusion.11": _PRE + "fluid/rhs/add"}
 HAND_OPS = [["fusion.1 [scatter_sort:sort]", 0.010],
             ["fusion.2 [scatter_sort:gather]", 0.020],
             ["fusion.3 [scatter_sort:gather]", 0.004],
@@ -422,6 +427,8 @@ HAND_OPS = [["fusion.1 [scatter_sort:sort]", 0.010],
             ["copy-done.5 [copy]", 0.002],
             ["fusion.6 [other:mul]", 0.008],
             ["fft.7 [fft:fft]", 0.002],
+            ["fusion.10 [other:select_n]", 0.005],
+            ["fusion.11 [other:add]", 0.003],
             ["fusion.8 [other:add]", 0.001],
             # its label's primitive is another program's: unphased
             ["fusion.9 [other:mul]", 0.003],
@@ -430,8 +437,9 @@ HAND_OPS = [["fusion.1 [scatter_sort:sort]", 0.010],
             ["while.1 [loop]", 0.0005]]
 HAND_WANT = {"ib/prep": 10.0, "ib/interp": 20.0, "ib/refresh": 4.0,
              "ib/force": 6.0, "ib/spread": 30.0,
-             "ib/refresh/repack": 0.0, "fluid": 10.0,
-             "fluid/transforms": 2.0, "unphased": 6.5}
+             "ib/refresh/repack": 0.0, "fluid": 18.0,
+             "fluid/transforms": 2.0, "fluid/convect": 5.0,
+             "fluid/rhs": 3.0, "unphased": 6.5}
 
 
 def _hand_ctx(monkeypatch, op_names=HAND_OP_NAMES):
@@ -468,7 +476,39 @@ def test_phase_reader(monkeypatch, metric):
     assert seen == [["driver/chunk[20]", "driver/chunk[10]"]]
     entry = _per_layer()[metric]
     assert (entry["source"], entry["moves"]) == ("device_trace", "step_ms")
-    assert len(entry["workloads"]) == 3
+    if metric in FLUID_ONLY:
+        assert entry["workloads"] == ["tg_256.advance"]
+    else:
+        assert {"ex4_shell_256.advance", "ex4_shell_128.advance",
+                "ex4_shell_128.production"} <= set(entry["workloads"])
+
+
+def test_fluid_phases_add_up_to_the_solve(monkeypatch):
+    """``fluid.algebra_ms`` is what is under ``fluid`` and under none of
+    its three named parts; the convective operator's share of its
+    roofline is its least bytes (from shapes) over the HBM peak, over
+    the phase's time."""
+    ctx, _ = _hand_ctx(monkeypatch)
+    ctx.update(grid_n=[256, 256, 256], device={"kind": "TPU v5 lite"},
+               peaks={"device_kinds": {"TPU v5 lite": {
+                   "hbm_bytes_per_s": 819e9}}})
+    parts = [_reader(m)(ctx) for m in ("fluid.convect_ms", "fluid.rhs_ms",
+                                       "fluid.algebra_ms",
+                                       "fluid.transform_ms")]
+    assert parts == pytest.approx([5.0, 3.0, 8.0, 2.0])
+    assert sum(parts) == pytest.approx(_reader("fluid.solve_ms")(ctx))
+    least_ms = 1e3 * 6 * 256 ** 3 * 4 / 819e9
+    assert _reader("fluid.convect_roofline")(ctx) == pytest.approx(
+        100.0 * least_ms / 5.0)
+    for m in FLUID_ONLY:
+        assert _per_layer()[m]["layer"] == "fluid solve"
+    # a program from before the two phases (the parent): nothing to read
+    old = {k: v for k, v in HAND_OP_NAMES.items()
+           if "convect" not in v and "rhs" not in v}
+    monkeypatch.setattr(deviceprof, "PHASES", deviceprof.PHASES[:-2])
+    ctx, _ = _hand_ctx(monkeypatch, old)
+    for m in FLUID_ONLY:
+        assert _reader(m)(ctx) is None
 
 
 def test_phase_readers_add_up_to_busy(monkeypatch):

@@ -147,3 +147,28 @@ def test_whole_step_packed_bf16_fits_one_chip(one_chip, program):
     total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
              + ma.temp_size_in_bytes + ma.generated_code_size_in_bytes)
     assert 0 < total < HBM_BYTES, ma
+
+
+def test_fluid_only_ppm_chunk_fits_one_chip(one_chip):
+    # the Taylor-Green configuration's program (tg_256, PR 28): the
+    # driver's scan chunk of the fluid solve alone with the ghost-padded,
+    # limited PPM operator, which no shell input uses (the 256^3 chunk of
+    # 20 steps compiles in ~30 s and takes 2.5 GiB; made by hand)
+    import math
+
+    from ibamr_tpu.integrators.ins import INSStaggeredIntegrator
+    from ibamr_tpu.utils.hierarchy_driver import HierarchyDriver, RunConfig
+
+    grid = StaggeredGrid(n=(64,) * 3, x_lo=(-math.pi,) * 3,
+                         x_up=(math.pi,) * 3)
+    integ = INSStaggeredIntegrator(grid, rho=1.0, mu=1.0 / 1600,
+                                   convective_op_type="PPM")
+    state = jax.eval_shape(integ.initialize)
+    drv = HierarchyDriver(integ, RunConfig(dt=0.02, num_steps=2,
+                                           health_interval=2))
+    compiled = drv._chunk(2).lower(_on(one_chip, state), 0.02).compile()
+    ma = compiled.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             + ma.temp_size_in_bytes + ma.generated_code_size_in_bytes)
+    assert 0 < total < HBM_BYTES, ma
+    assert "/fluid/convect/" in compiled.as_text()
