@@ -136,15 +136,23 @@ class INSStaggeredIntegrator:
             self.fused_stokes = fft.helmholtz_project_periodic
         # convective operator (P4 menu). Walls or PPM need the
         # ghost-padded path; fully-periodic centered/upwind keep the
-        # original roll formulation.
-        from ibamr_tpu.ops.convection import convective_rate_bc
+        # original roll formulation. ``_convective`` evaluates the
+        # padded path's operator with the slab-fused kernel where
+        # shape, dtype and boundary allow (chosen at trace time);
+        # ``_convective_padded`` never does (the sharded wrapper puts
+        # it in ``_convective``'s place: a pallas_call does not
+        # partition).
+        from ibamr_tpu.ops.convection import convective_rate_select
+        self._convective_padded = None
         if convective_op_type == "none":
             self._convective = None
         elif any(self.wall_axes) or convective_op_type in ("ppm", "cui"):
-            self._convective = partial(
-                convective_rate_bc, scheme=convective_op_type,
-                wall_axes=self.wall_axes,
-                wall_tangential=self.wall_tangential)
+            menu = dict(scheme=convective_op_type,
+                        wall_axes=self.wall_axes,
+                        wall_tangential=self.wall_tangential)
+            self._convective_padded = partial(
+                convective_rate_select, partitioned=True, **menu)
+            self._convective = partial(convective_rate_select, **menu)
         else:
             self._convective = partial(convective_rate,
                                        scheme=convective_op_type)

@@ -53,6 +53,7 @@ from ibamr_tpu.obs.bus import (  # noqa: F401
     Histogram,
     LEDGER_SCHEMA,
     RunLedger,
+    annotate,
     attach,
     chunk_boundary,
     clear_spans,

@@ -621,6 +621,17 @@ def _stack() -> list:
     return st
 
 
+def annotate(span_name: str, **attrs) -> bool:
+    """Add attributes to the innermost OPEN span of this thread named
+    ``span_name`` (what a trace-time decision tells the span of the
+    call that traced it); False, and nothing done, where none is open."""
+    for name, _sid, open_attrs in reversed(_stack()):
+        if name == span_name:
+            open_attrs.update(attrs)
+            return True
+    return False
+
+
 def spans() -> list:
     """The closed spans still in the ring, oldest first."""
     return list(_RING)

@@ -22,7 +22,7 @@ Schemes:
   (``INSStaggeredPPMConvectiveOperator``), implemented as whole-array
   limited interpolants instead of Fortran predictor loops.
 
-Two code paths:
+Three code paths:
 - :func:`convective_rate` — the original fully-periodic roll formulation
   (centered/upwind only; kept as the minimal-HBM fast path).
 - :func:`convective_rate_bc` — ghost-padded formulation supporting all
@@ -35,6 +35,16 @@ Two code paths:
   about the wall NODE; tangential components are cell-centered along the
   wall axis, so their ghosts reflect about the wall PLANE
   (ghost = 2*V_wall - interior).
+- ``ops/pallas_convection.convective_rate_ppm_fused`` — the same PPM
+  arithmetic as :func:`convective_rate_bc` evaluated slab by slab in a
+  Pallas kernel whose intermediates never leave VMEM (the padded path
+  moves 45 times the bytes the operator needs at 256^3).
+  :func:`convective_rate_select` takes it where the code can see that
+  it applies — scheme ``ppm``, no walls, three float32 components of
+  one rank-3 shape whose last two extents are multiples of (8, 128) —
+  and :func:`convective_rate_bc` everywhere else (``cui``, walls, 2D,
+  the 16^3-64^3 test grids, float64); no option chooses. The padded
+  path stays the oracle and supplies the fused path's VJP.
 """
 
 from __future__ import annotations
@@ -43,6 +53,7 @@ from typing import Dict, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
 
+from ibamr_tpu import obs
 from ibamr_tpu.ops import stencils
 
 Vel = Tuple[jnp.ndarray, ...]
@@ -198,30 +209,24 @@ def _sh(ap: jnp.ndarray, axis: int, s: int, n: int, g: int) -> jnp.ndarray:
     return _take(ap, axis, g + s, g + s + n)
 
 
-def _ppm_states(ap: jnp.ndarray, axis: int, n: int, g: int):
-    """CW84 limited parabola edge states over the EXTENDED cell range
-    [-1, n] (length n+2 along ``axis``): returns (aL, aR) with aL/aR the
-    monotonized lower/upper-face states of each 1D cell."""
-    def ext(s):
-        return _take(ap, axis, g - 1 + s, g + 1 + s + n)
+def _mc_slope(c, m, p):
+    """Monotonized-central slope of cell ``c`` from its neighbours."""
+    d = 0.5 * (p - m)
+    mono = (p - c) * (c - m) > 0.0
+    lim = jnp.minimum(jnp.abs(d),
+                      2.0 * jnp.minimum(jnp.abs(p - c), jnp.abs(c - m)))
+    return jnp.where(mono, jnp.sign(d) * lim, 0.0)
 
-    a, am, ap1 = ext(0), ext(-1), ext(1)
-    am2, ap2 = ext(-2), ext(2)
 
-    def mc_slope(c, m, p):
-        d = 0.5 * (p - m)
-        mono = (p - c) * (c - m) > 0.0
-        lim = jnp.minimum(jnp.abs(d),
-                          2.0 * jnp.minimum(jnp.abs(p - c), jnp.abs(c - m)))
-        return jnp.where(mono, jnp.sign(d) * lim, 0.0)
+def _ppm_face(am, a, sm, s):
+    """4th-order interpolant at the face between cells ``am | a`` with
+    the limited-slope correction (CW84 1.6)."""
+    return am + 0.5 * (a - am) - (1.0 / 6.0) * (s - sm)
 
-    s0 = mc_slope(a, am, ap1)
-    sm = mc_slope(am, am2, a)
-    sp = mc_slope(ap1, a, ap2)
-    # 4th-order face interpolants with limited-slope correction (CW84 1.6)
-    fL = am + 0.5 * (a - am) - (1.0 / 6.0) * (s0 - sm)
-    fR = a + 0.5 * (ap1 - a) - (1.0 / 6.0) * (sp - s0)
-    # monotonize the parabola (CW84 1.10)
+
+def _ppm_monotonize(a, fL, fR):
+    """Monotonize the cell's parabola (CW84 1.10): its limited
+    lower/upper edge states from the face interpolants."""
     local_ext = (fR - a) * (a - fL) <= 0.0
     aL = jnp.where(local_ext, a, fL)
     aR = jnp.where(local_ext, a, fR)
@@ -231,6 +236,29 @@ def _ppm_states(ap: jnp.ndarray, axis: int, n: int, g: int):
     aL = jnp.where(q6 > d2, 3.0 * a - 2.0 * aR, aL)
     aR = jnp.where(q6 < -d2, 3.0 * a - 2.0 * aL, aR)
     return aL, aR
+
+
+def _upwind_face(adv, up, dn):
+    """The upwind state by the sign of the advecting velocity, the
+    centred value where it is exactly zero."""
+    return jnp.where(adv > 0.0, up,
+                     jnp.where(adv < 0.0, dn, 0.5 * (up + dn)))
+
+
+def _ppm_states(ap: jnp.ndarray, axis: int, n: int, g: int):
+    """CW84 limited parabola edge states over the EXTENDED cell range
+    [-1, n] (length n+2 along ``axis``): returns (aL, aR) with aL/aR the
+    monotonized lower/upper-face states of each 1D cell."""
+    def ext(s):
+        return _take(ap, axis, g - 1 + s, g + 1 + s + n)
+
+    a, am, ap1 = ext(0), ext(-1), ext(1)
+    am2, ap2 = ext(-2), ext(2)
+    s0 = _mc_slope(a, am, ap1)
+    sm = _mc_slope(am, am2, a)
+    sp = _mc_slope(ap1, a, ap2)
+    return _ppm_monotonize(a, _ppm_face(am, a, sm, s0),
+                           _ppm_face(a, ap1, s0, sp))
 
 
 def _face_value_padded(ap: jnp.ndarray, adv: jnp.ndarray, axis: int,
@@ -249,15 +277,13 @@ def _face_value_padded(ap: jnp.ndarray, adv: jnp.ndarray, axis: int,
         aL, aR = _ppm_states(ap, axis, n, g)
         up = _take(aR, axis, shift, shift + n)        # aR of cell i+shift-1
         dn = _take(aL, axis, shift + 1, shift + 1 + n)  # aL of cell i+shift
-        return jnp.where(adv > 0.0, up,
-                         jnp.where(adv < 0.0, dn, 0.5 * (up + dn)))
+        return _upwind_face(adv, up, dn)
     if scheme == "cui":
         qmm = _sh(ap, axis, shift - 2, n, g)
         qpp = _sh(ap, axis, shift + 1, n, g)
         up = _cui_face(qmm, qm, qp)
         dn = _cui_face(qpp, qp, qm)
-        return jnp.where(adv > 0.0, up,
-                         jnp.where(adv < 0.0, dn, 0.5 * (up + dn)))
+        return _upwind_face(adv, up, dn)
     raise ValueError(f"unknown convective scheme {scheme!r}")
 
 
@@ -338,3 +364,42 @@ def convective_rate_bc(
             acc = _pin_wall_faces(acc, d)
         out.append(acc)
     return tuple(out)
+
+
+# which evaluation a trace chose (bumped once per traced selection)
+_FUSED_TOTAL = obs.counter("fluid_convect_fused_total")
+_PADDED_TOTAL = obs.counter("fluid_convect_padded_total")
+obs.describe("fluid_convect_fused_total",
+             "traces of the ghost-padded-menu convective operator that "
+             "took the slab-fused periodic PPM kernel")
+obs.describe("fluid_convect_padded_total",
+             "traces of it that took convective_rate_bc")
+
+
+def convective_rate_select(
+        u: Vel, dx: Sequence[float], scheme: str = "ppm",
+        wall_axes: Optional[Sequence[bool]] = None,
+        wall_tangential: Optional[Dict[Tuple[int, int, int], float]] = None,
+        partitioned: bool = False,
+) -> Vel:
+    """:func:`convective_rate_bc`'s operator by whichever evaluation
+    fits what is observed at trace time: the slab-fused kernel for
+    fully periodic 3D float32 ``ppm`` on tile-aligned extents, the
+    ghost-padded path otherwise, and always where the caller's program
+    is ``partitioned`` over a mesh (the sharded wrapper says so: a
+    pallas_call does not partition). The choice is counted
+    (``fluid_convect_{fused,padded}_total``) and told to the
+    ``driver/chunk`` span whose call traced it (``convect_path``)."""
+    from ibamr_tpu.ops import pallas_convection
+
+    fused = (scheme == "ppm" and not partitioned
+             and not any(wall_axes or ())
+             and all(isinstance(h, (int, float)) for h in dx)
+             and pallas_convection.fused_ppm_supported(u))
+    (_FUSED_TOTAL if fused else _PADDED_TOTAL).inc()
+    obs.annotate("driver/chunk",
+                 convect_path="fused" if fused else "padded")
+    if fused:
+        return pallas_convection.convective_rate_ppm_fused(
+            tuple(u), tuple(dx))
+    return convective_rate_bc(u, dx, scheme, wall_axes, wall_tangential)

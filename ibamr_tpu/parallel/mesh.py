@@ -110,6 +110,10 @@ def _with_pencil_solvers(ins_integ, mesh: Mesh):
     # the fused single-device spectral path bypasses the seams above;
     # sharded stepping must go through the pencil transposes
     integ2.fused_stokes = None
+    # and the slab-fused convective kernel is one device's: a
+    # pallas_call does not partition, the ghost-padded path does
+    if getattr(integ2, "_convective_padded", None) is not None:
+        integ2._convective = integ2._convective_padded
     return integ2
 
 

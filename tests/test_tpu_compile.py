@@ -153,7 +153,10 @@ def test_fluid_only_ppm_chunk_fits_one_chip(one_chip):
     # the Taylor-Green configuration's program (tg_256, PR 28): the
     # driver's scan chunk of the fluid solve alone with the ghost-padded,
     # limited PPM operator, which no shell input uses (the 256^3 chunk of
-    # 20 steps compiles in ~30 s and takes 2.5 GiB; made by hand)
+    # 20 steps compiles in ~30 s and takes 2.5 GiB; made by hand). At
+    # 64^3 the last extent is no multiple of 128, so by the shape rule
+    # of ops/convection.convective_rate_select this chunk stays on the
+    # padded path (PR 29); the fused kernel has its own case below
     import math
 
     from ibamr_tpu.integrators.ins import INSStaggeredIntegrator
@@ -172,3 +175,32 @@ def test_fluid_only_ppm_chunk_fits_one_chip(one_chip):
              + ma.temp_size_in_bytes + ma.generated_code_size_in_bytes)
     assert 0 < total < HBM_BYTES, ma
     assert "/fluid/convect/" in compiled.as_text()
+
+
+def test_fused_ppm_operator_compiles_at_256(one_chip, monkeypatch):
+    # tg_256's convective operator since PR 29: the slab-fused periodic
+    # PPM kernel alone at the cell's own size (Mosaic takes ~3 s). The
+    # custom call has to sit under /fluid/convect/ (the phase metrics
+    # read its op_name), and the operator's intermediates in VMEM: the
+    # padded path at this size has 1.14 GiB of temporaries in HBM
+    from ibamr_tpu.obs import deviceprof
+    from ibamr_tpu.ops import convection
+
+    # the kernel picks interpret mode from the default backend, which
+    # is the CPU here: steer it in the test, not through an option
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n, h = 256, 2 * 3.141592653589793 / 256
+
+    def rate(u):
+        with jax.named_scope("fluid"), jax.named_scope("convect"):
+            return convection.convective_rate_select(u, (h, h, h), "ppm")
+
+    u = tuple(jax.ShapeDtypeStruct((n,) * 3, jnp.float32) for _ in range(3))
+    compiled = jax.jit(rate).lower(_on(one_chip, u)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    names, phases = deviceprof.names_from_hlo(text)
+    call = [i for i, name in names.items() if "pallas_call" in name]
+    assert len(call) == 1 and "/fluid/convect/" in names[call[0]]
+    assert phases[call[0]] == "fluid/convect"
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.3 * 2 ** 30
