@@ -1116,8 +1116,7 @@ def main():
             # and report the best configuration as the headline value.
             # Each leg is deadline-guarded; the pallas leg runs in a
             # terminable child (remote-compile stall history).
-            for label in ("packed", "packed_bf16", "packed3",
-                          "packed3_bf16", "pallas_packed",
+            for label in ("packed", "packed_bf16", "pallas_packed",
                           "hybrid_bf16", "fluid_bf16"):
                 if time.perf_counter() - t_start > args.deadline:
                     errors.append(f"flagship[{label}]: skipped "
@@ -1162,15 +1161,13 @@ def main():
                     n_lon = max(16, int(round(args.n_lon * frac)))
                     cmp = {}
                     # transfer-engine compare: scatter / MXU-bucketed /
-                    # occupancy-packed / Pallas tile kernel /
+                    # occupancy-packed /
                     # Pallas-packed / hybrid pallas-spread + bf16-interp
                     # (VERDICT round 2 item 5 + round 3 packed engines).
                     # A failed leg only loses that engine's entry.
                     for label, fast in (("mxu", True),
                                         ("scatter", False),
                                         ("packed", "packed"),
-                                        ("packed3", "packed3"),
-                                        ("pallas", "pallas"),
                                         ("pallas_packed",
                                          "pallas_packed"),
                                         ("hybrid_bf16",

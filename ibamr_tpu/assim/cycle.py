@@ -52,7 +52,7 @@ from ibamr_tpu.assim.observe import ObservationOperator, stream_from_list
 from ibamr_tpu.utils.hierarchy_driver import (HierarchyDriver, RunConfig,
                                               SimulationDiverged)
 
-# the ENGINE_FALLBACKS / PRECISION_FALLBACKS chain shape: each rung
+# the engine-fallback / PRECISION_FALLBACKS chain shape: each rung
 # maps to the next-stronger one; the top rung has no successor (the
 # supervisor then falls back to its generic dt-backoff retry, which
 # for a filter fault effectively gives up gracefully)

@@ -249,7 +249,7 @@ class IBExplicitIntegrator:
         scan: one bucket prep at ``state.X`` (the ``ib/prep`` scope),
         or None where nothing can be carried — the strategy has no
         ``prepare``/``refresh``, or its engine answers a refresh with
-        no context (``scatter``, ``mxu*``, ``packed3*``, ``pallas``)."""
+        no context (``scatter``, ``mxu``)."""
         refresh = getattr(self.ib, "refresh", None)
         if refresh is None:
             return None

@@ -1,10 +1,18 @@
-"""Pluggable transfer-engine resolver — the autotuner's consumption seam.
+"""The transfer engines: which exist, how each is built, what it
+degrades to, and which one ``auto`` means.
 
-``build_shell_example(use_fast_interaction=None)`` ("auto") used to
-hard-code the round-5 packed promotion inline. The serving cache
-(ibamr_tpu/serve/aot_cache.py) keys executables on the RESOLVED engine,
-and the measured-search autotuner (ibamr_tpu/tune/, docs/TUNING.md)
-publishes winners here — so auto resolution routes through this module:
+One table, :data:`ENGINES`, has a row per engine name. Every list of
+engine names elsewhere (the input key ``IBMethod { transfer_engine }``,
+the ``use_fast_interaction`` keyword of ``build_shell_example``, the
+``IBAMR_TRANSFER_ENGINE`` override, the tuning DB's ``engine`` field,
+the autotuner's menu and probe set, the degradation chain of
+docs/RESILIENCE.md) is read from it. ``ops/`` holds the engines and
+knows none of their names.
+
+``build_shell_example(use_fast_interaction=None)`` ("auto") resolves
+here. The serving cache (ibamr_tpu/serve/aot_cache.py) keys
+executables on the RESOLVED engine, and the measured-search autotuner
+(ibamr_tpu/tune/, docs/TUNING.md) publishes winners here:
 
 1. ``IBAMR_TRANSFER_ENGINE`` env var: an explicit operator override
    (validated against the engine vocabulary; ``"auto"``/empty defers).
@@ -52,9 +60,188 @@ from __future__ import annotations
 
 import json
 import os
-from typing import Optional, Sequence
+import warnings
+from functools import partial
+from typing import Callable, NamedTuple, Optional, Sequence
 
 from ibamr_tpu import obs as _obs
+
+
+def _overflow_cap(vertices) -> int:
+    return max(2048, vertices.shape[0] // 4)
+
+
+def _build_mxu(grid, vertices, kernel):
+    from ibamr_tpu.ops.interaction_fast import FastInteraction, suggest_cap
+    # pole-clustered tiles overflow into the compact scatter path; keep
+    # the dense per-tile capacity bounded so padding FLOPs stay sane
+    # (the packed layouts size chunks instead)
+    cap = min(suggest_cap(grid, vertices, kernel=kernel, tile=8,
+                          slack=1.2), 1024)
+    return FastInteraction(grid, kernel=kernel, tile=8, cap=cap,
+                           overflow_cap=_overflow_cap(vertices))
+
+
+def _packed_layout(grid, vertices, kernel) -> dict:
+    from ibamr_tpu.ops.interaction_packed import suggest_chunks
+    return dict(kernel=kernel, tile=8, chunk=128,
+                nchunks=suggest_chunks(grid, vertices, kernel=kernel,
+                                       tile=8, chunk=128, slack=1.3),
+                overflow_cap=_overflow_cap(vertices))
+
+
+def _build_packed(grid, vertices, kernel, bf16=False):
+    import jax.numpy as jnp
+
+    from ibamr_tpu.ops.interaction_packed import PackedInteraction
+    return PackedInteraction(
+        grid, **_packed_layout(grid, vertices, kernel),
+        compute_dtype=jnp.bfloat16 if bf16 else None)
+
+
+def _build_pallas_packed(grid, vertices, kernel):
+    from ibamr_tpu.ops.pallas_interaction import PallasPackedInteraction
+    return PallasPackedInteraction(
+        grid, **_packed_layout(grid, vertices, kernel))
+
+
+def _build_hybrid_bf16(grid, vertices, kernel):
+    import jax.numpy as jnp
+
+    from ibamr_tpu.ops.pallas_interaction import HybridPackedInteraction
+    return HybridPackedInteraction(
+        grid, **_packed_layout(grid, vertices, kernel),
+        compute_dtype=jnp.bfloat16)
+
+
+class EngineRow(NamedTuple):
+    """``build(grid, vertices, kernel)`` constructs the engine (None
+    for the scatter/gather path of IBMethod itself; engine modules are
+    imported only when their row is built). ``fallback`` is the next
+    link of the degradation chain: a row whose construction or compile
+    fails gives way to it, trading measured speed for availability.
+    ``probed`` rows get a build-time compile probe under
+    ``probe="auto"``: the Pallas-backed ones, whose Mosaic lowering is
+    what a chip's compiler has refused in the field; probing plain-XLA
+    rows would tax every build for a failure never observed."""
+    build: Callable
+    fallback: Optional[str]
+    probed: bool = False
+
+
+# THE table. ``scatter`` is the oracle every other row is tested
+# against and the end of every fallback chain. "auto" is deliberately
+# absent: resolution must terminate in a row.
+ENGINES = {
+    "scatter": EngineRow(lambda grid, vertices, kernel: None, None),
+    "mxu": EngineRow(_build_mxu, "scatter"),
+    "packed": EngineRow(_build_packed, "scatter"),
+    "packed_bf16": EngineRow(partial(_build_packed, bf16=True), "packed"),
+    "pallas_packed": EngineRow(_build_pallas_packed, "packed",
+                               probed=True),
+    "hybrid_bf16": EngineRow(_build_hybrid_bf16, "packed_bf16",
+                             probed=True),
+}
+
+RESOLVED_ENGINES = tuple(ENGINES)
+PROBED_ENGINES = frozenset(k for k, row in ENGINES.items() if row.probed)
+
+
+def normalize_engine_name(name) -> str:
+    """Map the ``use_fast_interaction`` vocabulary (True/False/str) to
+    a row name of :data:`ENGINES`."""
+    if name is True:
+        return "mxu"
+    if name is False or name is None:
+        return "scatter"
+    return str(name).lower()
+
+
+def _validate(name: str, source: str) -> str:
+    if name not in ENGINES:
+        raise ValueError(
+            f"{source}: unknown transfer engine {name!r}; expected one "
+            f"of {RESOLVED_ENGINES}")
+    return name
+
+
+def fallback_chain(name) -> list:
+    """The degradation order starting AT ``name`` (inclusive), ending
+    at "scatter". Raises KeyError for unknown engine names."""
+    chain = [normalize_engine_name(name)]
+    while ENGINES[chain[-1]].fallback is not None:
+        chain.append(ENGINES[chain[-1]].fallback)
+    return chain
+
+
+def construct_transfer_engine(name, grid, vertices, kernel: str):
+    """Construct the named transfer engine against ``grid`` for a
+    structure with marker positions ``vertices``. ``name`` uses the
+    ``use_fast_interaction`` vocabulary (True/False/str); "scatter"
+    returns None (the IBMethod scatter/gather path). Raises on
+    unsatisfiable geometry (a grid the 8-tile does not divide);
+    :func:`build_engine_with_fallback` turns such failures into
+    degradation instead of death."""
+    name = _validate(normalize_engine_name(name), "construct_transfer_engine")
+    return ENGINES[name].build(grid, vertices, kernel)
+
+
+def probe_transfer_engine(fast, vertices) -> None:
+    """Trace AND compile (without executing) a bucket + spread +
+    interp composition at the real marker shapes — the cheap stand-in
+    for 'does this engine's first step survive': trace-time failures
+    (a monkeypatched or buggy engine method) and XLA/Mosaic compile
+    failures (the round-2 Pallas remote-compile stall) both surface
+    here, at build time, where degradation is still possible."""
+    if fast is None:
+        return
+    import jax
+    import jax.numpy as jnp
+
+    X = jnp.asarray(vertices)
+    F = jnp.zeros_like(X)
+
+    def fn(F, X):
+        b = fast.buckets(X)
+        g = fast.spread_vel(F, X, b=b)
+        return fast.interpolate_vel(g, X, b=b)
+
+    jax.jit(fn).lower(F, X).compile()
+
+
+def build_engine_with_fallback(name, grid, vertices, kernel: str,
+                               probe="auto"):
+    """Construct ``name``'s transfer engine, degrading down its
+    :func:`fallback_chain` when construction or compile fails: each
+    failure logs a warning naming the failed engine and its
+    replacement, counts on ``engine_fallbacks_total{engine,to}`` (the
+    warning tells a human once, the counter shows the degradation in
+    every later ledger snapshot), and the run continues on the next
+    engine instead of dying. ``probe`` is True / False / "auto" (probe
+    only the rows marked ``probed``). The terminal "scatter" link
+    cannot fail (engine None). Returns ``(engine_or_None,
+    engine_name)``."""
+    chain = fallback_chain(name)
+    for eng_name, nxt in zip(chain, chain[1:] + [None]):
+        try:
+            fast = construct_transfer_engine(eng_name, grid, vertices,
+                                             kernel)
+            if probe is True or (probe == "auto"
+                                 and eng_name in PROBED_ENGINES):
+                probe_transfer_engine(fast, vertices)
+            return fast, eng_name
+        except Exception as e:
+            if nxt is None:
+                raise
+            _obs.counter("engine_fallbacks_total", engine=eng_name,
+                         to=nxt).inc()
+            warnings.warn(
+                f"transfer engine {eng_name!r} failed to "
+                f"build/compile ({type(e).__name__}: {e}); degrading "
+                f"to {nxt!r}", RuntimeWarning)
+
+
+# -- which engine "auto" means ------------------------------------------------
 
 ENV_ENGINE = "IBAMR_TRANSFER_ENGINE"
 ENV_TUNING_DB = "IBAMR_TUNING_DB"
@@ -68,13 +255,6 @@ REPO_ROOT = os.path.dirname(os.path.dirname(
 DEFAULT_DB_PATH = os.path.join(REPO_ROOT, "TUNING_DB.json")
 
 DB_SCHEMA = 1
-
-# the resolved-name vocabulary (normalize_engine_name output space);
-# "auto" is deliberately absent — resolution must terminate here
-RESOLVED_ENGINES = (
-    "scatter", "mxu", "packed", "pallas", "pallas_packed", "mxu_bf16",
-    "packed_bf16", "packed3", "packed3_bf16", "hybrid_packed",
-    "hybrid_packed_bf16", "hybrid_bf16")
 
 # match-field specificity weights: an exact grid list outranks a cubic
 # extent; every other pinned field counts 1. The sum is the entry's
@@ -104,14 +284,6 @@ def default_rule(n: Sequence[int], n_markers: int, support: int) -> str:
         and all(v % 8 == 0 for v in n[:-1])
         and all(v >= 8 + support + 1 for v in n[:-1]))
     return "packed" if eligible else "scatter"
-
-
-def _validate(name: str, source: str) -> str:
-    if name not in RESOLVED_ENGINES:
-        raise ValueError(
-            f"{source}: unknown transfer engine {name!r}; expected one "
-            f"of {RESOLVED_ENGINES}")
-    return name
 
 
 def normalize_spectral_dtype(value) -> str:

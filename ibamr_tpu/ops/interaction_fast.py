@@ -409,13 +409,12 @@ def spread_overflow_fallbacks(out: jnp.ndarray, b: Buckets,
 
 def contract_compressed(spec: str, a, b, compute_dtype,
                         precision=jax.lax.Precision.HIGHEST):
-    """The one transfer-engine contraction point: exact f32 einsum, or
+    """The packed engines' contraction point: exact f32 einsum, or
     bf16-compressed operands with f32 accumulation when
-    ``compute_dtype`` is set (the (B,cap,P)/(B,cap,nz) operands are the
-    dominant HBM traffic of the whole IB step — PERF.md round-3
-    breakdown; compression costs ~3 decimal digits of delta-weight
-    precision, pinned by tests). Shared by the MXU and packed engines
-    in both directions so the scheme cannot diverge between them."""
+    ``compute_dtype`` is set (the weight operands are the dominant HBM
+    traffic of the whole IB step; compression costs ~3 decimal digits
+    of delta-weight precision, pinned by tests). One definition for
+    both directions so the scheme cannot diverge between them."""
     if compute_dtype is not None:
         return jnp.einsum(spec, a.astype(compute_dtype),
                           b.astype(compute_dtype),
@@ -426,8 +425,7 @@ def contract_compressed(spec: str, a, b, compute_dtype,
 
 def spread_bucketed(geom: BucketGeometry, grid: StaggeredGrid,
                     b: Buckets, F: jnp.ndarray, X: jnp.ndarray,
-                    centering, kernel: Kernel,
-                    compute_dtype=None) -> jnp.ndarray:
+                    centering, kernel: Kernel) -> jnp.ndarray:
     """Spread marker values F (N,) -> grid field; exact up to roundoff
     vs interaction.spread (overflow markers go through that path).
 
@@ -439,7 +437,8 @@ def spread_bucketed(geom: BucketGeometry, grid: StaggeredGrid,
     Ff = bucketed_channel(b, F)
     A, Wlast = _tile_weights(geom, grid, b, centering, kernel)
     A = A * (Ff * b.wb * inv_vol)[..., None]
-    T = contract_compressed("bmp,bmz->bpz", A, Wlast, compute_dtype)
+    T = jnp.einsum("bmp,bmz->bpz", A, Wlast,
+                   precision=jax.lax.Precision.HIGHEST)
     out = _overlap_add(geom, grid, T.reshape(
         (T.shape[0],) + tuple(geom.width) + (grid.n[grid.dim - 1],)))
     return spread_overflow_fallbacks(out, b, F, X, grid, centering,
@@ -484,13 +483,13 @@ def unbucket_with_overflow(Ub: jnp.ndarray, b: Buckets, f: jnp.ndarray,
 
 def interpolate_bucketed(geom: BucketGeometry, grid: StaggeredGrid,
                          b: Buckets, f: jnp.ndarray, X: jnp.ndarray,
-                         centering, kernel: Kernel,
-                         compute_dtype=None) -> jnp.ndarray:
+                         centering, kernel: Kernel) -> jnp.ndarray:
     """Interpolate grid field at markers -> (N,) (adjoint of spread).
     Marker weights come from ``b`` only — see spread_bucketed."""
     T = _extract_tiles(geom, grid, f)                 # (B, P, n_last)
     A, Wlast = _tile_weights(geom, grid, b, centering, kernel)
-    D = contract_compressed("bpz,bmz->bmp", T, Wlast, compute_dtype)
+    D = jnp.einsum("bpz,bmz->bmp", T, Wlast,
+                   precision=jax.lax.Precision.HIGHEST)
     # wb already carries the caller's marker weights (bucket_markers)
     Ub = jnp.sum(A * D, axis=-1) * b.wb               # (B, cap)
     return unbucket_with_overflow(Ub, b, f, X, grid, centering, kernel)
@@ -508,15 +507,11 @@ class FastInteraction:
 
     def __init__(self, grid: StaggeredGrid, kernel: Kernel = "IB_4",
                  tile: int = 8, cap: int = 256,
-                 overflow_cap: Optional[int] = None,
-                 compute_dtype=None):
+                 overflow_cap: Optional[int] = None):
         self.grid = grid
         self.kernel: Kernel = kernel
         self.geom = make_geometry(grid, kernel, tile=tile, cap=cap)
         self.overflow_cap = overflow_cap
-        # None = exact f32 contractions; jnp.bfloat16 = compressed
-        # operands (see spread_bucketed)
-        self.compute_dtype = compute_dtype
 
     def buckets(self, X: jnp.ndarray,
                 weights: Optional[jnp.ndarray] = None) -> Buckets:
@@ -529,8 +524,7 @@ class FastInteraction:
         if b is None:
             b = self.buckets(X, weights)
         cols = [interpolate_bucketed(self.geom, self.grid, b, u[d], X,
-                                     d, self.kernel,
-                                     compute_dtype=self.compute_dtype)
+                                     d, self.kernel)
                 for d in range(self.grid.dim)]
         return jnp.stack(cols, axis=-1)
 
@@ -540,6 +534,5 @@ class FastInteraction:
         if b is None:
             b = self.buckets(X, weights)
         return tuple(spread_bucketed(self.geom, self.grid, b, F[:, d], X,
-                                     d, self.kernel,
-                                     compute_dtype=self.compute_dtype)
+                                     d, self.kernel)
                      for d in range(self.grid.dim))

@@ -125,10 +125,7 @@ def suggest_chunks(grid: StaggeredGrid, X, kernel: Kernel = "IB_4",
 def chunk_pack_core(bid: jnp.ndarray, X: jnp.ndarray,
                     weights: jnp.ndarray, Q: int, c: int, B: int,
                     overflow_cap: int):
-    """THE occupancy-packing core shared by every chunk-packed layout
-    (xy-packed here, fully-blocked in interaction_packed3 — one
-    definition so the sort/assign/scatter/overflow machinery cannot
-    diverge between engines): given per-marker tile ids ``bid`` in
+    """The occupancy-packing core: given per-marker tile ids ``bid`` in
     [0, B), pack markers into ``Q`` chunks of ``c`` slots allocated
     compactly in tile order. Returns
     (Xb, wb, slot_of_marker, w_overflow, o_idx, o_w, n_over,
@@ -591,62 +588,3 @@ class PackedInteraction:
                                    compute_dtype=self.compute_dtype)
                      for d in range(self.grid.dim))
 
-
-# -- engine registry: graceful degradation chain -----------------------------
-#
-# Registry-level fallback order for every named transfer engine. When an
-# engine's construction, compile, or probe execution fails (a Pallas
-# remote-compile stall, a Mosaic lowering regression, a geometry
-# constraint on an unusual grid), the run degrades one link down this
-# chain — trading measured speed for availability — instead of dying.
-# Every chain terminates at "scatter" (the always-correct XLA
-# scatter/gather oracle, engine object None). Consumed by
-# models.shell3d.build_engine_with_fallback; pinned by
-# tests/test_resilience.py with monkeypatched failures.
-
-ENGINE_FALLBACKS = {
-    "pallas_packed": "packed",
-    "hybrid_bf16": "packed_bf16",
-    "hybrid_packed_bf16": "packed_bf16",   # alias of hybrid_bf16
-    "packed_bf16": "packed",
-    "packed3_bf16": "packed3",
-    "packed3": "packed",
-    "packed": "scatter",
-    "pallas": "mxu",
-    "mxu_bf16": "mxu",
-    "mxu": "scatter",
-}
-
-
-def normalize_engine_name(name) -> str:
-    """Map the ``use_fast_interaction`` vocabulary (True/False/str) to
-    a canonical registry name."""
-    if name is True:
-        return "mxu"
-    if name is False or name is None or name == "scatter":
-        return "scatter"
-    return str(name).lower()
-
-
-def fallback_chain(name):
-    """The degradation order starting AT ``name`` (inclusive), ending
-    at "scatter". Raises KeyError for unknown engine names."""
-    cur = normalize_engine_name(name)
-    chain = [cur]
-    while cur != "scatter":
-        cur = ENGINE_FALLBACKS[cur]
-        chain.append(cur)
-    return chain
-
-
-def record_engine_fallback(failed: str, to: str) -> None:
-    """Publish one ENGINE_FALLBACKS degradation onto the telemetry bus
-    (labeled by the failed engine and its replacement). Called by
-    ``models.shell3d.build_engine_with_fallback`` next to the warning
-    it already emits — the warning tells a human once, the counter
-    makes the degradation visible in every later ledger snapshot."""
-    from ibamr_tpu import obs
-
-    obs.counter("engine_fallbacks_total",
-                engine=normalize_engine_name(failed),
-                to=normalize_engine_name(to)).inc()

@@ -1,39 +1,35 @@
-"""Pallas TPU kernel for the bucketed spread (SURVEY.md §7.3 #1).
+"""Pallas TPU programs for the occupancy-packed spread and interpolate.
 
-Reference parity: the Fortran ``lagrangian_ib4_spread_3d`` inner loop
-(T2/P23) — the north-star scatter. The framework already has two
-formulations: the XLA scatter-add (ops.interaction) and the MXU
-one-hot-matmul (ops.interaction_fast). This module adds the bespoke
-TPU schedule SURVEY.md names as hard-part #1: markers bucketed by tile
-(reusing interaction_fast's Buckets layout), then ONE Pallas program
-per tile accumulating its (W*W, NZ) dense tile in VMEM — per-marker
-rank-1 outer-product updates on VPU-friendly (169, NZ) shapes, with no
-(B, 169, NZ)-sized HBM intermediate and no scatter at all. The
-periodic overlap-add of the finished tiles reuses
-interaction_fast._overlap_add (pure data movement).
+Reference parity: the Fortran ``lagrangian_ib4_spread_3d`` /
+``lagrangian_ib4_interp_3d`` inner loops (T2/P23), the north-star
+scatter and its adjoint. Beside the XLA scatter-add (ops.interaction),
+the MXU one-hot matmul (ops.interaction_fast) and its chunk-packed
+layout (ops.interaction_packed), this module drives that SAME packed
+layout with hand-written programs: ONE Pallas program per chunk
+accumulating its tile's (W*W, NZ) dense block in VMEM (same-tile chunks
+are consecutive grid steps, so the block stays resident), with no
+weight intermediates in HBM and no scatter at all. The periodic
+overlap-add of the finished tiles reuses interaction_fast._overlap_add
+(pure data movement).
 
 Weights evaluate the SAME delta.get_kernel functions at ALL W tile
 offsets — compact support zeroes everything outside the true stencil,
 so no dynamic slicing (and none of its TPU layout constraints) is
 needed inside the kernel.
 
-Correctness oracle: bitwise-level agreement with ops.interaction.spread
-(tested in interpret mode on CPU).
+Correctness oracle: agreement with ops.interaction.spread /
+interpolate to f32 roundoff (tested in interpret mode on the CPU;
+tests/test_tpu_compile.py compiles the programs for a described v5e).
 
-Wiring status (round 3): BOTH transfers now exist as Pallas programs
-(:class:`PallasSpread3D` + the interp twin in
-:class:`PallasInteraction`), selectable from the flagship model via
-``build_shell_example(use_fast_interaction="pallas")`` and compared
-three-way (scatter / MXU / pallas) by ``bench.py``, in the one
-process that holds the chip. The default production
-engine remains the MXU bucketed formulation until a compiled-TPU
-timing shows the Pallas schedule winning; its intended advantage is
-identical FLOPs with no (B, cap, P) weight intermediates in HBM.
+Two engines: :class:`PallasPackedInteraction` (both directions) and
+:class:`HybridPackedInteraction` (this spread beside the XLA packed
+interpolation with bf16 operands). Neither is what ``auto`` resolves
+to anywhere; PERF.md §6 (PR 30) has their chip readings beside the
+selected engines'.
 """
 
 from __future__ import annotations
 
-import math
 from typing import Optional
 
 import jax
@@ -43,8 +39,7 @@ from jax.experimental import pallas as pl
 from ibamr_tpu.grid import StaggeredGrid
 from ibamr_tpu.ops.delta import Kernel, get_kernel
 from ibamr_tpu.ops.interaction import _centering_offsets
-from ibamr_tpu.ops.interaction_fast import (BucketGeometry, Buckets,
-                                            _overlap_add, _phi_safe)
+from ibamr_tpu.ops.interaction_fast import BucketGeometry, _phi_safe
 
 
 def _marker_weight_preamble(geom: BucketGeometry, grid: StaggeredGrid,
@@ -87,162 +82,6 @@ def _marker_weight_preamble(geom: BucketGeometry, grid: StaggeredGrid,
         return phi(tx), phi(ty), phi(tz)
 
     return weights
-
-
-def _spread_kernel_3d(geom: BucketGeometry, grid: StaggeredGrid,
-                      offs, phi, interpret: bool):
-    """Build the per-tile Pallas program (static closure)."""
-    W0, W1 = geom.width
-    nz = grid.n[2]
-    nb0, nb1 = geom.nblk
-    cap = geom.cap
-    weights = _marker_weight_preamble(geom, grid, offs, phi)
-
-    def kernel(XbT_ref, coef_ref, out_ref):
-        b = pl.program_id(0)
-        bx = b // nb1
-        by = b % nb1
-        Xt = XbT_ref[0]                                # (3, cap)
-        c = coef_ref[0]                                # (1, cap)
-        wx, wy, wz = weights(Xt, bx, by)
-        wzc = wz * c                                   # (nz, cap)
-
-        # out[a*W1 + b, z] = sum_m wx[a,m] wy[b,m] c[m] wz[z,m]
-        for a in range(W0):                            # static unroll
-            rows = jax.lax.dot_general(
-                wy * wx[a:a + 1, :], wzc,
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=out_ref.dtype,
-                precision=jax.lax.Precision.HIGHEST)   # (W1, nz)
-            out_ref[0, a * W1:(a + 1) * W1, :] = rows
-
-    def call(Xb, coef):
-        B = Xb.shape[0]
-        # markers on the lane axis: transpose OUTSIDE the kernel (XLA
-        # handles layout changes; Mosaic must not see them)
-        XbT = jnp.swapaxes(Xb, 1, 2)                   # (B, 3, cap)
-        coefT = coef[:, None, :]                       # (B, 1, cap)
-        return pl.pallas_call(
-            kernel,
-            grid=(B,),
-            in_specs=[
-                pl.BlockSpec((1, 3, cap), lambda b: (b, 0, 0)),
-                pl.BlockSpec((1, 1, cap), lambda b: (b, 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, W0 * W1, nz), lambda b: (b, 0, 0)),
-            out_shape=jax.ShapeDtypeStruct((B, W0 * W1, nz), Xb.dtype),
-            interpret=interpret,
-        )(XbT, coefT)
-
-    return call
-
-
-class PallasSpread3D:
-    """Spread engine: interaction_fast bucketing + a Pallas tile kernel.
-
-    3D only (the north-star configuration); falls back is the caller's
-    concern. ``interpret=True`` runs the same program in the Pallas
-    interpreter (CPU testing).
-    """
-
-    def __init__(self, grid: StaggeredGrid, kernel: Kernel = "IB_4",
-                 tile: int = 8, cap: int = 256,
-                 interpret: Optional[bool] = None):
-        from ibamr_tpu.ops.interaction_fast import make_geometry
-
-        if grid.dim != 3:
-            raise ValueError("PallasSpread3D is 3D-only")
-        self.grid = grid
-        self.kernel: Kernel = kernel
-        self.geom = make_geometry(grid, kernel, tile=tile, cap=cap)
-        if interpret is None:
-            interpret = jax.default_backend() == "cpu"
-        self.interpret = bool(interpret)
-        support, phi0 = get_kernel(kernel)
-        self._phi = _phi_safe(phi0, support)
-
-    def spread(self, F: jnp.ndarray, X: jnp.ndarray, centering,
-               b: Buckets) -> jnp.ndarray:
-        """Spread one scalar channel (N,) -> grid field, exact vs
-        ops.interaction.spread for in-capacity markers (overflow flows
-        through the caller's fallback exactly as in interaction_fast)."""
-        from ibamr_tpu.ops.interaction_fast import (
-            bucketed_channel, spread_overflow_fallbacks)
-
-        geom = self.geom
-        grid = self.grid
-        inv_vol = 1.0 / math.prod(grid.dx)
-        offs = _centering_offsets(grid, centering)
-        coef = bucketed_channel(b, F) * b.wb * inv_vol
-        # accumulate in the caller's dtype (f32 states stay f32; an f64
-        # caller keeps full precision end to end)
-        call = _spread_kernel_3d(geom, grid, offs, self._phi,
-                                 self.interpret)
-        T = call(b.Xb.astype(coef.dtype), coef)
-        T = T.reshape((T.shape[0],) + tuple(geom.width) + (grid.n[2],))
-        out = _overlap_add(geom, grid, T.astype(F.dtype))
-        return spread_overflow_fallbacks(out, b, F, X, grid, centering,
-                                         self.kernel)
-
-    def spread_vel(self, F: jnp.ndarray, X: jnp.ndarray,
-                   b: Buckets) -> tuple:
-        return tuple(self.spread(F[:, d], X, d, b)
-                     for d in range(self.grid.dim))
-
-
-def _interp_kernel_3d(geom: BucketGeometry, grid: StaggeredGrid,
-                      offs, phi, interpret: bool):
-    """Per-tile interp program: contract the extracted tile with ALL
-    cap markers' tensor-product weights in one fused VMEM computation —
-    the gather twin of _spread_kernel_3d. The (P, cap) contraction is a
-    dense dot (MXU); no (B, cap, P) weight intermediate ever reaches
-    HBM (the MXU einsum path materializes two of those)."""
-    W0, W1 = geom.width
-    nz = grid.n[2]
-    nb1 = geom.nblk[1]
-    cap = geom.cap
-    weights = _marker_weight_preamble(geom, grid, offs, phi)
-
-    def kernel(XbT_ref, T_ref, out_ref):
-        # the gather twin of _spread_kernel_3d, same shared weight
-        # preamble: the z-contraction as ONE dot_general, the (a, b)
-        # contraction as a static W0-unroll of sublane reductions.
-        b = pl.program_id(0)
-        bx = b // nb1
-        by = b % nb1
-        Xt = XbT_ref[0]                                # (3, cap)
-        wx, wy, wz = weights(Xt, bx, by)               # (nz, cap) wz
-
-        T = T_ref[0]                                   # (P, nz)
-        # accumulate in the caller's dtype: f64 callers keep full
-        # precision end to end, like the spread twin
-        tmp = jnp.dot(T, wz.astype(T.dtype),
-                      preferred_element_type=T.dtype,
-                      precision=jax.lax.Precision.HIGHEST)  # (P, cap)
-        out = jnp.zeros((1, cap), dtype=T.dtype)
-        for a in range(W0):                            # static unroll
-            blk = tmp[a * W1:(a + 1) * W1, :]          # (W1, cap)
-            inner = jnp.sum(wy.astype(T.dtype) * blk, axis=0,
-                            keepdims=True)             # (1, cap)
-            out = out + wx[a:a + 1, :].astype(T.dtype) * inner
-        out_ref[0] = out
-
-    def call(Xb, T):
-        B = Xb.shape[0]
-        XbT = jnp.swapaxes(Xb, 1, 2)                   # (B, 3, cap)
-        return pl.pallas_call(
-            kernel,
-            grid=(B,),
-            in_specs=[
-                pl.BlockSpec((1, 3, cap), lambda b: (b, 0, 0)),
-                pl.BlockSpec((1, W0 * W1, nz), lambda b: (b, 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, cap), lambda b: (b, 0, 0)),
-            out_shape=jax.ShapeDtypeStruct((B, 1, cap), Xb.dtype),
-            interpret=interpret,
-        )(XbT, T)
-
-    return call
 
 
 def _packed_spread_kernel_3d(geom: BucketGeometry, grid: StaggeredGrid,
@@ -531,69 +370,3 @@ class HybridPackedInteraction:
             b = self.buckets(X, weights=weights)
         return self._xla.interpolate_vel(u, X, weights=weights, b=b)
 
-
-class PallasInteraction:
-    """Drop-in FastInteraction-shaped engine with BOTH transfers as
-    Pallas tile kernels (3D only): spread via :class:`PallasSpread3D`'s
-    program, interp via its gather twin. Selectable from the flagship
-    model with ``use_fast_interaction="pallas"`` and benchmarked
-    three-way (scatter / MXU / Pallas) by bench.py (VERDICT round 2
-    item 5)."""
-
-    def __init__(self, grid: StaggeredGrid, kernel: Kernel = "IB_4",
-                 tile: int = 8, cap: int = 256,
-                 overflow_cap: Optional[int] = None,
-                 interpret: Optional[bool] = None):
-        from ibamr_tpu.ops.interaction_fast import make_geometry
-
-        if grid.dim != 3:
-            raise ValueError("PallasInteraction is 3D-only")
-        self.grid = grid
-        self.kernel: Kernel = kernel
-        self.geom = make_geometry(grid, kernel, tile=tile, cap=cap)
-        self.overflow_cap = overflow_cap
-        if interpret is None:
-            interpret = jax.default_backend() == "cpu"
-        self.interpret = bool(interpret)
-        support, phi0 = get_kernel(kernel)
-        self._phi = _phi_safe(phi0, support)
-        self._spread = PallasSpread3D(grid, kernel=kernel, tile=tile,
-                                      cap=cap, interpret=interpret)
-
-    def buckets(self, X: jnp.ndarray,
-                weights: Optional[jnp.ndarray] = None) -> Buckets:
-        from ibamr_tpu.ops.interaction_fast import bucket_markers
-
-        return bucket_markers(self.geom, self.grid, X, weights=weights,
-                              overflow_cap=self.overflow_cap)
-
-    def interpolate(self, f: jnp.ndarray, X: jnp.ndarray, centering,
-                    b: Buckets) -> jnp.ndarray:
-        from ibamr_tpu.ops.interaction_fast import (
-            _extract_tiles, unbucket_with_overflow)
-
-        geom = self.geom
-        grid = self.grid
-        offs = _centering_offsets(grid, centering)
-        T = _extract_tiles(geom, grid, f)             # (B, P, nz)
-        call = _interp_kernel_3d(geom, grid, offs, self._phi,
-                                 self.interpret)
-        Ub = call(b.Xb.astype(f.dtype), T.astype(f.dtype))[:, 0, :]
-        Ub = Ub * b.wb                                # (B, cap)
-        return unbucket_with_overflow(Ub, b, f, X, grid, centering,
-                                      self.kernel)
-
-    def interpolate_vel(self, u, X: jnp.ndarray,
-                        weights: Optional[jnp.ndarray] = None,
-                        b: Optional[Buckets] = None) -> jnp.ndarray:
-        if b is None:
-            b = self.buckets(X, weights=weights)
-        return jnp.stack([self.interpolate(u[d], X, d, b)
-                          for d in range(self.grid.dim)], axis=-1)
-
-    def spread_vel(self, F: jnp.ndarray, X: jnp.ndarray,
-                   weights: Optional[jnp.ndarray] = None,
-                   b: Optional[Buckets] = None):
-        if b is None:
-            b = self.buckets(X, weights=weights)
-        return self._spread.spread_vel(F, X, b)

@@ -14,8 +14,8 @@ the production answer:
   owner opted in (``record_stats=True``) — the default adds nothing to
   jitted/sharded paths.
 - :func:`escalate_solve` walks a DECLARED fallback chain, mirroring
-  PR 2's ``ENGINE_FALLBACKS`` shape: each level names a cheap recipe
-  (more FGMRES restarts, a longer Krylov basis, a more accurate inner
+  the transfer engines' fallback-chain shape: each level names a cheap
+  recipe (more FGMRES restarts, a longer Krylov basis, a more accurate inner
   preconditioner — the "tighter inner tol" knob) and the walk stops at
   the first level that converges. Level 0 converging returns its
   result untouched (bitwise the plain solve). Any walk past level 0
@@ -119,7 +119,7 @@ def record_solve_stats(sink, sol, solver: str = "",
 
 
 # ---------------------------------------------------------------------------
-# the declared escalation chain (the ENGINE_FALLBACKS shape)
+# the declared escalation chain (the transfer engines' fallback-chain shape)
 # ---------------------------------------------------------------------------
 
 @dataclasses.dataclass(frozen=True)
@@ -143,7 +143,7 @@ ESCALATION_LEVELS: Dict[str, EscalationLevel] = {
         "deep_x4_inner_x2", restarts_scale=4, m_scale=2, inner_scale=2),
 }
 
-# name -> next link (None terminates), mirroring ENGINE_FALLBACKS: one
+# name -> next link (None terminates), like the transfer engines' chain: one
 # flat registry, chains derived by walking it, no cycles by inspection
 ESCALATION_FALLBACKS: Dict[str, Optional[str]] = {
     "base": "restarts_x4",
@@ -202,7 +202,7 @@ class PrecisionDrift(SimulationDiverged):
                 "div_drift": self.div_drift}
 
 
-# level name -> next link (None terminates): the ENGINE_FALLBACKS /
+# level name -> next link (None terminates): the engine-fallback /
 # ESCALATION_FALLBACKS shape, applied to the spectral_dtype knob. The
 # names are exactly the canonical_spectral_dtype aliases, so a level
 # name can be assigned straight onto ``integ.spectral_dtype``.

@@ -57,11 +57,6 @@ class TrialResult:
         return out
 
 
-def _engine_arg(engine: str):
-    # the build_shell_example use_fast_interaction vocabulary
-    return {"scatter": False, "mxu": True}.get(engine, engine)
-
-
 def chunk_callable(integ, length: int):
     """The L-step scan chunk the trial times — one executable per
     (family, length), exactly the dispatch-amortization graph a
@@ -95,7 +90,7 @@ def run_trial(candidate: Candidate, *, n_cells: int = 16,
         integ, state = build_shell_example(
             n_cells=n_cells, n_lat=n_lat, n_lon=n_lon, radius=0.25,
             aspect=1.2, stiffness=1.0, rest_length_factor=0.75,
-            mu=mu, use_fast_interaction=_engine_arg(candidate.engine),
+            mu=mu, use_fast_interaction=candidate.engine,
             spectral_dtype=candidate.spectral_dtype,
             engine_fallback=False)
         fp = aot_cache.step_fingerprint(integ)

@@ -9,9 +9,6 @@ optional compile probe for the Pallas family:
   blocks the xy plane in 8-tiles and needs the ``make_geometry``
   minimum extent (``tile + support + 1``), the same facts
   ``default_rule`` promotes on;
-- **packed3 z tile** — the z-blocked layout additionally needs a
-  valid z tile (16 or 8 dividing the z extent with footprint room) —
-  ``shell3d.construct_transfer_engine`` raises on exactly this;
 - **wall-BC bf16 refusal** — the bf16/split-real spectral transform
   path is periodic-only; a non-periodic config prunes every
   ``spectral_dtype="bf16"`` candidate instead of timing a
@@ -19,8 +16,9 @@ optional compile probe for the Pallas family:
 - **Pallas compile probe** — the Pallas-backed engines have failed to
   compile in the field (the round-2 remote-compile stall); with a
   ``probe_fn`` the enumeration trace+compiles each Pallas candidate
-  through the PR-2 probe machinery
-  (``shell3d.probe_transfer_engine``) and prunes the ones that die.
+  through the build-time probe
+  (``engine_resolver.probe_transfer_engine``) and prunes the ones
+  that die.
 
 The marker-count heuristic (``n_markers >= 4096``) is deliberately
 NOT a pruning rule: it is exactly the hand-tuned promotion threshold
@@ -33,22 +31,13 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Callable, Optional, Sequence, Tuple
 
-from ibamr_tpu.models.engine_resolver import RESOLVED_ENGINES
+from ibamr_tpu.models.engine_resolver import (
+    PROBED_ENGINES, RESOLVED_ENGINES, construct_transfer_engine,
+    probe_transfer_engine)
 
-# the default searched engine menu: the r5 shootout set. hybrid
-# aliases and "pallas" (superseded by pallas_packed at every measured
-# size) stay out of the default menu but remain valid --engines args.
-DEFAULT_ENGINES = ("scatter", "packed", "packed_bf16", "pallas_packed",
-                   "packed3", "packed3_bf16", "mxu", "mxu_bf16")
-
-# engines whose compile path has actually failed in the field — gated
-# by a compile probe when one is supplied (shell3d._PROBED_ENGINES
-# plus plain "pallas")
-PROBED_ENGINES = frozenset(
-    {"pallas", "pallas_packed", "hybrid_packed", "hybrid_packed_bf16",
-     "hybrid_bf16"})
-
-_PACKED3 = ("packed3", "packed3_bf16")
+# the default searched engine menu: every row of the resolver's table,
+# in the table's order
+DEFAULT_ENGINES = RESOLVED_ENGINES
 
 
 @dataclass(frozen=True)
@@ -74,13 +63,6 @@ def _engine_eligible(engine: str, n: Sequence[int],
     if not all(v >= 8 + support + 1 for v in n[:-1]):
         return (f"xy extents {tuple(n[:-1])} below the make_geometry "
                 f"minimum (tile + support + 1 = {8 + support + 1})")
-    if engine in _PACKED3:
-        tz = next((t for t in (16, 8)
-                   if n[-1] % t == 0 and n[-1] >= t + support + 1
-                   and t >= support + 1), None)
-        if tz is None:
-            return (f"no valid z tile for n_z = {n[-1]} "
-                    f"(need 8 or 16 dividing it with footprint room)")
     return None
 
 
@@ -139,13 +121,11 @@ def make_probe_fn(n: Sequence[int], n_lat: int, n_lon: int,
                   kernel: str = "IB_4") -> Callable[[str], None]:
     """The real compile probe: construct the engine against the actual
     grid + a representative shell lattice and trace+compile a
-    bucket/spread/interp composition (the PR-2 fallback machinery's
+    bucket/spread/interp composition (the fallback machinery's
     build-time check). Raises on construction or compile failure."""
     def probe(engine: str) -> None:
         from ibamr_tpu.grid import StaggeredGrid
-        from ibamr_tpu.models.shell3d import (construct_transfer_engine,
-                                              make_spherical_shell,
-                                              probe_transfer_engine)
+        from ibamr_tpu.models.shell3d import make_spherical_shell
 
         grid = StaggeredGrid(n=tuple(int(v) for v in n),
                              x_lo=(0.0,) * len(n), x_up=(1.0,) * len(n))

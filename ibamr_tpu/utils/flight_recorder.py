@@ -97,13 +97,8 @@ def canonicalize(obj):
 def _engine_label(val) -> Optional[str]:
     """Normalize an engine selection value (the ``use_fast_interaction``
     vocabulary) to a stable string label."""
-    if val is None:
-        return "auto"
-    if val is True:
-        return "mxu"
-    if val is False:
-        return "scatter"
-    return str(val)
+    from ibamr_tpu.models.engine_resolver import normalize_engine_name
+    return "auto" if val is None else normalize_engine_name(val)
 
 
 def describe_integrator(integ) -> dict:
@@ -350,7 +345,7 @@ class FlightRecorder:
         if label is None:
             return None, None
         try:
-            from ibamr_tpu.ops.interaction_packed import fallback_chain
+            from ibamr_tpu.models.engine_resolver import fallback_chain
             return label, list(fallback_chain(label))
         except Exception:
             return label, None

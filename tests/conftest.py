@@ -78,7 +78,6 @@ def _bound_xla_state_per_module():
 
 SLOW_FILES = {
     "test_lagrangian_sharded.py",   # ~29 min total: sharded-marker suites
-    "test_pallas_interaction.py",   # Pallas interpret mode: ~4 min on CPU
     "test_pallas_packed.py",        # Pallas interpret mode: ~3 min on CPU
 }
 
@@ -95,7 +94,6 @@ SLOW_TESTS = {
     "test_elastic_disc_relaxes",
     "test_ib_shell3d_sharded_matches_single",
     "test_sharded_multilevel_matches_single_device",
-    "test_pallas_spread_overflow_fallback",
     "test_membrane_in_refined_box_tracks_uniform_fine",
     "test_shell_step_fast_matches_scatter",
     "test_wall_bounded_ins_sharded_matches_single",
@@ -103,7 +101,6 @@ SLOW_TESTS = {
     "test_two_level_ib_sharded_matches_single",
     "test_vc_poisson_3d",
     "test_straight_rod_zero_strain",
-    "test_pallas_spread_matches_scatter",
     "test_falling_drop_volume_and_symmetry",
     "test_fac_3d_smoke",
     "test_total_force_and_torque_balance",
@@ -122,7 +119,6 @@ SLOW_TESTS = {
     "test_packed_spread_vjp_matches_fd",
     "test_gib_twisted_rod_relaxes",
     "test_project_vc_divergence_free",
-    "test_pallas_total_force_conserved",
     "test_3d_channel_smoke",
     "test_matches_scatter_path",
     "test_f32_convergence_regression",
@@ -247,7 +243,6 @@ SLOW_TESTS = {
     "test_fleet_smoke_drill_end_to_end",
     "test_sliced_capsule_replays_bitwise",
     "test_open_outlet_hydrostatic_quiescence",
-    "test_shell_engine_knob_and_step",
     "test_walled_momentum_wall_shear_sign",
     "test_hybrid_in_flagship_model",
     "test_failed_engine_degrades_and_matches_fallback",

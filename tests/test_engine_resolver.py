@@ -15,6 +15,7 @@ import pytest
 from ibamr_tpu.models.engine_resolver import (ENV_ENGINE, ENV_TUNING_DB,
                                               RESOLVED_ENGINES,
                                               default_rule,
+                                              fallback_chain,
                                               load_tuning_db,
                                               resolve_engine)
 
@@ -33,8 +34,8 @@ def test_default_rule_promotion_band():
 
 
 def test_env_override_wins_and_validates():
-    env = {ENV_ENGINE: "packed3"}
-    assert resolve_engine((8, 8, 8), 10, _SUPPORT, env=env) == "packed3"
+    env = {ENV_ENGINE: "packed"}
+    assert resolve_engine((8, 8, 8), 10, _SUPPORT, env=env) == "packed"
     # "auto"/empty defer to the rest of the chain
     assert resolve_engine((8, 8, 8), 10, _SUPPORT,
                           env={ENV_ENGINE: "auto"}) == "scatter"
@@ -50,22 +51,22 @@ def test_env_override_wins_and_validates():
 def test_tuning_db_most_specific_wins(tmp_path):
     db = tmp_path / "tuning.json"
     db.write_text(json.dumps({"schema": 1, "entries": [
-        {"engine": "packed3", "n_cells": 256},
+        {"engine": "packed", "n_cells": 256},
         # generic marker-band entry FIRST...
         {"engine": "mxu", "markers_min": 50, "markers_max": 500},
         # ...but the later, MORE SPECIFIC entry wins the overlap:
         # file order is not load-bearing for differently-specific
         # entries (the PR-12 first-match order-dependence is gone)
-        {"engine": "packed3_bf16", "n_cells": 64,
+        {"engine": "packed_bf16", "n_cells": 64,
          "markers_min": 50, "markers_max": 500},
     ]}))
     env = {ENV_TUNING_DB: str(db)}
     assert resolve_engine((256, 256, 256), 10_000, _SUPPORT,
-                          env=env) == "packed3"
+                          env=env) == "packed"
     # overlap: both the mxu band and the n_cells=64 entry match;
     # higher specificity (n_cells + band > band alone) wins
     assert resolve_engine((64, 64, 64), 100, _SUPPORT,
-                          env=env) == "packed3_bf16"
+                          env=env) == "packed_bf16"
     # off the pinned n_cells, the generic band entry still serves
     assert resolve_engine((32, 32, 32), 100, _SUPPORT,
                           env=env) == "mxu"
@@ -75,14 +76,14 @@ def test_tuning_db_most_specific_wins(tmp_path):
     # env override outranks the DB
     assert resolve_engine((256, 256, 256), 10_000, _SUPPORT,
                           env={ENV_TUNING_DB: str(db),
-                               ENV_ENGINE: "pallas"}) == "pallas"
+                               ENV_ENGINE: "packed_bf16"}) == "packed_bf16"
 
 
 def test_tuning_db_equal_specificity_keeps_file_order(tmp_path):
     db = tmp_path / "tuning.json"
     db.write_text(json.dumps({"schema": 1, "entries": [
         {"engine": "mxu", "markers_min": 50, "markers_max": 500},
-        {"engine": "packed3", "markers_min": 40, "markers_max": 600},
+        {"engine": "packed", "markers_min": 40, "markers_max": 600},
     ]}))
     # both match at score 2 -> the deterministic tiebreak is file
     # order (earlier wins), never dict-iteration accident
@@ -94,7 +95,7 @@ def test_tuning_db_platform_and_provenance_gates(tmp_path):
     db = tmp_path / "tuning.json"
     db.write_text(json.dumps({"schema": 1, "entries": [
         # platform match-field pin: only serves tpu queries
-        {"engine": "packed3", "platform": "tpu"},
+        {"engine": "packed", "platform": "tpu"},
         # provenance pin: measured on tpu, must not steer cpu runs
         {"engine": "mxu", "markers_min": 50, "markers_max": 500,
          "provenance": {"platform": "tpu", "timestamp": "2026-08-06"}},
@@ -106,7 +107,7 @@ def test_tuning_db_platform_and_provenance_gates(tmp_path):
     # an explicit tpu query reaches them (10 markers: outside the mxu
     # band, so the platform-pinned entry serves)
     assert resolve_engine((64, 64, 64), 10, _SUPPORT, env=env,
-                          platform="tpu") == "packed3"
+                          platform="tpu") == "packed"
     # cpu provenance serves cpu queries
     db.write_text(json.dumps({"schema": 1, "entries": [
         {"engine": "mxu", "markers_min": 50, "markers_max": 500,
@@ -182,3 +183,87 @@ def test_explicit_engine_stamped_too():
                                    rest_length_factor=0.75, mu=0.05,
                                    use_fast_interaction=False)
     assert integ.ib.engine_name == "scatter"
+
+
+# ---------------------------------------------------------------------------
+# the one table: every row, the pin of what auto means, and its owner
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("name", RESOLVED_ENGINES)
+def test_every_table_row_builds_and_is_accepted_everywhere(name):
+    """A row of ENGINES is a whole engine: it builds at 16^3 (Pallas
+    rows in interpret mode, as on any CPU), degrades to scatter, and is
+    a valid value of the input key, the keyword and the autotuner's
+    menu."""
+    from ibamr_tpu.models.shell3d import build_shell_example
+    from ibamr_tpu.tune.space import DEFAULT_ENGINES, enumerate_space
+    from ibamr_tpu.utils.input_db import parse_input_string
+
+    chain = fallback_chain(name)
+    assert chain[0] == name and chain[-1] == "scatter"
+    assert len(chain) == len(set(chain))
+    integ, _ = build_shell_example(n_cells=16, n_lat=8, n_lon=8,
+                                   use_fast_interaction=name)
+    assert integ.ib.engine_name == name
+    assert (integ.ib.fast is None) == (name == "scatter")
+    db = parse_input_string(f'''
+CartesianGeometry {{ n_cells = 16, 16, 16 }}
+Shell {{ n_lat = 8 n_lon = 8 }}
+IBMethod {{ transfer_engine = "{name}" }}
+''')
+    integ2, _ = build_shell_example(input_db=db)
+    assert integ2.ib.engine_name == name
+    assert type(integ2.ib.fast) is type(integ.ib.fast)
+    assert name in DEFAULT_ENGINES
+    cands, pruned = enumerate_space((16, 16, 16), 64, 4, engines=(name,),
+                                    spectral_dtypes=("f32",),
+                                    chunk_lengths=(1,))
+    assert [c.engine for c in cands] == [name] and not pruned
+
+
+@pytest.mark.parametrize("n,platform,want", [
+    (128, "tpu", "packed"),
+    (256, "tpu", "packed_bf16"),
+    (128, "cpu", "packed"),
+    (256, "cpu", "packed"),
+])
+def test_committed_db_resolves_the_benchmark_cells(n, platform, want):
+    """What ``auto`` means for the two shell configurations of
+    BENCHMARK.json under the committed TUNING_DB.json: the engine each
+    cell's ledger lines were measured on."""
+    assert resolve_engine((n,) * 3, 99856, 4, env={},
+                          spectral_dtype="f32",
+                          platform=platform) == want
+
+
+def test_ops_knows_no_engine_names():
+    """ops/ holds the engines; which exist, what each falls back to and
+    which get probed is models/engine_resolver.py's alone."""
+    import pathlib
+
+    import ibamr_tpu.ops as ops
+
+    for src in pathlib.Path(ops.__path__[0]).glob("*.py"):
+        text = src.read_text()
+        for word in ("ENGINE_FALLBACKS", "normalize_engine_name",
+                     "fallback_chain", '"packed_bf16"', '"pallas_packed"',
+                     '"hybrid_bf16"', '"mxu"'):
+            assert word not in text, (src.name, word)
+
+
+@pytest.mark.parametrize("name", ["pallas", "hybrid", "packed_f16", "fast"])
+def test_name_outside_the_table_is_refused_everywhere(name):
+    """A name that is no row (``pallas`` was one until PR 30;
+    docs/MIGRATING.md names the survivor for each that left) is refused
+    by the override, the keyword and the input key alike."""
+    from ibamr_tpu.models.shell3d import build_shell_example
+    from ibamr_tpu.utils.input_db import parse_input_string
+
+    with pytest.raises(ValueError, match="unknown transfer engine"):
+        resolve_engine((64, 64, 64), 10, _SUPPORT, env={ENV_ENGINE: name})
+    with pytest.raises(ValueError, match="use_fast_interaction"):
+        build_shell_example(n_cells=16, n_lat=8, n_lon=8,
+                            use_fast_interaction=name)
+    with pytest.raises(ValueError, match="transfer_engine"):
+        build_shell_example(input_db=parse_input_string(
+            f'IBMethod {{ transfer_engine = "{name}" }}'))

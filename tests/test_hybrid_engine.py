@@ -76,7 +76,7 @@ def test_hybrid_in_flagship_model():
 
     integ, state = build_shell_example(
         n_cells=16, n_lat=16, n_lon=16, radius=0.25,
-        use_fast_interaction="hybrid_packed_bf16")
+        use_fast_interaction="hybrid_bf16")
     step = jax.jit(lambda s, d: integ.step(s, d))
     s1 = step(state, 1e-4)
     assert bool(jnp.isfinite(s1.X).all())
@@ -91,10 +91,9 @@ def test_hybrid_in_flagship_model():
 
 
 def test_hybrid_bf16_registry_name():
-    """``hybrid_bf16`` is the canonical registry/knob name of the
-    pallas-spread + bf16-interp engine (``hybrid_packed_bf16`` stays
-    as an alias); both the python arg and the reference-style input
-    knob must build the same configuration."""
+    """``hybrid_bf16`` is the one name of the pallas-spread +
+    bf16-interp engine; both the python arg and the reference-style
+    input knob must build the same configuration."""
     from ibamr_tpu.models.shell3d import build_shell_example
     from ibamr_tpu.utils.input_db import parse_input_string
 

@@ -168,32 +168,29 @@ def test_bf16_compute_matches_f32_within_tolerance():
     u = tuple(jnp.asarray(rng.standard_normal(g.n), jnp.float32)
               for _ in range(3))
 
-    from ibamr_tpu.ops.interaction_fast import FastInteraction
-    for mk in (lambda **kw: FastInteraction(g, tile=8, cap=256, **kw),
-               lambda **kw: PackedInteraction(g, tile=8, chunk=128,
-                                              nchunks=64, **kw)):
-        exact = mk()
-        comp = mk(compute_dtype=jnp.bfloat16)
-        f0 = exact.spread_vel(F, X)
-        f1 = comp.spread_vel(F, X)
-        scale = max(float(jnp.max(jnp.abs(c))) for c in f0)
-        err = max(float(jnp.max(jnp.abs(a - b)))
-                  for a, b in zip(f0, f1))
-        assert err < 8e-3 * scale, (type(exact).__name__, err, scale)
+    exact = PackedInteraction(g, tile=8, chunk=128, nchunks=64)
+    comp = PackedInteraction(g, tile=8, chunk=128, nchunks=64,
+                             compute_dtype=jnp.bfloat16)
+    f0 = exact.spread_vel(F, X)
+    f1 = comp.spread_vel(F, X)
+    scale = max(float(jnp.max(jnp.abs(c))) for c in f0)
+    err = max(float(jnp.max(jnp.abs(a - b)))
+              for a, b in zip(f0, f1))
+    assert err < 8e-3 * scale, (err, scale)
 
-        U0 = exact.interpolate_vel(u, X)
-        U1 = comp.interpolate_vel(u, X)
-        uscale = float(jnp.max(jnp.abs(U0)))
-        uerr = float(jnp.max(jnp.abs(U0 - U1)))
-        assert uerr < 8e-3 * uscale, (type(exact).__name__, uerr)
+    U0 = exact.interpolate_vel(u, X)
+    U1 = comp.interpolate_vel(u, X)
+    uscale = float(jnp.max(jnp.abs(U0)))
+    uerr = float(jnp.max(jnp.abs(U0 - U1)))
+    assert uerr < 8e-3 * uscale, uerr
 
-        # adjointness at bf16 tolerance: <spread(F), u> == <F, interp(u)>
-        lhs = sum(float(jnp.sum(a * b)) for a, b in
-                  zip(comp.spread_vel(F, X), u))
-        rhs = float(jnp.sum(F * comp.interpolate_vel(u, X))) \
-            / float(np.prod(g.dx))
-        assert abs(lhs - rhs) < 2e-2 * max(abs(lhs), abs(rhs), 1e-6), \
-            (lhs, rhs)
+    # adjointness at bf16 tolerance: <spread(F), u> == <F, interp(u)>
+    lhs = sum(float(jnp.sum(a * b)) for a, b in
+              zip(comp.spread_vel(F, X), u))
+    rhs = float(jnp.sum(F * comp.interpolate_vel(u, X))) \
+        / float(np.prod(g.dx))
+    assert abs(lhs - rhs) < 2e-2 * max(abs(lhs), abs(rhs), 1e-6), \
+        (lhs, rhs)
 
 
 def test_transfer_engine_input_key():
@@ -214,7 +211,7 @@ IBMethod {{ transfer_engine = "{eng}" }}
     for eng, cls in (("packed", "PackedInteraction"),
                      ("scatter", "NoneType"),
                      ("mxu", "FastInteraction"),
-                     ("mxu_bf16", "FastInteraction")):
+                     ("packed_bf16", "PackedInteraction")):
         integ, _ = build_shell_example(input_db=db_for(eng))
         assert type(integ.ib.fast).__name__ == cls, eng
     with pytest.raises(ValueError, match="transfer_engine"):

@@ -307,7 +307,7 @@ def test_supervisor_preemption_writes_final_checkpoint(tmp_path):
 # ---------------------------------------------------------------------------
 
 def test_engine_fallback_vocabulary():
-    from ibamr_tpu.ops.interaction_packed import (ENGINE_FALLBACKS,
+    from ibamr_tpu.models.engine_resolver import (ENGINES,
                                                   fallback_chain,
                                                   normalize_engine_name)
 
@@ -319,7 +319,7 @@ def test_engine_fallback_vocabulary():
     assert fallback_chain("pallas_packed") == [
         "pallas_packed", "packed", "scatter"]
     assert fallback_chain("scatter") == ["scatter"]
-    for name in ENGINE_FALLBACKS:
+    for name in ENGINES:
         chain = fallback_chain(name)
         assert chain[-1] == "scatter"
         assert len(chain) == len(set(chain))        # no cycles
@@ -730,7 +730,7 @@ def test_health_rollback_before_any_nan(tmp_path):
 # ---------------------------------------------------------------------------
 
 def test_escalation_chain_vocabulary():
-    """The chain registry mirrors ENGINE_FALLBACKS: one flat name->next
+    """The chain registry mirrors the transfer engines': one flat name->next
     dict, chains derived by walking it, terminal level ends every walk,
     no cycles, unknown names raise."""
     assert [l.name for l in escalation_chain()] == [

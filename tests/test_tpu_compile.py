@@ -30,8 +30,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from ibamr_tpu.grid import StaggeredGrid
+from ibamr_tpu.models.engine_resolver import construct_transfer_engine
 from ibamr_tpu.models.shell3d import (build_shell_example,
-                                      construct_transfer_engine,
                                       make_spherical_shell)
 
 HBM_BYTES = 16e9          # one v5e chip
@@ -76,8 +76,7 @@ def _compiled_mode(fast):
     classes choose it from ``jax.default_backend()``, which is the CPU
     here): flip every ``interpret`` flag the engine carries."""
     flipped = 0
-    for obj in (fast, getattr(fast, "_pal", None),
-                getattr(fast, "_spread", None)):
+    for obj in (fast, getattr(fast, "_pal", None)):
         if obj is not None and hasattr(obj, "interpret"):
             obj.interpret = False
             flipped += 1
@@ -102,10 +101,8 @@ def _engine(name, n):
 
 @pytest.mark.parametrize("name,op,n", [
     ("pallas_packed", "interp", 256),
-    ("pallas", "interp", 256),
     ("pallas_packed", "spread", 64),
     ("hybrid_bf16", "spread", 64),
-    ("pallas", "spread", 64),
 ])
 def test_transfer_kernel_compiles(one_chip, name, op, n):
     fast, X, b = _engine(name, n)

@@ -35,7 +35,7 @@ _SUPPORT = 4                          # the real IB_4 half-width
 # ---------------------------------------------------------------------------
 
 def test_space_static_geometry_pruning():
-    engines = ("scatter", "packed", "packed3")
+    engines = ("scatter", "packed", "packed_bf16")
     # non-8-divisible xy: every non-scatter candidate pruned
     cands, pruned = enumerate_space((12, 12, 12), 4096, _SUPPORT,
                                     engines=engines,
@@ -43,14 +43,19 @@ def test_space_static_geometry_pruning():
                                     chunk_lengths=(1,))
     assert {c.engine for c in cands} == {"scatter"}
     assert all("8-tile" in r for c, r in pruned)
-    # eligible xy but no valid packed3 z tile (12 % 8 == 4)
+    # 8-divisible xy below the make_geometry minimum extent
+    cands, pruned = enumerate_space((8, 8, 12), 4096, _SUPPORT,
+                                    engines=engines,
+                                    spectral_dtypes=("f32",),
+                                    chunk_lengths=(1,))
+    assert {c.engine for c in cands} == {"scatter"}
+    assert all("minimum" in r for c, r in pruned)
+    # eligible xy: the z extent is not blocked, so 12 serves
     cands, pruned = enumerate_space((16, 16, 12), 4096, _SUPPORT,
                                     engines=engines,
                                     spectral_dtypes=("f32",),
                                     chunk_lengths=(1,))
-    assert {c.engine for c in cands} == {"scatter", "packed"}
-    assert any("z tile" in r for c, r in pruned
-               if c.engine == "packed3")
+    assert {c.engine for c in cands} == set(engines) and not pruned
     # every grid point is accounted for, nothing silently dropped
     total = len(engines) * 1 * 1
     assert len(cands) + len(pruned) == total
@@ -130,10 +135,10 @@ def test_trial_through_cache_second_is_hit():
 
 
 def test_trial_build_failure_reported_not_raised():
-    # packed3 has no valid z tile at n_z=12 and the trial builds with
+    # the 8-tile does not divide n = 12 and the trial builds with
     # engine_fallback=False — the error must land in the result, the
     # grid must survive
-    res = run_trial(Candidate(engine="packed3"), n_cells=12, n_lat=6,
+    res = run_trial(Candidate(engine="packed"), n_cells=12, n_lat=6,
                     n_lon=8, reps=1)
     assert res.error is not None
     assert res.steps_per_s == 0.0
@@ -164,7 +169,7 @@ def test_db_validation_rejects_bad_shapes():
         {"engine": "packed", "markers_min": 500, "markers_max": 100},
         {"engine": "mxu", "n_cells": "big"},
         {"engine": "scatter", "measured": {"steps_per_s": "fast"}},
-        {"engine": "packed3", "provenance": {"timestamp": "x"}},
+        {"engine": "packed_bf16", "provenance": {"timestamp": "x"}},
     ]}
     problems = tdb.validate_db(doc)
     assert any("schema" in p for p in problems)
@@ -209,7 +214,7 @@ def test_db_shadow_lint_flags_dead_entries():
         # query entry[1] matches, entry[0] wins the file-order tie
         {"engine": "packed", "markers_min": 100, "markers_max": 400},
         # NOT shadowed: matches queries outside the band too
-        {"engine": "packed3", "n_cells": 64},
+        {"engine": "packed_bf16", "n_cells": 64},
     ]
     shadows = tdb.shadowed_entries(entries)
     assert [(j, i) for j, i, _ in shadows] == [(1, 0)]

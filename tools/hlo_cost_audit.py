@@ -18,7 +18,7 @@ counts:
 
 Every leg runs in its own child process (the XLA CPU pipeline has a
 rare native-crash flake; an isolated leg loses one data point, not the
-artifact). Results land in ``HLO_COST_r06.json`` and feed PERF.md.
+artifact). Results land in the file ``--out`` names.
 Round 6 adds an FFT census per leg (batched-transform call count +
 per-transform bytes at the jaxpr primitive level) and the fluid trio
 (``fluid`` fused / ``fluid_chained`` pre-fusion / ``fluid_bf16``
@@ -281,13 +281,8 @@ def run_leg(n, n_lat, n_lon, engine, piece, timeout_s):
 ENGINES = {
     "scatter": False,
     "mxu": True,
-    "mxu_bf16": "mxu_bf16",
     "packed": "packed",
     "packed_bf16": "packed_bf16",
-    # round 5: fully-blocked (z-tiled) packing + spill-folding
-    # overlap-add (ops.interaction_packed3)
-    "packed3": "packed3",
-    "packed3_bf16": "packed3_bf16",
     # round 6: pallas-spread + bf16-interp hybrid (XLA legs only — the
     # pallas spread has no CPU cost model; see module docstring)
     "hybrid_bf16": "hybrid_bf16",
@@ -302,8 +297,7 @@ def main() -> int:
     ap.add_argument("--quick-n", type=int, default=64,
                     help="small cross-check size (0 disables)")
     ap.add_argument("--timeout", type=float, default=2400.0)
-    ap.add_argument("--out", type=str,
-                    default=os.path.join(REPO, "HLO_COST_r06.json"))
+    ap.add_argument("--out", type=str, required=True)
     ap.add_argument("--engines", type=str, default="",
                     help="comma-separated engine subset (default all)")
     ap.add_argument("--pieces", type=str, default="",
@@ -332,11 +326,10 @@ def main() -> int:
                 pieces = ["spread", "interp"]
                 if eng is not False:
                     pieces.append("bucket_prep")
-            if label in ("packed", "mxu", "packed3"):
+            if label in ("packed", "mxu"):
                 pieces.append("transfers_fused")
-            if label in ("packed", "packed3"):
-                pieces.append("step")
             if label == "packed":
+                pieces.append("step")
                 # the fluid trio (whole ins.step) plus the isolated
                 # substep trio (the solve alone): fused plan path vs
                 # the pre-fusion chain vs the bf16 transform path —

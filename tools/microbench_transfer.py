@@ -133,13 +133,7 @@ def main():
     t_pinterp3 = timeit(jax.jit(
         lambda: peng.interpolate_vel(u, X, b=pb)), r)
 
-    # bf16-compressed twins (operand HBM traffic halved)
-    engb = fast.FastInteraction(grid, tile=args.tile, cap=cap,
-                                overflow_cap=max(2048, N // 4),
-                                compute_dtype=jnp.bfloat16)
-    t_bspread3 = timeit(jax.jit(lambda: engb.spread_vel(F, X, b=b)), r)
-    t_binterp3 = timeit(jax.jit(
-        lambda: engb.interpolate_vel(u, X, b=b)), r)
+    # bf16-compressed twin (operand HBM traffic halved)
     pengb = packed.PackedInteraction(grid, tile=args.tile, chunk=128,
                                      nchunks=Q,
                                      overflow_cap=max(2048, N // 4),
@@ -148,33 +142,6 @@ def main():
                          r)
     t_pbinterp3 = timeit(jax.jit(
         lambda: pengb.interpolate_vel(u, X, b=pb)), r)
-
-    # fully-blocked packed3: z-tiled chunks + spill-folding overlap-add
-    from ibamr_tpu.ops import interaction_packed3 as packed3
-
-    tz = 16 if grid.n[-1] % 16 == 0 else 8
-    Q3 = packed3.suggest_chunks3(grid, s.vertices, tile=args.tile,
-                                 tile_last=tz, chunk=64)
-    p3eng = packed3.PackedInteraction3(grid, tile=args.tile,
-                                       tile_last=tz, chunk=64,
-                                       nchunks=Q3,
-                                       overflow_cap=max(2048, N // 4))
-    p3b = jax.jit(p3eng.buckets)(X)
-    print(f"packed3: Q={Q3} slots={Q3 * 64} util={N / (Q3 * 64):.3f} "
-          f"overflow={int(jnp.sum(p3b.w_overflow > 0))}")
-    t_p3bucket = timeit(jax.jit(lambda: p3eng.buckets(X)), r)
-    t_p3spread3 = timeit(jax.jit(lambda: p3eng.spread_vel(F, X, b=p3b)), r)
-    t_p3interp3 = timeit(jax.jit(
-        lambda: p3eng.interpolate_vel(u, X, b=p3b)), r)
-    p3engb = packed3.PackedInteraction3(grid, tile=args.tile,
-                                        tile_last=tz, chunk=64,
-                                        nchunks=Q3,
-                                        overflow_cap=max(2048, N // 4),
-                                        compute_dtype=jnp.bfloat16)
-    t_p3bspread3 = timeit(jax.jit(
-        lambda: p3engb.spread_vel(F, X, b=p3b)), r)
-    t_p3binterp3 = timeit(jax.jit(
-        lambda: p3engb.interpolate_vel(u, X, b=p3b)), r)
 
     # pallas-packed: same chunk layout, Pallas tile programs
     t_ppspread3 = t_ppinterp3 = None
@@ -221,15 +188,8 @@ def main():
           f"hit={refresh_hit})")
     print(f"packed spread 3ch {t_pspread3:8.2f} ms")
     print(f"packed interp 3ch {t_pinterp3:8.2f} ms")
-    print(f"mxu-bf16 sprd 3ch {t_bspread3:8.2f} ms")
-    print(f"mxu-bf16 intp 3ch {t_binterp3:8.2f} ms")
     print(f"pk-bf16 sprd 3ch  {t_pbspread3:8.2f} ms")
     print(f"pk-bf16 intp 3ch  {t_pbinterp3:8.2f} ms")
-    print(f"packed3 bucket    {t_p3bucket:8.2f} ms")
-    print(f"packed3 sprd 3ch  {t_p3spread3:8.2f} ms")
-    print(f"packed3 intp 3ch  {t_p3interp3:8.2f} ms")
-    print(f"p3-bf16 sprd 3ch  {t_p3bspread3:8.2f} ms")
-    print(f"p3-bf16 intp 3ch  {t_p3binterp3:8.2f} ms")
     if t_ppspread3 is not None:
         print(f"pallas-pk sprd 3c {t_ppspread3:8.2f} ms")
         print(f"pallas-pk intp 3c {t_ppinterp3:8.2f} ms")

@@ -415,7 +415,7 @@ def _run_once(manifest, arrays, overrides, dt_scale, sharded=False):
 
 def _norm_engine(label) -> str:
     try:
-        from ibamr_tpu.ops.interaction_packed import normalize_engine_name
+        from ibamr_tpu.models.engine_resolver import normalize_engine_name
         return normalize_engine_name(label)
     except Exception:
         return str(label).lower()
