@@ -62,6 +62,7 @@ from ibamr_tpu.obs.bus import (  # noqa: F401
     current_trace,
     describe,
     detach,
+    detached,
     emit,
     gauge,
     help_for,

@@ -632,6 +632,22 @@ def annotate(span_name: str, **attrs) -> bool:
     return False
 
 
+@contextmanager
+def detached():
+    """Spans opened inside open as ROOTS of this thread, not as children
+    of the spans open around it: for work that belongs to an earlier
+    interval and only runs inside a later one (the run loop's deferred
+    callbacks of chunk k, beside chunk k+1 in flight), so that its path
+    is the one it has when it runs on its own."""
+    st = _stack()
+    held = st[:]
+    del st[:]
+    try:
+        yield
+    finally:
+        st[:] = held
+
+
 def spans() -> list:
     """The closed spans still in the ring, oldest first."""
     return list(_RING)

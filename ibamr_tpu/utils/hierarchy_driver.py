@@ -39,6 +39,19 @@ _STEPS_TOTAL = _obs.counter("driver_steps_total")
 _CHUNK_WALL = _obs.histogram("driver_chunk_wall_seconds")
 _obs.describe("driver_chunk_wall_seconds",
               "Per-chunk wall time including the post-chunk sync.")
+# due viz_fn / checkpoint_fn calls by where they ran: after the next
+# chunk's dispatch (deferred: True) or before it
+_CALLBACKS = {
+    (name, deferred): _obs.counter(
+        "driver_callbacks_deferred_total" if deferred
+        else "driver_callbacks_inline_total", callback=name)
+    for name in ("viz_fn", "checkpoint_fn") for deferred in (True, False)}
+_obs.describe("driver_callbacks_deferred_total",
+              "Due viz/checkpoint callbacks run after the next chunk's "
+              "dispatch, while the device steps.")
+_obs.describe("driver_callbacks_inline_total",
+              "Due viz/checkpoint callbacks run before the next dispatch "
+              "(a regrid due, donated buffers, the last chunk).")
 _REFRESHES_TOTAL = _obs.counter("transfer_refreshes_total")
 _FALLS_TOTAL = _obs.counter("transfer_repack_falls_total")
 _obs.describe("transfer_repack_falls_total",
@@ -285,14 +298,30 @@ class HierarchyDriver:
     The carried chunk's refresh and fall counts leave with the health
     value in the one sync per chunk and land on
     ``transfer_refreshes_total`` / ``transfer_repack_falls_total`` and
-    a ``driver/chunk/refresh`` span. Callbacks (all optional):
+    a ``driver/chunk/refresh`` span. Callbacks (all optional), in the
+    order they run after chunk k's sync and health check:
 
-    - ``viz_fn(state, step)`` at the viz cadence;
-    - ``metrics_fn(state, step) -> dict`` after every chunk (logged by
-      the caller — returned dicts are aggregated into ``self.history``);
-    - ``regrid_fn(state, step) -> state`` at the regrid cadence
-      (host-side retagging — may rebuild sharded placement);
-    - ``checkpoint_fn(state, step)`` at the restart cadence.
+    - ``metrics_fn(state, step) -> dict`` after every chunk, always
+      BEFORE the next dispatch (logged by the caller — returned dicts
+      are aggregated into ``self.history``);
+    - ``viz_fn(state, step)`` at the viz cadence, then
+      ``checkpoint_fn(state, step)`` at the restart cadence. Both only
+      read the post-chunk state, so where nothing forbids it the driver
+      dispatches chunk k+1 FIRST and runs them beside it, on the calling
+      thread, to completion before it syncs chunk k+1: the device steps
+      while files are written, and a checkpoint of step k is on disk
+      before chunk k+1's health is looked at. They run before the next
+      dispatch, as ``metrics_fn`` does, where the loop sees that it must:
+      ``regrid_fn`` is due at this step (chunk k+1 starts from another
+      state), ``cfg.donate`` (chunk k+1 invalidates the buffers they
+      read), or this was the last chunk. A due call of a healthy chunk is
+      never dropped: if the next dispatch raises, it still runs before
+      the exception leaves ``run``. Spans ``driver/viz_fn`` /
+      ``driver/checkpoint_fn`` carry ``deferred``; counters
+      ``driver_callbacks_deferred_total`` / ``_inline_total``. (Device
+      work a deferred callback launches queues behind chunk k+1.)
+    - ``regrid_fn(state, step) -> state`` at the regrid cadence, last
+      (host-side retagging — may rebuild sharded placement).
 
     ``health_probe`` (a :class:`ibamr_tpu.utils.health.HealthProbe`)
     upgrades the per-chunk finite bool to the fused vitals vector at
@@ -335,8 +364,8 @@ class HierarchyDriver:
         self.metrics_fn = metrics_fn
         self.regrid_fn = regrid_fn
         self.checkpoint_fn = checkpoint_fn
-        self.timer = timer                 # TimerManager: scopes ONLY the
-        self.timer_name = timer_name       # jitted advance, not callbacks
+        self.timer = timer                 # TimerManager: scopes the chunk
+        self.timer_name = timer_name       # (dispatch to sync), see run()
         self.health_probe = health_probe
         self.recorder = recorder
         self.shadow_audit = shadow_audit
@@ -608,131 +637,164 @@ class HierarchyDriver:
                                 cfg.restart_interval,
                                 cfg.regrid_interval) if i]
         ordinal = 0                     # chunk ordinal within this run
-        while step < cfg.num_steps:
-            if cfg.cfl is not None:
-                # float() keeps dt a weak-typed Python scalar whichever
-                # branch wins (a device-scalar cfl_dt would otherwise
-                # flip the aval and retrace)
-                dt = float(min(cfg.dt,
-                               self.integ.cfl_dt(state, cfg.cfl)))
-            n = min(cfg.health_interval, cfg.num_steps - step)
-            for i in cadences:               # land exactly on cadences
-                n = min(n, i - step % i)
-            probe = self.health_probe
-            fleet = self.lanes is not None
-            if fleet:
-                snap_dt = self.lane_dt.copy()
-                snap_alive = self.lane_alive.copy()
-                chunk_args = (jnp.asarray(self.lane_dt),
-                              jnp.asarray(self.lane_alive))
-            else:
-                snap_dt, snap_alive = dt, None
-                chunk_args = (dt,)
-            if self.recorder is not None:
-                # host copy of the PRE-chunk state, taken before the
-                # (possibly donated) chunk invalidates its buffers
-                self.recorder.snapshot(state, step=step, dt=snap_dt,
-                                       length=n, integ=self.integ,
-                                       cfg=cfg, alive=snap_alive)
-            t0 = time.perf_counter()
-            # the chunk span brackets dispatch AND the one-per-chunk
-            # host sync; its children split the two. Every span closes
-            # into obs's ring (and the run ledger when one is attached)
-            # and sits on a profiler capture's timeline. Telemetry never
-            # reaches inside the jitted chunk — the *_telemetry graph
-            # contracts pin zero in-scan host transfers with the bus
-            # armed. The TimerManager scope (itself an obs.span) wraps
-            # the same interval from outside.
-            first_call = n not in self._called
-            timed = (self.timer.scope(self.timer_name)
-                     if self.timer is not None
-                     else contextlib.nullcontext())
-            with timed, _obs.span("driver/chunk", step=step, length=n,
-                                  chunk=ordinal):
-                fn = self._chunk(n)
-                if first_call:
-                    # what obs/deviceprof needs to read this program's
-                    # compiled text after the run: shapes, no buffers
-                    # (taken before a donating call deletes them)
-                    self._called.add(n)
-                    _obs.register_program(f"driver/chunk[{n}]",
-                                          self._chunks[n],
-                                          (state,) + chunk_args, steps=n)
-                with _obs.span("dispatch", step=step, chunk=ordinal,
-                               first_call=first_call):
-                    state, health = fn(state, *chunk_args)
-                with _obs.span("sync", step=step, chunk=ordinal):
-                    # one device sync per chunk: the finite bool or the
-                    # fused vitals vector
-                    health = np.asarray(health)
-                if self._carried:
-                    # the carried chunk's tally, off the tail of what
-                    # the sync brought: counters and an event span
-                    refreshes, falls = (int(v) for v in health[-2:])
-                    health = health[:-2]
-                    _REFRESHES_TOTAL.inc(refreshes)
-                    _FALLS_TOTAL.inc(falls)
-                    with _obs.span("refresh", step=step, chunk=ordinal,
-                                   refreshes=refreshes, falls=falls):
-                        pass
-            self.last_chunk_wall_s = time.perf_counter() - t0
-            _CHUNKS_TOTAL.inc()
-            _STEPS_TOTAL.inc(n)
-            _CHUNK_WALL.observe(self.last_chunk_wall_s)
-            # per-chunk counters snapshot + device-memory watermarks,
-            # riding the sync that just happened (no-op when no ledger
-            # is attached)
-            _obs.chunk_boundary(step=step + n,
-                                chunk_wall_s=self.last_chunk_wall_s)
-            if fleet:
-                # per-lane triage; raises LaneFault (carrying the
-                # post-chunk state so healthy-lane progress survives)
-                # BEFORE any cadence callback sees a poisoned lane
-                self._triage_fleet(state, health, step + n)
-            else:
-                finite = bool(health.reshape(-1)[0] >= 1.0)
-                if not finite:
-                    raise SimulationDiverged(step + n,
-                                             _bad_leaf_names(state))
-                if probe is not None:
-                    # host-side triage; raises HealthDegraded (the
-                    # SimulationDiverged precursor) BEFORE any cadence
-                    # callback can checkpoint the degraded state
-                    self.last_vitals = probe.check(health, step=step + n,
-                                                   dt=dt)
-            if self.shadow_audit is not None and not fleet:
-                # strided f64 shadow audit; raises PrecisionDrift
-                # BEFORE the checkpoint cadence can persist a
-                # silently-drifted state
-                self.shadow_audit.maybe_audit(self.integ, state, dt,
-                                              step=step + n)
-            step += n
+        # the due viz_fn / checkpoint_fn calls of the chunk that just
+        # ended: (name, fn, state, step, chunk, deferred)
+        pending = []
 
-            if self.metrics_fn is not None:
-                with _obs.span("driver/metrics_fn", step=step,
-                               chunk=ordinal):
-                    rec = self.metrics_fn(state, step)
-                if rec:
-                    self.history.append(rec)
-            if (cfg.viz_dump_interval and self.viz_fn is not None
-                    and step % cfg.viz_dump_interval == 0):
-                with _obs.span("driver/viz_fn", step=step, chunk=ordinal):
-                    self.viz_fn(state, step)
-            if (cfg.restart_interval and self.checkpoint_fn is not None
-                    and step % cfg.restart_interval == 0):
-                with _obs.span("driver/checkpoint_fn", step=step,
-                               chunk=ordinal):
-                    self.checkpoint_fn(state, step)
-            if (cfg.regrid_interval and self.regrid_fn is not None
-                    and step % cfg.regrid_interval == 0):
-                with _obs.span("driver/regrid_fn", step=step,
-                               chunk=ordinal):
-                    state = self.regrid_fn(state, step)
-            ordinal += 1
+        def flush():
+            due = pending[:]
+            del pending[:]      # a callback that raises drops the rest
+            for name, cb, s, k, chunk, deferred in due:
+                _CALLBACKS[name, deferred].inc()
+                # a root span, as where it runs before the dispatch
+                with _obs.detached(), _obs.span(
+                        "driver/" + name, step=k, chunk=chunk,
+                        deferred=deferred):
+                    cb(s, k)
+
+        try:
+            while step < cfg.num_steps:
+                if cfg.cfl is not None:
+                    # float() keeps dt a weak-typed Python scalar whichever
+                    # branch wins (a device-scalar cfl_dt would otherwise
+                    # flip the aval and retrace)
+                    dt = float(min(cfg.dt,
+                                   self.integ.cfl_dt(state, cfg.cfl)))
+                n = min(cfg.health_interval, cfg.num_steps - step)
+                for i in cadences:               # land exactly on cadences
+                    n = min(n, i - step % i)
+                probe = self.health_probe
+                fleet = self.lanes is not None
+                if fleet:
+                    snap_dt = self.lane_dt.copy()
+                    snap_alive = self.lane_alive.copy()
+                    chunk_args = (jnp.asarray(self.lane_dt),
+                                  jnp.asarray(self.lane_alive))
+                else:
+                    snap_dt, snap_alive = dt, None
+                    chunk_args = (dt,)
+                if self.recorder is not None:
+                    # host copy of the PRE-chunk state, taken before the
+                    # (possibly donated) chunk invalidates its buffers
+                    self.recorder.snapshot(state, step=step, dt=snap_dt,
+                                           length=n, integ=self.integ,
+                                           cfg=cfg, alive=snap_alive)
+                t0 = time.perf_counter()
+                # the chunk span brackets dispatch AND the one-per-chunk
+                # host sync; its children split the two. Every span closes
+                # into obs's ring (and the run ledger when one is attached)
+                # and sits on a profiler capture's timeline. Telemetry never
+                # reaches inside the jitted chunk — the *_telemetry graph
+                # contracts pin zero in-scan host transfers with the bus
+                # armed. The TimerManager scope (itself an obs.span) wraps
+                # the same interval from outside. Callbacks a boundary
+                # deferred run inside this interval (beside the chunk, on
+                # this thread), so it and last_chunk_wall_s hold them.
+                first_call = n not in self._called
+                timed = (self.timer.scope(self.timer_name)
+                         if self.timer is not None
+                         else contextlib.nullcontext())
+                with timed, _obs.span("driver/chunk", step=step, length=n,
+                                      chunk=ordinal):
+                    fn = self._chunk(n)
+                    if first_call:
+                        # what obs/deviceprof needs to read this program's
+                        # compiled text after the run: shapes, no buffers
+                        # (taken before a donating call deletes them)
+                        self._called.add(n)
+                        _obs.register_program(f"driver/chunk[{n}]",
+                                              self._chunks[n],
+                                              (state,) + chunk_args, steps=n)
+                    with _obs.span("dispatch", step=step, chunk=ordinal,
+                                   first_call=first_call):
+                        state, health = fn(state, *chunk_args)
+                    # the boundary before deferred its file writes to
+                    # here: they run while the device steps, and are
+                    # done before this chunk's health is looked at
+                    flush()
+                    with _obs.span("sync", step=step, chunk=ordinal):
+                        # one device sync per chunk: the finite bool or the
+                        # fused vitals vector
+                        health = np.asarray(health)
+                    if self._carried:
+                        # the carried chunk's tally, off the tail of what
+                        # the sync brought: counters and an event span
+                        refreshes, falls = (int(v) for v in health[-2:])
+                        health = health[:-2]
+                        _REFRESHES_TOTAL.inc(refreshes)
+                        _FALLS_TOTAL.inc(falls)
+                        with _obs.span("refresh", step=step, chunk=ordinal,
+                                       refreshes=refreshes, falls=falls):
+                            pass
+                self.last_chunk_wall_s = time.perf_counter() - t0
+                _CHUNKS_TOTAL.inc()
+                _STEPS_TOTAL.inc(n)
+                _CHUNK_WALL.observe(self.last_chunk_wall_s)
+                # per-chunk counters snapshot + device-memory watermarks,
+                # riding the sync that just happened (no-op when no ledger
+                # is attached)
+                _obs.chunk_boundary(step=step + n,
+                                    chunk_wall_s=self.last_chunk_wall_s)
+                if fleet:
+                    # per-lane triage; raises LaneFault (carrying the
+                    # post-chunk state so healthy-lane progress survives)
+                    # BEFORE any cadence callback sees a poisoned lane
+                    self._triage_fleet(state, health, step + n)
+                else:
+                    finite = bool(health.reshape(-1)[0] >= 1.0)
+                    if not finite:
+                        raise SimulationDiverged(step + n,
+                                                 _bad_leaf_names(state))
+                    if probe is not None:
+                        # host-side triage; raises HealthDegraded (the
+                        # SimulationDiverged precursor) BEFORE any cadence
+                        # callback can checkpoint the degraded state
+                        self.last_vitals = probe.check(health, step=step + n,
+                                                       dt=dt)
+                if self.shadow_audit is not None and not fleet:
+                    # strided f64 shadow audit; raises PrecisionDrift
+                    # BEFORE the checkpoint cadence can persist a
+                    # silently-drifted state
+                    self.shadow_audit.maybe_audit(self.integ, state, dt,
+                                                  step=step + n)
+                step += n
+
+                if self.metrics_fn is not None:
+                    with _obs.span("driver/metrics_fn", step=step,
+                                   chunk=ordinal):
+                        rec = self.metrics_fn(state, step)
+                    if rec:
+                        self.history.append(rec)
+                regrid = (cfg.regrid_interval and self.regrid_fn is not None
+                          and step % cfg.regrid_interval == 0)
+                # the next dispatch may go ahead of this boundary's file
+                # writes unless it starts from another state (a regrid),
+                # invalidates the one they read (donation) or never
+                # comes (the last chunk)
+                defer = not (regrid or cfg.donate or step == cfg.num_steps)
+                for name, cb, every in (
+                        ("viz_fn", self.viz_fn, cfg.viz_dump_interval),
+                        ("checkpoint_fn", self.checkpoint_fn,
+                         cfg.restart_interval)):
+                    if every and cb is not None and step % every == 0:
+                        pending.append((name, cb, state, step, ordinal,
+                                        defer))
+                if not defer:
+                    flush()
+                if regrid:
+                    with _obs.span("driver/regrid_fn", step=step,
+                                   chunk=ordinal):
+                        state = self.regrid_fn(state, step)
+                ordinal += 1
+        finally:
+            # a due callback of a chunk that passed its health check is
+            # never dropped: whatever stopped the next chunk from being
+            # dispatched, its files are written before that leaves run()
+            flush()
         # always visualize the final configuration, aligned or not
         if (cfg.viz_dump_interval and self.viz_fn is not None
                 and step % cfg.viz_dump_interval != 0):
-            with _obs.span("driver/viz_fn", step=step,
-                           chunk=max(ordinal - 1, 0)):
-                self.viz_fn(state, step)
+            pending.append(("viz_fn", self.viz_fn, state, step,
+                            max(ordinal - 1, 0), False))
+            flush()
         return state

@@ -2,6 +2,8 @@
 VERDICT round 1 item 8).
 """
 
+import json
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -215,3 +217,211 @@ def test_falls_reach_the_counter_and_the_refresh_span():
               zip(jax.tree_util.tree_leaves(out),
                   jax.tree_util.tree_leaves(ref)))
     assert gap < 1e4 * float(jnp.finfo(ref.X.dtype).eps)
+
+
+# -- the order of the loop at a chunk boundary (PR 31) -----------------------
+
+class _Health:
+    """Stands in for a chunk's health value: logs the driver's one sync."""
+
+    def __init__(self, health, log, i):
+        self.health, self.log, self.i = health, log, i
+
+    def __array__(self, dtype=None, copy=None):
+        self.log.append(("sync", self.i))
+        return np.asarray(self.health)
+
+
+class _LoggedDriver(HierarchyDriver):
+    """Logs dispatches and syncs by chunk ordinal; ``fail`` makes the
+    request for (``"ask"``) or the call of (``"dispatch"``) chunk
+    ``fail_at`` raise."""
+
+    def __init__(self, *a, log, fail=None, fail_at=1, **kw):
+        super().__init__(*a, **kw)
+        self.log, self.fail, self.fail_at, self.asked = log, fail, fail_at, 0
+
+    def _chunk(self, n):
+        i, fn = self.asked, super()._chunk(n)
+        self.asked += 1
+        if self.fail == "ask" and i == self.fail_at:
+            raise RuntimeError("no chunk program")
+
+        def call(state, *args):
+            self.log.append(("dispatch", i))
+            if self.fail == "dispatch" and i == self.fail_at:
+                raise RuntimeError("dispatch failed")
+            out, health = fn(state, *args)
+            return out, _Health(health, self.log, i)
+        return call
+
+
+def _logged(log, integ, cfg, **kw):
+    def cb(name):
+        return lambda s, k: log.append((name, k))
+
+    return _LoggedDriver(integ, cfg, log=log, metrics_fn=cb("metrics"),
+                         viz_fn=cb("viz"), checkpoint_fn=cb("ckpt"), **kw)
+
+
+_D0 = [("dispatch", 0), ("sync", 0), ("metrics", 2)]
+_END = [("sync", 1), ("metrics", 4), ("viz", 4), ("ckpt", 4)]
+
+
+@pytest.mark.parametrize("case, cfg_kw, regrid, expected", [
+    # viz and checkpoint of step 2 wait for chunk 1's dispatch and are
+    # done before its sync; step 4 is the last chunk's
+    ("deferring", dict(num_steps=4, viz_dump_interval=2, restart_interval=2),
+     False, _D0 + [("dispatch", 1), ("viz", 2), ("ckpt", 2)] + _END),
+    ("donate", dict(num_steps=4, viz_dump_interval=2, restart_interval=2,
+                    donate=True),
+     False, _D0 + [("viz", 2), ("ckpt", 2), ("dispatch", 1)] + _END),
+    ("regrid_due", dict(num_steps=4, viz_dump_interval=2, restart_interval=2,
+                        regrid_interval=2),
+     True, _D0 + [("viz", 2), ("ckpt", 2), ("regrid", 2), ("dispatch", 1)]
+     + _END + [("regrid", 4)]),
+    ("last_chunk", dict(num_steps=2, viz_dump_interval=2, restart_interval=2),
+     False, _D0 + [("viz", 2), ("ckpt", 2)]),
+    ("nothing_due", dict(num_steps=4, viz_dump_interval=4,
+                         restart_interval=4),
+     False, _D0 + [("dispatch", 1)] + _END),
+])
+def test_order_at_a_chunk_boundary(case, cfg_kw, regrid, expected):
+    integ, log = _ins(), []
+
+    def regrid_fn(s, k):
+        log.append(("regrid", k))
+        return s
+
+    _logged(log, integ, RunConfig(dt=1e-3, health_interval=2, **cfg_kw),
+            regrid_fn=regrid_fn if regrid else None).run(_tg_state(integ))
+    assert log == expected
+
+
+def _run_with_files(tmp, donate, step_fn=None, driver=None):
+    """A 12-step run that dumps a CSV every 2 steps and checkpoints
+    every 4."""
+    from ibamr_tpu.utils.checkpoint import save_checkpoint
+
+    integ = _ins()
+    (tmp / "viz").mkdir()
+
+    def viz_fn(s, k):
+        np.savetxt(tmp / "viz" / f"u.{k:04d}.csv", np.asarray(s.u[0]),
+                   delimiter=",")
+
+    drv = (driver or HierarchyDriver)(
+        integ, RunConfig(dt=1e-3, num_steps=12, health_interval=2,
+                         viz_dump_interval=2, restart_interval=4,
+                         donate=donate),
+        viz_fn=viz_fn, step_fn=step_fn,
+        metrics_fn=lambda s, k: {"step": k, "ke": float(
+            integ.kinetic_energy(s))},
+        checkpoint_fn=lambda s, k: save_checkpoint(str(tmp / "rst"), s, k))
+    return drv, _tg_state(integ)
+
+
+def _files(tmp):
+    csv = {p.name: p.read_bytes() for p in sorted((tmp / "viz").iterdir())}
+    crc = {p.name: json.loads(p.read_text())["integrity"]["npz_crc32"]
+           for p in sorted((tmp / "rst").glob("*.json"))}
+    return csv, crc
+
+
+def test_deferred_run_equals_the_inline_run_bit_for_bit(tmp_path):
+    # donation is the observable that keeps every boundary inline
+    got = {}
+    for name, donate in (("deferred", False), ("inline", True)):
+        (tmp_path / name).mkdir()
+        drv, state = _run_with_files(tmp_path / name, donate)
+        out = drv.run(state)
+        got[name] = (jax.tree_util.tree_map(np.asarray, out), drv.history,
+                     *_files(tmp_path / name))
+    (s_d, h_d, csv_d, crc_d), (s_i, h_i, csv_i, crc_i) = (got["deferred"],
+                                                          got["inline"])
+    assert _equal(s_d, s_i) and h_d == h_i
+    assert sorted(csv_d) == [f"u.{k:04d}.csv" for k in range(2, 13, 2)]
+    assert csv_d == csv_i
+    assert sorted(crc_d) == [f"restore.{k:08d}.json" for k in (4, 8, 12)]
+    assert crc_d == crc_i
+
+
+def test_divergence_at_the_next_chunk_keeps_the_last_checkpoint(tmp_path):
+    # steps 5 and 6 (chunk 2) blow up: the checkpoint of step 4, written
+    # beside that chunk, is whole; none of step 6 or later exists
+    from ibamr_tpu.utils.checkpoint import latest_step, verify_checkpoint
+
+    integ = _ins()
+
+    def step_fn(s, dt):
+        return integ.step(s, jnp.where(s.k >= 4, jnp.nan, dt))
+
+    drv, state = _run_with_files(tmp_path, False, step_fn=step_fn)
+    with pytest.raises(SimulationDiverged) as ei:
+        drv.run(state)
+    assert ei.value.step == 6
+    rst = str(tmp_path / "rst")
+    assert latest_step(rst) == 4 and verify_checkpoint(rst, 4)
+    assert sorted(p.name for p in (tmp_path / "rst").iterdir()) == [
+        "restore.00000004.json", "restore.00000004.npz"]
+    assert sorted(_files(tmp_path)[0]) == ["u.0002.csv", "u.0004.csv"]
+
+
+@pytest.mark.parametrize("fail", ["ask", "dispatch"])
+def test_a_failed_dispatch_still_writes_the_due_files(tmp_path, fail):
+    # chunk 2 (from step 4) cannot start: step 4's dump and checkpoint,
+    # deferred to after that dispatch, are on disk when the error arrives
+    from ibamr_tpu.utils.checkpoint import verify_checkpoint
+
+    log = []
+    drv, state = _run_with_files(
+        tmp_path, False, driver=lambda *a, **kw: _LoggedDriver(
+            *a, log=log, fail=fail, fail_at=2, **kw))
+    with pytest.raises(RuntimeError, match="chunk program|dispatch failed"):
+        drv.run(state)
+    assert sorted(_files(tmp_path)[0]) == ["u.0002.csv", "u.0004.csv"]
+    assert verify_checkpoint(str(tmp_path / "rst"), 4)
+    assert [h["step"] for h in drv.history] == [2, 4]
+
+
+def test_deferred_and_inline_counters_and_span_attribute():
+    from ibamr_tpu import obs
+
+    def counts():
+        snap = obs.metrics_snapshot()["counters"]
+        return {(where, cb): snap.get(
+            f'driver_callbacks_{where}_total{{callback="{cb}"}}', 0)
+            for where in ("deferred", "inline")
+            for cb in ("viz_fn", "checkpoint_fn")}
+
+    integ, before = _ins(), counts()
+    obs.clear_spans()
+    # three boundaries: 2 (dump), 4 (dump + checkpoint), 6 (dump, last)
+    HierarchyDriver(
+        integ, RunConfig(dt=1e-3, num_steps=6, health_interval=2,
+                         viz_dump_interval=2, restart_interval=4),
+        viz_fn=lambda s, k: None, checkpoint_fn=lambda s, k: None,
+    ).run(_tg_state(integ))
+    after = counts()
+    assert {k: after[k] - before[k] for k in after} == {
+        ("deferred", "viz_fn"): 2, ("deferred", "checkpoint_fn"): 1,
+        ("inline", "viz_fn"): 1, ("inline", "checkpoint_fn"): 0}
+    ring = obs.spans()
+
+    def named(path):
+        return [s for s in ring if s["path"] == path]
+
+    viz, ckpt = named("driver/viz_fn"), named("driver/checkpoint_fn")
+    assert [(s["attrs"]["step"], s["attrs"]["chunk"], s["attrs"]["deferred"])
+            for s in viz] == [(2, 0, True), (4, 1, True), (6, 2, False)]
+    assert [(s["attrs"]["step"], s["attrs"]["deferred"])
+            for s in ckpt] == [(4, True)]
+    # a deferred callback of chunk k runs between chunk k+1's dispatch and
+    # its sync, as a root span
+    dispatch, sync = named("driver/chunk/dispatch"), named("driver/chunk/sync")
+    for s in viz[:2] + ckpt:
+        nxt = s["attrs"]["chunk"] + 1
+        assert s["parent"] is None
+        assert dispatch[nxt]["t1"] <= s["t0"] <= s["t1"] <= sync[nxt]["t0"]
+    assert sync[2]["t1"] <= viz[2]["t0"]
+    obs.clear_spans()
