@@ -8,6 +8,11 @@ against the Ghia table (pinned at Re=100 by
 tests/test_ins_ppm_walls.py::test_lid_driven_cavity_re100_ghia).
 
 Run:  python examples/navier_stokes/cavity2d/main.py [input2d]
+
+This file keeps its own Python loop because the Ghia test pins it. The
+cavity on the normal path (``HierarchyDriver``: scan chunks, health vector,
+checkpoint and restart) is ``examples/navier_stokes/cavity3d/main.py``, the
+cubic cavity at Re = 1000; start a new wall-bounded case from that one.
 """
 
 import os

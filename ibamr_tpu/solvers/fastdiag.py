@@ -40,8 +40,20 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ibamr_tpu import obs
 from ibamr_tpu.bc import AxisBC, DomainBC, ghost_reflect_coeff
 from ibamr_tpu.grid import StaggeredGrid
+
+# axis transforms a traced solve makes (bumped at trace time, forward
+# and inverse counted apart)
+_DENSE_AXES_TOTAL = obs.counter("fluid_transform_dense_axes_total")
+_FFT_AXES_TOTAL = obs.counter("fluid_transform_fft_axes_total")
+obs.describe("fluid_transform_dense_axes_total",
+             "axis transforms of traced fast-diagonalization solves made "
+             "as dense eigenvector products (forward and inverse apart)")
+obs.describe("fluid_transform_fft_axes_total",
+             "axis transforms of traced fast-diagonalization solves made "
+             "as FFTs (forward and inverse apart)")
 
 
 def laplacian_1d_cc(n: int, h: float, axbc: AxisBC) -> np.ndarray:
@@ -159,9 +171,14 @@ class FastDiagSolver:
     # -- helpers -------------------------------------------------------------
     def _axis_matmul(self, x: jnp.ndarray, M: jnp.ndarray,
                      axis: int) -> jnp.ndarray:
-        """Apply M (m_out, m_in) along ``axis`` of x."""
+        """Apply M (m_out, m_in) along ``axis`` of x, to the accuracy of
+        x's own dtype: on a TPU a float32 product without a stated
+        precision is ONE bfloat16 pass, and an eigenvector transform
+        with bfloat16 operands loses the exact projection (div u = 0
+        to rounding) at about 1e-3."""
         moved = jnp.moveaxis(x, axis, -1)
-        out = jnp.tensordot(moved, M.astype(moved.dtype), axes=([-1], [1]))
+        out = jnp.tensordot(moved, M.astype(moved.dtype), axes=([-1], [1]),
+                            precision=jax.lax.Precision.HIGHEST)
         return jnp.moveaxis(out, -1, axis)
 
     def _interior(self, x: jnp.ndarray) -> Tuple[jnp.ndarray, list]:
@@ -177,22 +194,36 @@ class FastDiagSolver:
               zero_nullspace: bool = False) -> jnp.ndarray:
         """Solve (alpha + beta lap) Q = rhs. With alpha == 0 and an
         all-Neumann/periodic problem set ``zero_nullspace`` to project
-        out the constant mode (periodic-Poisson compatibility analog)."""
+        out the constant mode (periodic-Poisson compatibility analog).
+
+        The axis transforms sit under the phase ``transforms``
+        (``jax.named_scope``: metadata only), the diagonal divide
+        outside it; which kind a traced solve made is counted
+        (``fluid_transform_{dense,fft}_axes_total``) and told to the
+        ``driver/chunk`` span whose call traced it
+        (``transform_path``)."""
         x, pinned = self._interior(rhs)
         rdt = x.dtype
         cdt = jnp.complex128 if rdt == jnp.float64 else jnp.complex64
         dim = x.ndim
+        n_fft = sum(p[0] == "fft" for p in self.plans)
+        n_eig = len(self.plans) - n_fft
+        any_fft = n_fft > 0
+        _DENSE_AXES_TOTAL.inc(2 * n_eig)
+        _FFT_AXES_TOTAL.inc(2 * n_fft)
+        obs.annotate("driver/chunk", transform_path=(
+            "mixed" if n_eig and n_fft else "fft" if n_fft else "dense"))
 
         # forward eig transforms (real), then FFTs (complex)
-        for d, plan in enumerate(self.plans):
-            if plan[0] == "eig":
-                x = self._axis_matmul(x, plan[1].T, d)
-        any_fft = any(p[0] == "fft" for p in self.plans)
-        if any_fft:
-            x = x.astype(cdt)
+        with jax.named_scope("transforms"):
             for d, plan in enumerate(self.plans):
-                if plan[0] == "fft":
-                    x = jnp.fft.fft(x, axis=d)
+                if plan[0] == "eig":
+                    x = self._axis_matmul(x, plan[1].T, d)
+            if any_fft:
+                x = x.astype(cdt)
+                for d, plan in enumerate(self.plans):
+                    if plan[0] == "fft":
+                        x = jnp.fft.fft(x, axis=d)
 
         # diagonal solve
         sym = jnp.zeros((), dtype=rdt)
@@ -215,14 +246,15 @@ class FastDiagSolver:
             x = x / denom
 
         # inverse transforms
-        if any_fft:
+        with jax.named_scope("transforms"):
+            if any_fft:
+                for d, plan in enumerate(self.plans):
+                    if plan[0] == "fft":
+                        x = jnp.fft.ifft(x, axis=d)
+                x = jnp.real(x).astype(rdt)
             for d, plan in enumerate(self.plans):
-                if plan[0] == "fft":
-                    x = jnp.fft.ifft(x, axis=d)
-            x = jnp.real(x).astype(rdt)
-        for d, plan in enumerate(self.plans):
-            if plan[0] == "eig":
-                x = self._axis_matmul(x, plan[1], d)
+                if plan[0] == "eig":
+                    x = self._axis_matmul(x, plan[1], d)
 
         # re-attach pinned faces as zeros (homogeneous walls)
         for d in pinned:

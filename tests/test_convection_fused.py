@@ -230,3 +230,51 @@ def test_the_tracing_chunk_span_names_the_path():
     chunks = [s for s in obs.spans() if s["path"] == "driver/chunk"]
     assert [s["attrs"].get("convect_path") for s in chunks] == \
         ["fused", None]
+
+
+# -- the fast-diagonalization solves' own counters (PR 32) -------------------
+
+def _transform_counts():
+    c = obs.metrics_snapshot()["counters"]
+    return (c.get("fluid_transform_dense_axes_total", 0),
+            c.get("fluid_transform_fft_axes_total", 0))
+
+
+# 4 solves a step (three velocity Helmholtz, one pressure Poisson), every
+# axis forward and inverse: 4 x 3 x 2 = 24 axis transforms
+TRANSFORMS = [
+    ("walls on three axes", dict(n=(16, 16, 16),
+                                 wall_axes=(True, True, True)),
+     (24, 0), "dense"),
+    ("walls on one axis", dict(n=(16, 16, 16),
+                               wall_axes=(False, True, False)),
+     (8, 16), "mixed"),
+    ("periodic", dict(n=(16, 16, 16)), (0, 0), None),
+]
+
+
+@pytest.mark.parametrize("what,kw,raised,path", TRANSFORMS,
+                         ids=[t[0] for t in TRANSFORMS])
+def test_transform_counters_by_boundary(what, kw, raised, path):
+    integ = _integ(**kw)
+    state = _seeded(integ)
+    before = _transform_counts()
+    with obs.span("driver/chunk"):
+        text = str(jax.make_jaxpr(integ.step)(state, 1e-3))
+    after = _transform_counts()
+    # a periodic step goes through solvers/spectral_plan, not fastdiag
+    assert (after[0] - before[0], after[1] - before[1]) == raised
+    assert obs.spans()[-1]["attrs"].get("transform_path") == path
+    assert text.count("dot_general") == raised[0]
+
+
+def test_the_tracing_chunk_span_names_the_transform_path():
+    from ibamr_tpu.utils.hierarchy_driver import HierarchyDriver, RunConfig
+    integ = _integ((16, 16, 16), wall_axes=(True, True, True))
+    drv = HierarchyDriver(integ, RunConfig(dt=1e-3, num_steps=4,
+                                           health_interval=2))
+    obs.clear_spans()
+    drv.run(_seeded(integ))
+    chunks = [s for s in obs.spans() if s["path"] == "driver/chunk"]
+    assert [(s["attrs"].get("transform_path"), s["attrs"].get("convect_path"))
+            for s in chunks] == [("dense", "padded"), (None, None)]

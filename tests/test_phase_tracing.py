@@ -477,7 +477,8 @@ def test_phase_reader(monkeypatch, metric):
     entry = _per_layer()[metric]
     assert (entry["source"], entry["moves"]) == ("device_trace", "step_ms")
     if metric in FLUID_ONLY:
-        assert entry["workloads"] == ["tg_256.advance"]
+        # the fluid-only cells: periodic (PR 28) and wall-bounded (PR 32)
+        assert entry["workloads"] == ["tg_256.advance", "cavity_256.advance"]
     else:
         assert {"ex4_shell_256.advance", "ex4_shell_128.advance",
                 "ex4_shell_128.production"} <= set(entry["workloads"])

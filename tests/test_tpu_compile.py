@@ -174,6 +174,42 @@ def test_fluid_only_ppm_chunk_fits_one_chip(one_chip):
     assert "/fluid/convect/" in compiled.as_text()
 
 
+def test_walled_ppm_chunk_fits_one_chip_at_float32_products(one_chip):
+    # the lid-driven cavity's program (cavity_256, PR 32): the driver's
+    # scan chunk of the wall-bounded fluid solve, whose 24 axis
+    # transforms a step are dense products on the matrix unit (the 256^3
+    # chunk of 20 steps compiles in ~22 s and takes 2.8 GB; made by
+    # hand). A float32 product with no stated precision is ONE bfloat16
+    # pass on this chip, which a CPU run cannot see: every product under
+    # /fluid/transforms/ has to state the highest
+    import re
+
+    from ibamr_tpu.integrators.ins import INSStaggeredIntegrator
+    from ibamr_tpu.utils.hierarchy_driver import HierarchyDriver, RunConfig
+
+    grid = StaggeredGrid(n=(64,) * 3, x_lo=(0.0,) * 3, x_up=(1.0,) * 3)
+    integ = INSStaggeredIntegrator(
+        grid, rho=1.0, mu=1e-3, convective_op_type="PPM",
+        wall_axes=(True, True, True), wall_tangential={(0, 1, 1): 1.0})
+    state = jax.eval_shape(integ.initialize)
+    dt = 0.2 / 64
+    drv = HierarchyDriver(integ, RunConfig(dt=dt, num_steps=2,
+                                           health_interval=2))
+    compiled = drv._chunk(2).lower(_on(one_chip, state), dt).compile()
+    ma = compiled.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             + ma.temp_size_in_bytes + ma.generated_code_size_in_bytes)
+    assert 0 < total < HBM_BYTES, ma
+    text = compiled.as_text()
+    assert "/fluid/transforms/" in text and "/fluid/convect/" in text
+    products = [ln for ln in text.splitlines()
+                if re.search(r"= \S+ (dot|convolution)\(", ln)
+                and "/fluid/transforms/" in ln]
+    assert len(products) == 24, len(products)
+    for ln in products:
+        assert "operand_precision={highest,highest}" in ln, ln
+
+
 def test_fused_ppm_operator_compiles_at_256(one_chip, monkeypatch):
     # tg_256's convective operator since PR 29: the slab-fused periodic
     # PPM kernel alone at the cell's own size (Mosaic takes ~3 s). The
