@@ -138,7 +138,8 @@ class INSStaggeredIntegrator:
         # ghost-padded path; fully-periodic centered/upwind keep the
         # original roll formulation. ``_convective`` evaluates the
         # padded path's operator with the slab-fused kernel where
-        # shape, dtype and boundary allow (chosen at trace time);
+        # shape, dtype and scheme allow, walls included (chosen at
+        # trace time);
         # ``_convective_padded`` never does (the sharded wrapper puts
         # it in ``_convective``'s place: a pallas_call does not
         # partition).

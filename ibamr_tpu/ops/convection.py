@@ -38,13 +38,16 @@ Three code paths:
 - ``ops/pallas_convection.convective_rate_ppm_fused`` — the same PPM
   arithmetic as :func:`convective_rate_bc` evaluated slab by slab in a
   Pallas kernel whose intermediates never leave VMEM (the padded path
-  moves 45 times the bytes the operator needs at 256^3).
-  :func:`convective_rate_select` takes it where the code can see that
-  it applies — scheme ``ppm``, no walls, three float32 components of
-  one rank-3 shape whose last two extents are multiples of (8, 128) —
-  and :func:`convective_rate_bc` everywhere else (``cui``, walls, 2D,
-  the 16^3-64^3 test grids, float64); no option chooses. The padded
-  path stays the oracle and supplies the fused path's VJP.
+  moves 45 times the bytes the operator needs at 256^3), walls
+  included: reflecting halo planes along axis 0, ghosted rotations in
+  a plane. :func:`convective_rate_select` takes it where the code can
+  see that it applies — scheme ``ppm``, three float32 components of
+  one rank-3 shape whose last two extents are multiples of (8, 128),
+  ``dx`` and the walls' tangential values Python numbers — and
+  :func:`convective_rate_bc` everywhere else (``cui``, 2D, the
+  16^3-64^3 test grids, float64, a traced wall value, the sharded
+  wrapper); no option chooses. The padded path stays the oracle and
+  supplies the fused path's VJP.
 """
 
 from __future__ import annotations
@@ -371,7 +374,7 @@ _FUSED_TOTAL = obs.counter("fluid_convect_fused_total")
 _PADDED_TOTAL = obs.counter("fluid_convect_padded_total")
 obs.describe("fluid_convect_fused_total",
              "traces of the ghost-padded-menu convective operator that "
-             "took the slab-fused periodic PPM kernel")
+             "took the slab-fused PPM kernel (periodic or walled)")
 obs.describe("fluid_convect_padded_total",
              "traces of it that took convective_rate_bc")
 
@@ -383,23 +386,30 @@ def convective_rate_select(
         partitioned: bool = False,
 ) -> Vel:
     """:func:`convective_rate_bc`'s operator by whichever evaluation
-    fits what is observed at trace time: the slab-fused kernel for
-    fully periodic 3D float32 ``ppm`` on tile-aligned extents, the
-    ghost-padded path otherwise, and always where the caller's program
-    is ``partitioned`` over a mesh (the sharded wrapper says so: a
-    pallas_call does not partition). The choice is counted
+    fits what is observed at trace time: the slab-fused kernel for 3D
+    float32 ``ppm`` on tile-aligned extents, walled or periodic along
+    each axis, with ``dx`` and every wall's tangential value Python
+    numbers (the kernel's statics); the ghost-padded path otherwise,
+    and always where the caller's program is ``partitioned`` over a
+    mesh (the sharded wrapper says so: a pallas_call does not
+    partition). The choice is counted
     (``fluid_convect_{fused,padded}_total``) and told to the
-    ``driver/chunk`` span whose call traced it (``convect_path``)."""
+    ``driver/chunk`` span whose call traced it (``convect_path``, and
+    ``convect_walls``: the walled axes, ``"xyz"`` to ``""``)."""
     from ibamr_tpu.ops import pallas_convection
 
+    walls = tuple(bool(w) for w in wall_axes or (False,) * len(u))
+    tang = wall_tangential or {}
     fused = (scheme == "ppm" and not partitioned
-             and not any(wall_axes or ())
-             and all(isinstance(h, (int, float)) for h in dx)
-             and pallas_convection.fused_ppm_supported(u))
+             and all(isinstance(v, (int, float))
+                     for v in (*dx, *tang.values()))
+             and pallas_convection.fused_ppm_supported(u, walls))
     (_FUSED_TOTAL if fused else _PADDED_TOTAL).inc()
     obs.annotate("driver/chunk",
-                 convect_path="fused" if fused else "padded")
+                 convect_path="fused" if fused else "padded",
+                 convect_walls="".join(
+                     name for name, w in zip("xyz", walls) if w))
     if fused:
         return pallas_convection.convective_rate_ppm_fused(
-            tuple(u), tuple(dx))
+            tuple(u), tuple(dx), walls, tuple(sorted(tang.items())))
     return convective_rate_bc(u, dx, scheme, wall_axes, wall_tangential)

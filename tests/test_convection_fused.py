@@ -1,10 +1,11 @@
-"""The slab-fused periodic PPM operator (``ops/pallas_convection.py``)
-against its oracle, the ghost-padded ``convective_rate_bc``.
+"""The slab-fused PPM operator (``ops/pallas_convection.py``), periodic
+and walled, against its oracle, the ghost-padded ``convective_rate_bc``.
 
 CPU, Pallas interpret mode, small tile-aligned shapes. What the chip's
 compiler says of the kernel at 256^3 is in tests/test_tpu_compile.py.
 """
 
+import functools
 import math
 
 import jax
@@ -15,6 +16,7 @@ import pytest
 from ibamr_tpu import obs
 from ibamr_tpu.grid import StaggeredGrid
 from ibamr_tpu.integrators.ins import INSStaggeredIntegrator
+from ibamr_tpu.integrators.ins_walls import pin_normal
 from ibamr_tpu.ops import convection
 from ibamr_tpu.ops.pallas_convection import (convective_rate_ppm_fused,
                                              fused_ppm_supported)
@@ -110,18 +112,111 @@ def test_fused_wraps_periodically(axis, k):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
+# -- walls (PR 33) -----------------------------------------------------------
+
+WALLS = [(True, True, True), (True, False, False), (False, True, False),
+         (False, False, True), (True, False, True)]
+LID = {(0, 1, 1): 1.0}
+
+
+def _axes(walls):
+    return "".join(name for name, w in zip("xyz", walls or ()) if w)
+
+
+def _slot0(d):
+    """Index of component d's pinned slot along its own axis."""
+    return (slice(None),) * d + (0,)
+
+
+def _moving_walls(walls):
+    """One moving wall per walled axis on both sides, unequal values."""
+    tang = {}
+    for e in range(3):
+        if walls[e]:
+            d = (e + 1) % 3
+            tang[(d, e, 0)], tang[(d, e, 1)] = 0.6 + 0.3 * e, -1.1 + 0.2 * e
+    return tang
+
+
+TANGENTIAL = {
+    "still": lambda walls: {},
+    # the cavity's lid (as the oracle, the kernel takes no notice of it
+    # where axis 1 has no wall)
+    "lid": lambda walls: LID,
+    "moving": _moving_walls,
+}
+
+
+def _walled_fields(kind, shape, walls):
+    """``_fields`` made to honour the wall storage convention (component
+    d's slot 0 along a walled axis d is the wall's 0); the front kinds
+    also carry a step two cells from the lo wall and a spike two cells
+    from the hi wall of their axis."""
+    u = [np.array(c) for c in _fields(kind, shape)]
+    if kind.startswith("front"):
+        ax = int(kind[-1])
+        i = np.arange(shape[ax]).reshape(
+            [-1 if a == ax else 1 for a in range(3)])
+        for c in u:
+            c += 1.2 * (i < 2) - 0.9 * (i == shape[ax] - 2)
+    return tuple(pin_normal(jnp.asarray(c, jnp.float32), d, walls)
+                 for d, c in enumerate(u))
+
+
+@functools.lru_cache(maxsize=None)
+def _walled_ops(walls, tang_items):
+    tang = dict(tang_items)
+    return (jax.jit(lambda v: convection.convective_rate_bc(
+                v, DX, "ppm", walls, tang)),
+            jax.jit(lambda v: convective_rate_ppm_fused(
+                v, DX, walls, tang_items)),
+            jax.jit(lambda v: convection.convective_rate_bc(
+                v, DX, "centered", walls, tang)))
+
+
+@pytest.mark.parametrize("shape", SHAPES, ids=lambda s: "x".join(map(str, s)))
+@pytest.mark.parametrize("kind", ["random", "front0", "front1", "front2",
+                                  "zeros"])
+@pytest.mark.parametrize("tangential", list(TANGENTIAL))
+@pytest.mark.parametrize("walls", WALLS, ids=_axes)
+def test_fused_equals_the_padded_operator_with_walls(walls, tangential,
+                                                     kind, shape):
+    tang = TANGENTIAL[tangential](walls)
+    padded, fused, centred = _walled_ops(walls, tuple(sorted(tang.items())))
+    u = _walled_fields(kind, shape, walls)
+    want, got = padded(u), fused(u)
+    assert all(g.dtype == jnp.float32 and g.shape == shape for g in got)
+    assert _gap(got, want) <= 1e-5
+    # the wall-normal faces: rate d is exactly 0 on a walled axis d
+    for d in range(3):
+        if walls[d]:
+            assert not np.asarray(got[d])[_slot0(d)].any()
+    if kind.startswith("front") and walls[int(kind[-1])]:
+        # the limiter was at work within 3 cells of each wall of the
+        # front's axis: there the rate differs from the centred one
+        ax = int(kind[-1])
+        cen = centred(u)
+        scale = max(float(jnp.max(jnp.abs(w))) for w in want)
+        for near in (slice(0, 3), slice(shape[ax] - 3, shape[ax])):
+            at = (slice(None),) * ax + (near,)
+            assert max(float(jnp.max(jnp.abs(w[at] - c[at])))
+                       for w, c in zip(want, cen)) > 1e-2 * scale
+
+
 def _counts():
     c = obs.metrics_snapshot()["counters"]
     return (c.get("fluid_convect_fused_total", 0),
             c.get("fluid_convect_padded_total", 0))
 
 
-def _integ(n, op="PPM", wall_axes=None, dtype=jnp.float32):
+def _integ(n, op="PPM", wall_axes=None, dtype=jnp.float32,
+           wall_tangential=None):
     grid = StaggeredGrid(n=n, x_lo=(-math.pi,) * len(n),
                          x_up=(math.pi,) * len(n))
     return INSStaggeredIntegrator(grid, rho=1.0, mu=0.01,
                                   convective_op_type=op, dtype=dtype,
-                                  wall_axes=wall_axes)
+                                  wall_axes=wall_axes,
+                                  wall_tangential=wall_tangential)
 
 
 def _seeded(integ, seed=0):
@@ -132,7 +227,10 @@ def _seeded(integ, seed=0):
 
 SELECTION = [
     ("aligned periodic 3D ppm", dict(n=(16, 8, 128)), True),
-    ("walls", dict(n=(16, 8, 128), wall_axes=(False, True, False)), False),
+    ("walls", dict(n=(16, 8, 128), wall_axes=(False, True, False)), True),
+    ("walls, traced tangential value",
+     dict(n=(16, 8, 128), wall_axes=(False, True, False),
+          traced_lid=True), False),
     ("cui", dict(n=(16, 8, 128), op="CUI"), False),
     ("2D", dict(n=(16, 128)), False),
     ("32^3", dict(n=(32, 32, 32)), False),
@@ -143,7 +241,16 @@ SELECTION = [
 @pytest.mark.parametrize("what,kw,fused", SELECTION,
                          ids=[s[0] for s in SELECTION])
 def test_selection_by_shape_dtype_and_boundary(what, kw, fused):
+    kw = dict(kw)
+    traced_lid = kw.pop("traced_lid", False)
     integ = _integ(**kw)
+    if traced_lid:
+        # a wall value that is no Python number (the integrator itself
+        # takes none such: its solves lift the walls on the host)
+        integ._convective = functools.partial(
+            convection.convective_rate_select, scheme="ppm",
+            wall_axes=integ.wall_axes,
+            wall_tangential={(0, 1, 1): jnp.float32(1.0)})
     state = _seeded(integ)
     before = _counts()
     with obs.span("driver/chunk"):
@@ -153,8 +260,9 @@ def test_selection_by_shape_dtype_and_boundary(what, kw, fused):
         ((1, 0) if fused else (0, 1))
     assert ("pallas_call" in text) == fused
     # ... and the span of the call that traced it was told
-    assert obs.spans()[-1]["attrs"]["convect_path"] == \
-        ("fused" if fused else "padded")
+    attrs = obs.spans()[-1]["attrs"]
+    assert attrs["convect_path"] == ("fused" if fused else "padded")
+    assert attrs["convect_walls"] == _axes(kw.get("wall_axes"))
 
 
 def test_the_sharded_wrapper_stays_on_the_padded_path(mesh8):
@@ -191,6 +299,16 @@ def test_not_supported_shapes_and_dtypes():
     # an odd leading extent takes thinner slabs, not another path
     u = _fields("random", (5, 8, 128), seed=5)
     assert _gap(_fused(u), _padded(u)) <= 1e-5
+    # ... with walls too: slabs of one plane, whose halo reaches the
+    # wall from the second and third grid step as well
+    walls = (True, True, True)
+    u = _walled_fields("random", (5, 8, 128), walls)
+    padded, fused, _ = _walled_ops(walls, tuple(sorted(LID.items())))
+    assert _gap(fused(u), padded(u)) <= 1e-5
+    # a pinned ghost plane -3 is the image of plane 3
+    assert fused_ppm_supported(f32((3, 8, 128)))
+    assert not fused_ppm_supported(f32((3, 8, 128)), (True, False, False))
+    assert fused_ppm_supported(f32((4, 8, 128)), (True, False, False))
 
 
 def test_grad_and_vmap_through_the_step():
@@ -220,6 +338,48 @@ def test_grad_and_vmap_through_the_step():
         assert _gap(tuple(c[b] for c in out.u), one.u) <= 1e-5
 
 
+def test_a_walled_chunk_through_the_integrator():
+    # a cavity: six walls, the wall y = hi moving in x
+    from ibamr_tpu.utils.hierarchy_driver import scan_steps
+    walls, n = (True, True, True), (16, 8, 128)
+    fused = _integ(n, wall_axes=walls, wall_tangential=LID)
+    padded = _integ(n, wall_axes=walls, wall_tangential=LID)
+    padded._convective = padded._convective_padded
+    state = fused.initialize(u0_arrays=[
+        0.1 * np.asarray(c) for c in _walled_fields("random", n, walls)])
+    # the pressure is (rho / dt) times a potential: at dt = 1e-3 the
+    # float32 rounding of the right-hand side alone reads 1.5e-4 of p
+    dt = 0.1
+
+    def chunk(integ):
+        def body(s, _):
+            s = integ.step(s, dt)
+            return s, s.u
+        return jax.jit(lambda s: jax.lax.scan(body, s, None, length=4))
+
+    before = _counts()
+    (got, got_us), (want, _) = chunk(fused)(state), chunk(padded)(state)
+    after = _counts()
+    assert (after[0] - before[0], after[1] - before[1]) == (1, 1)
+    assert _gap(got.u, want.u) <= 1e-5
+    assert _gap((got.p,), (want.p,)) <= 1e-5
+    # the configuration's guarantee: the normal velocity on all six
+    # walls (slot 0 and its wrap image) is exactly 0 at every step
+    for d in range(3):
+        assert not np.asarray(got_us[d])[(slice(None),) + _slot0(d)].any()
+
+    # the VJP carries the walls: AB2 from a previous rate, so that
+    # N(u) of this step counts
+    def energy(integ, u):
+        out, _ = scan_steps(integ.step, got._replace(u=u), dt, 1)
+        return sum(jnp.sum(c * c) for c in out.u)
+
+    g_fused = jax.jit(jax.grad(lambda u: energy(fused, u)))(got.u)
+    g_padded = jax.jit(jax.grad(lambda u: energy(padded, u)))(got.u)
+    assert all(bool(jnp.all(jnp.isfinite(g))) for g in g_fused)
+    assert _gap(g_fused, g_padded) <= 1e-4
+
+
 def test_the_tracing_chunk_span_names_the_path():
     from ibamr_tpu.utils.hierarchy_driver import HierarchyDriver, RunConfig
     integ = _integ((16, 8, 128))
@@ -228,8 +388,8 @@ def test_the_tracing_chunk_span_names_the_path():
     obs.clear_spans()
     drv.run(_seeded(integ))
     chunks = [s for s in obs.spans() if s["path"] == "driver/chunk"]
-    assert [s["attrs"].get("convect_path") for s in chunks] == \
-        ["fused", None]
+    assert [(s["attrs"].get("convect_path"), s["attrs"].get("convect_walls"))
+            for s in chunks] == [("fused", ""), (None, None)]
 
 
 # -- the fast-diagonalization solves' own counters (PR 32) -------------------
@@ -276,5 +436,6 @@ def test_the_tracing_chunk_span_names_the_transform_path():
     obs.clear_spans()
     drv.run(_seeded(integ))
     chunks = [s for s in obs.spans() if s["path"] == "driver/chunk"]
-    assert [(s["attrs"].get("transform_path"), s["attrs"].get("convect_path"))
-            for s in chunks] == [("dense", "padded"), (None, None)]
+    assert [tuple(s["attrs"].get(k) for k in
+                  ("transform_path", "convect_path", "convect_walls"))
+            for s in chunks] == [("dense", "padded", "xyz"), (None,) * 3]

@@ -210,12 +210,19 @@ def test_walled_ppm_chunk_fits_one_chip_at_float32_products(one_chip):
         assert "operand_precision={highest,highest}" in ln, ln
 
 
-def test_fused_ppm_operator_compiles_at_256(one_chip, monkeypatch):
-    # tg_256's convective operator since PR 29: the slab-fused periodic
-    # PPM kernel alone at the cell's own size (Mosaic takes ~3 s). The
-    # custom call has to sit under /fluid/convect/ (the phase metrics
-    # read its op_name), and the operator's intermediates in VMEM: the
-    # padded path at this size has 1.14 GiB of temporaries in HBM
+@pytest.mark.parametrize("walls,tangential", [
+    ((False, False, False), {}),
+    ((True, True, True), {(0, 1, 1): 1.0}),
+], ids=["periodic", "six walls with the lid"])
+def test_fused_ppm_operator_compiles_at_256(one_chip, monkeypatch, walls,
+                                            tangential):
+    # tg_256's convective operator since PR 29 and cavity_256's since
+    # PR 33: the slab-fused PPM kernel alone at the cells' own size,
+    # periodic and with the cavity's walls (Mosaic takes ~3 s each).
+    # The custom call has to sit under /fluid/convect/ (the phase
+    # metrics read its op_name), and the operator's intermediates in
+    # VMEM: the padded path at this size has 1.14 GiB of temporaries
+    # in HBM
     from ibamr_tpu.obs import deviceprof
     from ibamr_tpu.ops import convection
 
@@ -226,7 +233,8 @@ def test_fused_ppm_operator_compiles_at_256(one_chip, monkeypatch):
 
     def rate(u):
         with jax.named_scope("fluid"), jax.named_scope("convect"):
-            return convection.convective_rate_select(u, (h, h, h), "ppm")
+            return convection.convective_rate_select(
+                u, (h, h, h), "ppm", walls, tangential)
 
     u = tuple(jax.ShapeDtypeStruct((n,) * 3, jnp.float32) for _ in range(3))
     compiled = jax.jit(rate).lower(_on(one_chip, u)).compile()
@@ -235,5 +243,6 @@ def test_fused_ppm_operator_compiles_at_256(one_chip, monkeypatch):
     names, phases = deviceprof.names_from_hlo(text)
     call = [i for i, name in names.items() if "pallas_call" in name]
     assert len(call) == 1 and "/fluid/convect/" in names[call[0]]
+    assert "ppm_convect_fused" in names[call[0]]
     assert phases[call[0]] == "fluid/convect"
     assert compiled.memory_analysis().temp_size_in_bytes < 0.3 * 2 ** 30
