@@ -278,7 +278,19 @@ def default_rule(n: Sequence[int], n_markers: int, support: int) -> str:
     shootout measured it 2.6x the bucketed-MXU engine at 256^3 (9.19
     vs 3.53 steps/s) and 4.2x at 128^3, roundoff-exact vs the scatter
     oracle (bf16 compression stays opt-in: exactness is the default
-    contract)."""
+    contract).
+
+    The rule sees extents, marker count and kernel support, nothing of
+    the cloud's shape: a non-cubic grid (the packed layout tiles every
+    axis but the last, so only ``n[:-1]`` has to divide) carrying a
+    cloud that is no shell (a solid ball of volumetric ConstraintIB
+    markers, which fills its tiles where a shell leaves most empty)
+    resolves to ``packed`` all the same, an EXACT float32 engine; its
+    chunk count is then sized from the concrete cloud at build time
+    (``suggest_chunks``). The tuning DB's ``packed_bf16`` row pins the
+    whole cubic ``n`` and a shell's marker band, so a 160 x 160 x 256
+    tank with 58k markers does not match it by its 256-wide last
+    axis."""
     eligible = (
         n_markers >= 4096
         and all(v % 8 == 0 for v in n[:-1])

@@ -65,14 +65,19 @@ SUMMARY_NAME = "prof_summary.json"
 OP_NAMES_NAME = "op_names.json"
 
 # The phases of the compiled step, as sequences of ``jax.named_scope``
-# names (opened in integrators/ib.py, integrators/ins.py,
-# ops/interaction_packed.py and solvers/spectral_plan.py). A phase
-# matches an ``op_name`` when its scopes appear on the path in order;
-# the deepest match wins.
+# names (opened in integrators/ib.py, integrators/constraint_ib.py,
+# integrators/ins.py, ops/interaction_packed.py, solvers/fastdiag.py
+# and solvers/spectral_plan.py). A phase matches an ``op_name`` when
+# its scopes appear on the path in order; the deepest match wins, and
+# of two equally deep the one listed first: an axis transform of the
+# ConstraintIB re-projection (``fluid/reproject/transforms/...``) is
+# ``fluid/transforms`` like every other, and ``fluid/reproject`` is
+# what that projection does around its transforms.
 PHASES = (("ib/prep",), ("ib/interp",), ("ib/refresh",),
           ("ib/refresh", "repack"), ("ib/force",), ("ib/spread",),
           ("fluid",), ("fluid", "transforms"), ("fluid", "convect"),
-          ("fluid", "rhs"))
+          ("fluid", "rhs"), ("fluid", "reproject"),
+          ("constraint/rigid",), ("constraint/impose",))
 # host annotations that are the program's own spans (obs.span paths)
 PROGRAM_SPAN_RE = re.compile(r"(^|/)(driver|checkpoint|setup|compile)/")
 _DEVICE_PLANE_RE = re.compile(r"^/device:(TPU|GPU):\d+$")

@@ -246,3 +246,45 @@ def test_fused_ppm_operator_compiles_at_256(one_chip, monkeypatch, walls,
     assert "ppm_convect_fused" in names[call[0]]
     assert phases[call[0]] == "fluid/convect"
     assert compiled.memory_analysis().temp_size_in_bytes < 0.3 * 2 ** 30
+
+
+def test_constraint_ib_chunk_lowers_at_the_tank_size(one_chip, monkeypatch):
+    # falling_sphere_e4's program (PR 34): the driver's carried scan
+    # chunk of the ConstraintIB strategy over the walled solve at the
+    # configuration's own 160 x 160 x 256 with its 57,777 markers, LOWERED
+    # for the TPU platform (3 s; the compile takes 95 s and 1.5 GB, made by
+    # hand: 30 transform products and 9 transfer products, all
+    # operand_precision={highest,highest}, one tpu_custom_call). What a
+    # CPU run cannot see: on non-cubic planes of 160 x 256 the walled
+    # fused PPM kernel is taken (one Mosaic custom call, not interpret
+    # mode's inlined operations), and every product (the five solves' 30
+    # axis transforms, the interpolation's three and the spreads' six
+    # packed contractions) states the highest precision: a float32
+    # product with none is ONE bfloat16 pass on this chip
+    import re
+
+    from ibamr_tpu.utils import parse_input_file
+    from ibamr_tpu.utils.hierarchy_driver import HierarchyDriver, RunConfig
+    from perfbench import harness
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    mod = harness.load_module(os.path.join(
+        root, "examples", "ConstraintIB", "falling_sphere", "main.py"),
+        "falling_sphere_for_the_chip")
+    method, state = mod.build_falling_sphere_example(parse_input_file(
+        os.path.join(root, "perfbench", "configs",
+                     "falling_sphere_e4.input3d")))
+    assert method.ins.grid.n == (160, 160, 256)
+    assert state.X.shape == (57777, 3) and method.engine_name == "packed"
+    # the kernel picks interpret mode from the default backend, which
+    # is the CPU here: steer it in the test, not through an option
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    drv = HierarchyDriver(method, RunConfig(dt=5e-4, num_steps=2,
+                                            health_interval=2))
+    assert drv._carried
+    text = drv._chunk(2).lower(_on(one_chip, state), 5e-4).as_text()
+    assert text.count("tpu_custom_call") == 1
+    products = re.findall(r"stablehlo\.dot_general.*", text)
+    assert len(products) == 39, len(products)
+    for ln in products:
+        assert "precision = [HIGHEST, HIGHEST]" in ln, ln
