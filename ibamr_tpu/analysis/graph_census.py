@@ -392,6 +392,28 @@ def hlo_op_counts(text: str) -> dict:
     return counts
 
 
+def indexed_op_counts(text: str, n_index: int, scope: str) -> dict:
+    """Of an optimized-HLO dump, the gathers and scatters under the
+    named scope ``scope`` and outside every ``cond`` branch whose INDEX
+    operand has ``n_index`` rows: ``{"gather": n, "scatter": n}``.
+    With ``n_index`` the marker count this counts the moves of
+    per-marker values between marker order and slot order
+    (tests/test_transfer_marshal.py pins one per velocity transfer of
+    the packed engine; the overflow fallbacks live in ``cond``
+    branches)."""
+    shape_of = dict(re.findall(r"%(\S+) = (\w+\[[\d,]*\])", text))
+    found = {"gather": 0, "scatter": 0}
+    for kind, operands, op_name in re.findall(
+            r" (gather|scatter)\(([^)]*)\).*op_name=\"([^\"]*)\"", text):
+        if f"/{scope}/" not in op_name or "/cond/" in op_name:
+            continue
+        index = operands.split(",")[1].strip().lstrip("%")
+        rows = re.search(r"\[(\d+)", shape_of[index])
+        if rows and int(rows.group(1)) == n_index:
+            found[kind] += 1
+    return found
+
+
 # opcode prefix -> budget class. ``fusion``/arithmetic opcodes are
 # deliberately unclassified: their counts are backend fusion decisions,
 # not graph contracts.

@@ -41,6 +41,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ibamr_tpu import obs
 from ibamr_tpu.grid import StaggeredGrid
 from ibamr_tpu.ops import interaction
 from ibamr_tpu.ops.delta import Kernel, get_kernel
@@ -367,11 +368,39 @@ def _extract_tiles(geom, grid, f: jnp.ndarray) -> jnp.ndarray:
 
 # -- public ops --------------------------------------------------------------
 
+# how a trace moved per-marker values between marker order and slot
+# order (bumped once per traced gather / scatter-add over
+# ``slot_of_marker``: three per velocity transfer moved a component at
+# a time, one moved as rows)
+_MARKER_GATHERS = obs.counter("transfer_marker_gathers_total")
+_MARKER_SCATTERS = obs.counter("transfer_marker_scatters_total")
+obs.describe("transfer_marker_gathers_total",
+             "traced gathers of per-slot values into marker order "
+             "(one index per marker, whatever the row under it holds)")
+obs.describe("transfer_marker_scatters_total",
+             "traced scatter-adds of per-marker values into slot order")
+
+
+def _marshalled(rows: bool) -> None:
+    """Tell the ``driver/chunk`` span whose call traced it in which
+    form the values crossed: ``rows`` (a trailing channel axis, one
+    index per marker for all components) or ``scalars``."""
+    obs.annotate("driver/chunk",
+                 transfer_marshal="rows" if rows else "scalars")
+
+
 def bucketed_channel(b: Buckets, F: jnp.ndarray) -> jnp.ndarray:
     """Scatter a per-marker channel (N,) into the bucket-slot layout
-    (B, cap) of ``b`` (shared by the MXU and Pallas spread engines)."""
-    Ff = jnp.zeros((b.Xb.shape[0] * b.Xb.shape[1] + 1,), dtype=F.dtype)
-    return Ff.at[b.slot_of_marker].add(F)[:-1].reshape(b.wb.shape)
+    (B, cap) of ``b`` (shared by the MXU and Pallas spread engines);
+    rows (N, C) go to (B, cap, C) through the SAME one scatter-add, an
+    index per marker. Slots are unique per marker, so a row lands as
+    its scalars would, to every bit; the dump row takes the overflowed
+    markers."""
+    _MARKER_SCATTERS.inc()
+    _marshalled(F.ndim > 1)
+    Ff = jnp.zeros((b.wb.size + 1,) + F.shape[1:], dtype=F.dtype)
+    return Ff.at[b.slot_of_marker].add(F)[:-1].reshape(
+        b.wb.shape + F.shape[1:])
 
 
 def spread_overflow_fallbacks(out: jnp.ndarray, b: Buckets,
@@ -445,35 +474,62 @@ def spread_bucketed(geom: BucketGeometry, grid: StaggeredGrid,
                                      kernel)
 
 
-def unbucket_with_overflow(Ub: jnp.ndarray, b: Buckets, f: jnp.ndarray,
-                           X: jnp.ndarray, grid: StaggeredGrid,
-                           centering, kernel: Kernel) -> jnp.ndarray:
+def slots_to_markers(Ub: jnp.ndarray, b: Buckets) -> jnp.ndarray:
+    """Per-slot values Ub (B, cap) or rows (B, cap, C) in marker order,
+    (N,) or (N, C): ONE gather over ``slot_of_marker``, zero where the
+    marker overflowed and has no slot."""
+    _MARKER_GATHERS.inc()
+    rows = Ub.ndim > b.wb.ndim
+    _marshalled(rows)
+    S = b.wb.size
+    U = jnp.take(Ub.reshape((S,) + Ub.shape[b.wb.ndim:]),
+                 jnp.minimum(b.slot_of_marker, S - 1), axis=0)
+    live = b.slot_of_marker < S
+    return jnp.where(live[:, None] if rows else live, U, 0.0)
+
+
+def unbucket_with_overflow(Ub: jnp.ndarray, b: Buckets, f, X: jnp.ndarray,
+                           grid: StaggeredGrid, centering, kernel: Kernel,
+                           merge=None) -> jnp.ndarray:
     """Scatter per-slot interpolants Ub (B, cap) back to marker order
     and add the overflow markers' contribution (compact gather for the
     buffered overflow, exact full gather when the buffer itself
     overflowed) — the interp twin of spread_overflow_fallbacks, shared
-    by the MXU and Pallas engines."""
-    U = jnp.take(Ub.reshape(-1), jnp.minimum(
-        b.slot_of_marker, Ub.size - 1), axis=0)
-    U = jnp.where(b.slot_of_marker < Ub.size, U, 0.0)
+    by the MXU and Pallas engines.
+
+    Rows: where Ub carries a trailing channel axis, (B, cap, C), ``f``
+    and ``centering`` are C-sequences (a field and its centering per
+    channel) and the result is (N, C), brought to marker order by the
+    one gather; the overflow branches still evaluate channel by
+    channel. ``merge(U, o_idx, vals)`` accumulates the compact list
+    in place of ``U.at[o_idx].add(vals)`` (the packed reverse mode
+    passes a scatter-free one)."""
+    rows = Ub.ndim > b.wb.ndim
+    channels = tuple(zip(f, centering)) if rows else ((f, centering),)
+    U = slots_to_markers(Ub, b)
+
+    def overflow(Xo, weights):
+        cols = [interaction.interpolate(fc, grid, Xo, centering=cc,
+                                        kernel=kernel, weights=weights)
+                for fc, cc in channels]
+        return jnp.stack(cols, axis=-1) if rows else cols[0]
 
     def compact(U):
         # pad slots rely on o_w == 0 making them inert (the index
         # aliases a real marker — compact_overflow's convention)
-        Uo = interaction.interpolate(f, grid, X[b.o_idx],
-                                     centering=centering, kernel=kernel,
-                                     weights=b.o_w)
+        Uo = overflow(X[b.o_idx], b.o_w)
         if _DEBUG_OVERFLOW_PAD:
+            live = b.o_w != 0
             _check_pad_inert(
                 "unbucket_with_overflow",
-                jnp.where(b.o_w != 0, 0.0, Uo),
+                jnp.where(live[:, None] if rows else live, 0.0, Uo),
                 jnp.zeros_like(Uo))
+        if merge is not None:
+            return merge(U, b.o_idx, Uo)
         return U.at[b.o_idx].add(Uo)
 
     def full(U):
-        return U + interaction.interpolate(
-            f, grid, X, centering=centering, kernel=kernel,
-            weights=b.w_overflow)
+        return U + overflow(X, b.w_overflow)
 
     return jax.lax.cond(
         b.exceeded, full,

@@ -295,13 +295,12 @@ def refresh_packed(geom: BucketGeometry, grid: StaggeredGrid,
     return jax.lax.cond(hit, lambda: b._replace(Xb=Xb), repack), hit
 
 
-def _spread_raw(geom: BucketGeometry, grid: StaggeredGrid,
-                b: PackedBuckets, F: jnp.ndarray, X: jnp.ndarray,
-                centering, kernel: Kernel,
-                precision=jax.lax.Precision.HIGHEST,
-                compute_dtype=None) -> jnp.ndarray:
+def _spread_slots(geom: BucketGeometry, grid: StaggeredGrid,
+                  b: PackedBuckets, Ff: jnp.ndarray, centering,
+                  kernel: Kernel, precision, compute_dtype) -> jnp.ndarray:
+    """Grid field of ONE component from its channel in slot order,
+    ``Ff`` (Q, c): the packed markers' part of a spread."""
     inv_vol = 1.0 / math.prod(grid.dx)
-    Ff = bucketed_channel(b, F)
     A, Wlast = _tile_weights(geom, grid, b, centering, kernel)
     A = A * (Ff * b.wb * inv_vol)[..., None]
     Tq = contract_compressed("qmp,qmz->qpz", A, Wlast, compute_dtype,
@@ -310,8 +309,30 @@ def _spread_raw(geom: BucketGeometry, grid: StaggeredGrid,
     T = jax.ops.segment_sum(Tq, b.tile_of_chunk, num_segments=B,
                             indices_are_sorted=True)
     with jax.named_scope("overlap_add"):
-        out = _overlap_add(geom, grid, T.reshape(
+        return _overlap_add(geom, grid, T.reshape(
             (B,) + tuple(geom.width) + (grid.n[grid.dim - 1],)))
+
+
+def _interp_slots(geom: BucketGeometry, grid: StaggeredGrid,
+                  b: PackedBuckets, f: jnp.ndarray, centering,
+                  kernel: Kernel, precision, compute_dtype) -> jnp.ndarray:
+    """Interpolants of ONE component in slot order, (Q, c): the packed
+    markers' part of an interpolation (pure gathers and a contraction)."""
+    T = _extract_tiles(geom, grid, f)                 # (B, P, nz)
+    Tq = jnp.take(T, b.tile_of_chunk, axis=0)         # (Q, P, nz)
+    A, Wlast = _tile_weights(geom, grid, b, centering, kernel)
+    D = contract_compressed("qpz,qmz->qmp", Tq, Wlast, compute_dtype,
+                            precision=precision)
+    return jnp.sum(A * D, axis=-1) * b.wb
+
+
+def _spread_raw(geom: BucketGeometry, grid: StaggeredGrid,
+                b: PackedBuckets, F: jnp.ndarray, X: jnp.ndarray,
+                centering, kernel: Kernel,
+                precision=jax.lax.Precision.HIGHEST,
+                compute_dtype=None) -> jnp.ndarray:
+    out = _spread_slots(geom, grid, b, bucketed_channel(b, F), centering,
+                        kernel, precision, compute_dtype)
     return spread_overflow_fallbacks(out, b, F, X, grid, centering,
                                      kernel)
 
@@ -320,14 +341,43 @@ def _interp_raw(geom: BucketGeometry, grid: StaggeredGrid,
                 b: PackedBuckets, f: jnp.ndarray, X: jnp.ndarray,
                 centering, kernel: Kernel,
                 precision=jax.lax.Precision.HIGHEST,
-                compute_dtype=None) -> jnp.ndarray:
-    T = _extract_tiles(geom, grid, f)                 # (B, P, nz)
-    Tq = jnp.take(T, b.tile_of_chunk, axis=0)         # (Q, P, nz)
-    A, Wlast = _tile_weights(geom, grid, b, centering, kernel)
-    D = contract_compressed("qpz,qmz->qmp", Tq, Wlast, compute_dtype,
-                            precision=precision)
-    Ub = jnp.sum(A * D, axis=-1) * b.wb               # (Q, c)
-    return unbucket_with_overflow(Ub, b, f, X, grid, centering, kernel)
+                compute_dtype=None, merge=None) -> jnp.ndarray:
+    Ub = _interp_slots(geom, grid, b, f, centering, kernel, precision,
+                       compute_dtype)
+    return unbucket_with_overflow(Ub, b, f, X, grid, centering, kernel,
+                                  merge=merge)
+
+
+def _spread_vel_raw(geom: BucketGeometry, grid: StaggeredGrid,
+                    kernel: Kernel, precision, compute_dtype,
+                    b: PackedBuckets, F: jnp.ndarray,
+                    X: jnp.ndarray) -> Vel:
+    """All components of a velocity-like spread, F (N, dim): the
+    markers' values go to slot order as ROWS, by one scatter-add; each
+    component then spreads from its own slice as ``_spread_raw`` would
+    from its own scatter-add, to every bit."""
+    Ff = bucketed_channel(b, F)                       # (Q, c, dim)
+    return tuple(
+        spread_overflow_fallbacks(
+            _spread_slots(geom, grid, b, Ff[..., d], d, kernel,
+                          precision, compute_dtype),
+            b, F[:, d], X, grid, d, kernel)
+        for d in range(grid.dim))
+
+
+def _interp_vel_raw(geom: BucketGeometry, grid: StaggeredGrid,
+                    kernel: Kernel, precision, compute_dtype,
+                    b: PackedBuckets, u: Vel, X: jnp.ndarray,
+                    merge=None) -> jnp.ndarray:
+    """All components of a velocity interpolation -> (N, dim): the
+    per-slot interpolants come to marker order as ROWS, by one
+    gather (``_interp_raw``'s columns, to every bit)."""
+    dims = tuple(range(grid.dim))
+    Ub = jnp.stack([_interp_slots(geom, grid, b, u[d], d, kernel,
+                                  precision, compute_dtype)
+                    for d in dims], axis=-1)          # (Q, c, dim)
+    return unbucket_with_overflow(Ub, b, tuple(u), X, grid, dims, kernel,
+                                  merge=merge)
 
 
 # -- packed-transfer reverse mode (PR 19) ------------------------------------
@@ -350,51 +400,15 @@ def _merge_overflow_gather(U: jnp.ndarray, o_idx: jnp.ndarray,
     and gather each marker's run sum via two searchsorted probes
     (sort + cumsum + gathers only — pad entries alias real markers
     with value 0, and duplicate ids sum exactly as the scatter-add
-    would)."""
+    would). Scalars (N,) or rows (N, C) alike."""
     perm = jnp.argsort(o_idx)
     so = o_idx[perm]
-    cs = jnp.concatenate([jnp.zeros((1,), vals.dtype),
-                          jnp.cumsum(vals[perm])])
+    cs = jnp.concatenate([jnp.zeros((1,) + vals.shape[1:], vals.dtype),
+                          jnp.cumsum(vals[perm], axis=0)])
     ar = jnp.arange(U.shape[0], dtype=so.dtype)
     lo = jnp.searchsorted(so, ar, side="left")
     hi = jnp.searchsorted(so, ar, side="right")
     return U + (cs[hi] - cs[lo])
-
-
-def _interp_gather_only(geom: BucketGeometry, grid: StaggeredGrid,
-                        b: PackedBuckets, g: jnp.ndarray,
-                        X: jnp.ndarray, centering, kernel: Kernel,
-                        precision, compute_dtype) -> jnp.ndarray:
-    """Interp of grid field ``g`` through the SAME buckets, emitting
-    ZERO scatter primitives: the packed main path is already pure
-    gathers/einsum; the overflow merge goes through
-    :func:`_merge_overflow_gather` instead of ``.at[].add``. This is
-    the spread VJP's cotangent pass — ``grad_spread`` pins the zero."""
-    T = _extract_tiles(geom, grid, g)
-    Tq = jnp.take(T, b.tile_of_chunk, axis=0)
-    A, Wlast = _tile_weights(geom, grid, b, centering, kernel)
-    D = contract_compressed("qpz,qmz->qmp", Tq, Wlast, compute_dtype,
-                            precision=precision)
-    Ub = jnp.sum(A * D, axis=-1) * b.wb
-    U = jnp.take(Ub.reshape(-1), jnp.minimum(
-        b.slot_of_marker, Ub.size - 1), axis=0)
-    U = jnp.where(b.slot_of_marker < Ub.size, U, 0.0)
-
-    def compact(U):
-        Uo = interaction.interpolate(g, grid, X[b.o_idx],
-                                     centering=centering, kernel=kernel,
-                                     weights=b.o_w)
-        return _merge_overflow_gather(U, b.o_idx, Uo)
-
-    def full(U):
-        return U + interaction.interpolate(
-            g, grid, X, centering=centering, kernel=kernel,
-            weights=b.w_overflow)
-
-    return jax.lax.cond(
-        b.exceeded, full,
-        lambda u: jax.lax.cond(b.any_overflow, compact,
-                               lambda uu: uu, u), U)
 
 
 def _position_cotangent(grid: StaggeredGrid, field: jnp.ndarray,
@@ -442,9 +456,10 @@ def _spread_bwd(geom, grid, centering, kernel, precision, compute_dtype,
     # d/dF: interp of the grid cotangent through the SAME buckets
     # (weights included), scaled by the spread's 1/h^dim — zero
     # scatters, zero bucket preps
-    F_ct = inv_vol * _interp_gather_only(geom, grid, b, ct, X,
-                                         centering, kernel, precision,
-                                         compute_dtype)
+    F_ct = inv_vol * _interp_raw(geom, grid, b, ct, X, centering, kernel,
+                                 precision=precision,
+                                 compute_dtype=compute_dtype,
+                                 merge=_merge_overflow_gather)
     # d/dX: the kernel-weight derivative, pulled back through the
     # oracle stencil evaluation of the SAME cotangent field
     w_full = _marker_weights(b)
@@ -490,6 +505,67 @@ def _interp_bwd(geom, grid, centering, kernel, precision, compute_dtype,
 
 
 _interp_vjp.defvjp(_interp_fwd, _interp_bwd)
+
+
+# The velocity transfers' reverse mode: the two rules above on ROWS.
+# The cotangent passes are the batched transfers themselves, so they
+# marshal once as the primal does: d(spread_vel) wrt F one row gather
+# (still scatter-free), d(interp_vel) wrt u the primal spread_vel's one
+# row scatter-add; no bucket prep in either.
+
+def _position_cotangent_vel(grid, fields, X, kernel, scale):
+    """The components' position cotangents, summed: ``scale`` (N, dim)
+    holds each component's per-marker chain factor in its column."""
+    X_ct = _position_cotangent(grid, fields[0], X, 0, kernel, scale[:, 0])
+    for d in range(1, grid.dim):
+        X_ct = X_ct + _position_cotangent(grid, fields[d], X, d, kernel,
+                                          scale[:, d])
+    return X_ct
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4))
+def _spread_vel_vjp(*args) -> Vel:
+    return _spread_vel_raw(*args)
+
+
+def _spread_vel_fwd(*args):
+    return _spread_vel_raw(*args), args[-3:]
+
+
+def _spread_vel_bwd(geom, grid, kernel, precision, compute_dtype, res, ct):
+    b, F, X = res
+    inv_vol = 1.0 / math.prod(grid.dx)
+    F_ct = inv_vol * _interp_vel_raw(geom, grid, kernel, precision,
+                                     compute_dtype, b, ct, X,
+                                     merge=_merge_overflow_gather)
+    X_ct = _position_cotangent_vel(
+        grid, ct, X, kernel, F * _marker_weights(b)[:, None] * inv_vol)
+    return (jax.tree_util.tree_map(_zeros_ct, b), F_ct, X_ct)
+
+
+_spread_vel_vjp.defvjp(_spread_vel_fwd, _spread_vel_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4))
+def _interp_vel_vjp(*args) -> jnp.ndarray:
+    return _interp_vel_raw(*args)
+
+
+def _interp_vel_fwd(*args):
+    return _interp_vel_raw(*args), args[-3:]
+
+
+def _interp_vel_bwd(geom, grid, kernel, precision, compute_dtype, res, ct):
+    b, u, X = res
+    vol = math.prod(grid.dx)
+    u_ct = tuple(vol * g for g in _spread_vel_raw(
+        geom, grid, kernel, precision, compute_dtype, b, ct, X))
+    X_ct = _position_cotangent_vel(grid, u, X, kernel,
+                                   ct * _marker_weights(b)[:, None])
+    return (jax.tree_util.tree_map(_zeros_ct, b), u_ct, X_ct)
+
+
+_interp_vel_vjp.defvjp(_interp_vel_fwd, _interp_vel_bwd)
 
 
 def spread_packed(geom: BucketGeometry, grid: StaggeredGrid,
@@ -570,21 +646,24 @@ class PackedInteraction:
     def interpolate_vel(self, u: Vel, X: jnp.ndarray,
                         weights: Optional[jnp.ndarray] = None,
                         b: Optional[PackedBuckets] = None) -> jnp.ndarray:
+        """(N, dim): the columns are ``interpolate_packed`` of each
+        component, brought to marker order together, as rows (one
+        gather over ``slot_of_marker`` and not one per component)."""
         if b is None:
             b = self.buckets(X, weights)
-        cols = [interpolate_packed(self.geom, self.grid, b, u[d], X,
-                                   d, self.kernel,
-                                   compute_dtype=self.compute_dtype)
-                for d in range(self.grid.dim)]
-        return jnp.stack(cols, axis=-1)
+        transfer = _interp_vel_vjp if GRAD_TRANSFERS else _interp_vel_raw
+        return transfer(self.geom, self.grid, self.kernel,
+                        jax.lax.Precision.HIGHEST, self.compute_dtype,
+                        b, tuple(u), X)
 
     def spread_vel(self, F: jnp.ndarray, X: jnp.ndarray,
                    weights: Optional[jnp.ndarray] = None,
                    b: Optional[PackedBuckets] = None) -> Vel:
+        """``spread_packed`` of each column of F (N, dim), the columns
+        taken to slot order together, as rows (one scatter-add)."""
         if b is None:
             b = self.buckets(X, weights)
-        return tuple(spread_packed(self.geom, self.grid, b, F[:, d], X,
-                                   d, self.kernel,
-                                   compute_dtype=self.compute_dtype)
-                     for d in range(self.grid.dim))
-
+        transfer = _spread_vel_vjp if GRAD_TRANSFERS else _spread_vel_raw
+        return transfer(self.geom, self.grid, self.kernel,
+                        jax.lax.Precision.HIGHEST, self.compute_dtype,
+                        b, F, X)

@@ -412,6 +412,33 @@ def test_chunk_span_says_what_was_constrained_and_how(traced_chunk):
     assert after[key] - before.get(key, 0) == 30
 
 
+def test_chunk_says_how_the_marker_values_crossed(traced_chunk):
+    """One ``interpolate_vel`` and two ``spread_vel`` a step, each moving
+    its rows between marker order and slot order once (PR 35)."""
+    before, after, span, _, _ = traced_chunk
+    assert span["attrs"]["transfer_marshal"] == "rows"
+    for key, count in (("transfer_marker_gathers_total", 1),
+                       ("transfer_marker_scatters_total", 2)):
+        assert after[key] - before.get(key, 0) == count
+
+
+def test_step_marshals_once_per_transfer():
+    """The compiled step holds one gather and two scatters with an index
+    per marker under the transfers' scopes (three and six when every
+    component crossed alone)."""
+    from ibamr_tpu.analysis.graph_census import indexed_op_counts
+
+    method, state = box_method("packed")
+    ctx = jax.jit(method.init_carry)(state)
+    text = jax.jit(lambda s, c: method.step_carried(s, c, 2e-3)).lower(
+        state, ctx).compile().as_text()
+    n = state.X.shape[0]
+    assert indexed_op_counts(text, n, "ib/interp") == \
+        {"gather": 1, "scatter": 0}
+    assert indexed_op_counts(text, n, "ib/spread") == \
+        {"gather": 0, "scatter": 2}
+
+
 @pytest.mark.parametrize("phase", [
     "ib/prep", "ib/refresh", "ib/refresh/repack", "ib/interp", "ib/spread",
     "constraint/rigid", "constraint/impose", "fluid", "fluid/convect",
