@@ -48,6 +48,7 @@ from ibamr_tpu.utils.backend_guard import auto_backend  # noqa: E402
 
 auto_backend()
 
+from ibamr_tpu import obs  # noqa: E402
 from ibamr_tpu.grid import StaggeredGrid  # noqa: E402
 from ibamr_tpu.integrators.cib import RigidBodies  # noqa: E402
 from ibamr_tpu.integrators.constraint_ib import (  # noqa: E402
@@ -67,6 +68,7 @@ class ClearanceLost(RuntimeError):
     """A marker came within ``CLEARANCE_CELLS`` cells of a wall."""
 
 
+@obs.span("setup/build")
 def build_falling_sphere_example(input_db, dtype=jnp.float32):
     """``(method, state)`` from ``CartesianGeometry``,
     ``INSStaggeredHierarchyIntegrator``, ``ConstraintIBMethod`` and

@@ -35,6 +35,7 @@ from ibamr_tpu.utils.backend_guard import auto_backend  # noqa: E402
 
 auto_backend()
 
+from ibamr_tpu import obs  # noqa: E402
 from ibamr_tpu.grid import StaggeredGrid  # noqa: E402
 from ibamr_tpu.integrators.ins import INSStaggeredIntegrator  # noqa: E402
 from ibamr_tpu.utils import MetricsLogger, parse_input_file  # noqa: E402
@@ -42,6 +43,7 @@ from ibamr_tpu.utils.checkpoint import restore_checkpoint, save_checkpoint  # no
 from ibamr_tpu.utils.hierarchy_driver import HierarchyDriver, RunConfig  # noqa: E402
 
 
+@obs.span("setup/build")
 def build_cavity_example(input_db, dtype=jnp.float32):
     """``(integ, state)`` from ``CartesianGeometry`` and
     ``INSStaggeredHierarchyIntegrator``: walls on all three axes, the

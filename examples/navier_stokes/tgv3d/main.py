@@ -30,6 +30,7 @@ from ibamr_tpu.utils.backend_guard import auto_backend  # noqa: E402
 
 auto_backend()
 
+from ibamr_tpu import obs  # noqa: E402
 from ibamr_tpu.grid import StaggeredGrid  # noqa: E402
 from ibamr_tpu.integrators.ins import INSStaggeredIntegrator  # noqa: E402
 from ibamr_tpu.utils import MetricsLogger, parse_input_file  # noqa: E402
@@ -45,6 +46,7 @@ def taylor_green(coords, t):
             jnp.zeros(()))
 
 
+@obs.span("setup/build")
 def build_tgv_example(input_db, dtype=jnp.float32):
     """``(integ, state)`` from ``CartesianGeometry`` and
     ``INSStaggeredHierarchyIntegrator``: the analytic field evaluated on

@@ -21,6 +21,7 @@ from typing import Optional, Tuple
 
 import numpy as np
 
+from ibamr_tpu import obs
 from ibamr_tpu.grid import StaggeredGrid
 from ibamr_tpu.integrators.ib import IBExplicitIntegrator, IBMethod, IBState
 from ibamr_tpu.integrators.ins import INSStaggeredIntegrator
@@ -95,6 +96,7 @@ def shell_volume(X: np.ndarray, center: Tuple[float, float, float]):
     return (4.0 / 3.0) * math.pi * jnp.mean(r ** 3)
 
 
+@obs.span("setup/build")
 def build_shell_example(
         n_cells: int = 64,
         n_lat: int = 32,

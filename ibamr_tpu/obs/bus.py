@@ -728,29 +728,66 @@ def span(name: str, block_on=None, **attrs):
 # compiles: one jax.monitoring listener -> spans + counters
 # ---------------------------------------------------------------------------
 
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_BACKEND = "/jax/core/compile/backend_compile_duration"
 _COMPILE_EVENTS = {
-    "/jax/core/compile/backend_compile_duration": "compile/backend",
+    _TRACE: "compile/trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile/lower",
+    _BACKEND: "compile/backend",
     "/jax/compilation_cache/cache_retrieval_time_sec":
         "compile/cache_read",
 }
 _listening = False
 
 
-def _on_duration(event: str, secs: float, **_kw) -> None:
+def _compiling() -> dict:
+    """This thread's open compile stages: the depth of nested traces and
+    one ``[fun, cached]`` per backend compile not yet closed."""
+    c = getattr(_TLS, "compiling", None)
+    if c is None:
+        c = _TLS.compiling = {"traces": 0, "backends": []}
+    return c
+
+
+def _on_start(event: str, _value, fun_name: str = "", **_kw) -> None:
+    """jax reports a stage's START as a scalar of the same event name."""
+    if event == _TRACE:
+        _compiling()["traces"] += 1
+    elif event == _BACKEND:
+        _compiling()["backends"].append([fun_name, False])
+
+
+def _on_duration(event: str, secs: float, fun_name: str = "",
+                 **_kw) -> None:
     name = _COMPILE_EVENTS.get(event)
     if name is None:
         return
     t1 = time.perf_counter()
-    if name == "compile/backend":
+    c = _compiling()
+    attrs = {"fun": fun_name}
+    if name == "compile/trace":
+        # every nested jit and jnp wrapper reports a trace inside its
+        # parent's: only the thread's outermost becomes a span
+        if c["traces"] == 0:
+            return                      # its start came before the listener
+        c["traces"] -= 1
+        if c["traces"]:
+            return
+    elif name == "compile/backend":
+        # jax's backend compile holds the cache key and the cache read
+        attrs["cached"] = c["backends"].pop()[1] if c["backends"] else False
         counter("compile_events_total").inc()
         counter("compile_seconds_total").inc(float(secs))
-    else:
+    elif name == "compile/cache_read":
+        # no fun_name of its own: the backend compile it is read for
+        if c["backends"]:
+            c["backends"][-1][1] = True
+            attrs["fun"] = c["backends"][-1][0]
         counter("compile_cache_reads_total").inc()
     # a child of whatever span the compiling thread is in; ``step`` and
     # ``chunk`` come down from the nearest span that carries them, so a
     # compile after a run's first chunk shows with the step it hit
     st = _stack()
-    attrs = {}
     for key in ("step", "chunk"):
         for _, _, a in reversed(st):
             if key in a:
@@ -768,6 +805,7 @@ def _listen_for_compiles(jax) -> None:
     _listening = True
     import jax.monitoring
 
+    jax.monitoring.register_scalar_listener(_on_start)
     jax.monitoring.register_event_duration_secs_listener(_on_duration)
 
 

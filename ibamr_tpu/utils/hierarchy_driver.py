@@ -21,7 +21,6 @@ the restart chain.
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import time
 from typing import Any, Callable, Optional
@@ -364,8 +363,8 @@ class HierarchyDriver:
         self.metrics_fn = metrics_fn
         self.regrid_fn = regrid_fn
         self.checkpoint_fn = checkpoint_fn
-        self.timer = timer                 # TimerManager: scopes the chunk
-        self.timer_name = timer_name       # (dispatch to sync), see run()
+        self.timer = timer                 # TimerManager: one entry per
+        self.timer_name = timer_name       # chunk (its wall), see run()
         self.health_probe = health_probe
         self.recorder = recorder
         self.shadow_audit = shadow_audit
@@ -686,16 +685,12 @@ class HierarchyDriver:
                 # and sits on a profiler capture's timeline. Telemetry never
                 # reaches inside the jitted chunk — the *_telemetry graph
                 # contracts pin zero in-scan host transfers with the bus
-                # armed. The TimerManager scope (itself an obs.span) wraps
-                # the same interval from outside. Callbacks a boundary
-                # deferred run inside this interval (beside the chunk, on
-                # this thread), so it and last_chunk_wall_s hold them.
+                # armed. Callbacks a boundary deferred run inside this
+                # interval (beside the chunk, on this thread), so it and
+                # last_chunk_wall_s hold them.
                 first_call = n not in self._called
-                timed = (self.timer.scope(self.timer_name)
-                         if self.timer is not None
-                         else contextlib.nullcontext())
-                with timed, _obs.span("driver/chunk", step=step, length=n,
-                                      chunk=ordinal):
+                with _obs.span("driver/chunk", step=step, length=n,
+                               chunk=ordinal):
                     fn = self._chunk(n)
                     if first_call:
                         # what obs/deviceprof needs to read this program's
@@ -727,6 +722,10 @@ class HierarchyDriver:
                                        refreshes=refreshes, falls=falls):
                             pass
                 self.last_chunk_wall_s = time.perf_counter() - t0
+                if self.timer is not None:
+                    # the report's entry per chunk, from the chunk span's
+                    # own interval: no second span around it
+                    self.timer.get(self.timer_name).add(self.last_chunk_wall_s)
                 _CHUNKS_TOTAL.inc()
                 _STEPS_TOTAL.inc(n)
                 _CHUNK_WALL.observe(self.last_chunk_wall_s)

@@ -42,9 +42,14 @@ class Timer:
         # only the outermost frame of a re-entrant timer accumulates, so
         # `total` stays wall-clock (matching SAMRAI's exclusive-timer report)
         if not self._starts:
-            self.total += dt
-            self.count += 1
+            self.add(dt)
         return dt
+
+    def add(self, seconds: float) -> None:
+        """Account one interval timed elsewhere (the run loop's chunk
+        wall, which its own span already brackets)."""
+        self.total += seconds
+        self.count += 1
 
 
 class TimerManager:

@@ -22,7 +22,8 @@ from ibamr_tpu import obs
 from ibamr_tpu.models.shell3d import build_shell_example
 from ibamr_tpu.obs import deviceprof
 from ibamr_tpu.utils.hierarchy_driver import HierarchyDriver, RunConfig
-from perfbench import obsread, tracereduce
+from ibamr_tpu.utils import parse_input_string
+from perfbench import harness, inputfile, obsread, tracereduce
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 RECORDED = os.path.join(ROOT, "perfbench", "tests", "data",
@@ -562,25 +563,49 @@ SPAN_METRICS = {
     "setup.backend_init_s": 9.0,
     "compile.cache_read_s": 3.0,
     "compile.backend_s": 1.5,
-    "compile.in_window": 1.0}
+    "compile.in_window": 1.0,
+    # set-up and recovery by stage
+    "compile.trace_s": 0.5 + 2.0,
+    "compile.lower_s": 0.1 + 0.75,
+    "setup.build_s": 0.5,
+    # the union of the set-up's spans: backend_init with build (the trace
+    # inside it counts once), the restore, two cache reads, the backend,
+    # the first dispatch (trace and lowerings inside it), the window's
+    # first chunk up to the window's start
+    "setup.unspanned_s": 32.0 - (9.5 + 0.2 + 1.0 + 2.0 + 1.5 + 5.0 + 0.25),
+    "recover.compile_s": 4.8 - 0.5,
+    "recover.first_chunk_s": 0.8,
+    "recover.unspanned_s": 6.0 - (0.3 + 5.4)}
+NEEDS_NEW_SPANS = ("compile.trace_s", "compile.lower_s", "setup.build_s",
+                   "setup.unspanned_s", "recover.compile_s",
+                   "recover.unspanned_s")
 
 
-@pytest.mark.parametrize("metric", sorted(SPAN_METRICS))
-def test_span_reader(monkeypatch, metric):
-    ctx, _ = _hand_ctx(monkeypatch)
+def _hand_ring(ctx):
+    """A set-up, a window and a recovery, by hand: set-up is the 32 s
+    before the window, the recovery the 6 s from the last restore on."""
     w0 = ctx["chunks"][0]["t_start"]
+    r0 = ctx["chunks"][-1]["t_end"] + 20.0
+    ctx.update(setup_s=32.0, recover={"recover_s": 6.0, "restore_s": 0.3})
 
     def sp(path, t0, dur):
         return {"id": 0, "parent": None, "name": path.rsplit("/", 1)[-1],
                 "path": path, "t0": t0, "t1": t0 + dur, "attrs": {}}
-    ring = [
+    return [
+        sp("checkpoint/restore", w0 - 31.0, 0.2),         # not the last
         sp("setup/backend_init", w0 - 30.0, 9.0),
+        sp("compile/trace", w0 - 25.0, 0.5),
+        sp("setup/build", w0 - 21.0, 0.5),
         sp("driver/chunk/dispatch/compile/cache_read", w0 - 20.0, 1.0),
         sp("compile/cache_read", w0 - 18.0, 2.0),
         sp("driver/chunk/dispatch/compile/backend", w0 - 15.0, 1.5),
         sp("driver/chunk/dispatch", w0 - 10.0, 5.0),      # set-up
-        sp("IB::advanceHierarchy/driver/chunk/dispatch", w0 + 0.1, 0.01),
-        sp("IB::advanceHierarchy/driver/chunk/sync", w0 + 0.2, 0.5),
+        sp("driver/chunk/dispatch/compile/trace", w0 - 10.0, 2.0),
+        sp("driver/chunk/dispatch/compile/lower", w0 - 9.5, 0.1),
+        sp("driver/chunk/dispatch/compile/lower", w0 - 8.0, 0.75),
+        sp("driver/chunk", w0 - 0.25, 2.0),               # opened before
+        sp("driver/chunk/dispatch", w0 + 0.1, 0.01),
+        sp("driver/chunk/sync", w0 + 0.2, 0.5),
         sp("driver/metrics_fn", w0 + 0.8, 0.04),
         sp("driver/chunk/dispatch", w0 + 2.1, 0.03),
         sp("driver/chunk/dispatch/compile/backend", w0 + 2.1, 0.02),
@@ -588,10 +613,61 @@ def test_span_reader(monkeypatch, metric):
         sp("driver/checkpoint_fn", w0 + 3.3, 0.3),
         sp("driver/checkpoint_fn/checkpoint/fetch", w0 + 3.3, 0.05),
         sp("driver/checkpoint_fn/checkpoint/commit", w0 + 3.4, 0.2),
-        sp("driver/chunk/sync", w0 + 10.0, 7.0)]           # after it
+        sp("driver/chunk/sync", w0 + 10.0, 7.0),          # after it
+        # the recovery: restore, then a new driver's first chunk
+        sp("checkpoint/restore", r0, 0.3),
+        sp("driver/chunk", r0 + 0.5, 5.4),
+        sp("driver/chunk/dispatch", r0 + 0.5, 4.5),
+        sp("driver/chunk/dispatch/compile/trace", r0 + 0.5, 2.0),
+        sp("driver/chunk/dispatch/compile/lower", r0 + 2.5, 0.5),
+        sp("driver/chunk/dispatch/compile/backend", r0 + 3.0, 1.8),
+        sp("driver/chunk/dispatch/compile/cache_read", r0 + 3.1, 1.6),
+        sp("driver/chunk/sync", r0 + 5.0, 0.8),
+        sp("driver/metrics_fn", r0 + 6.05, 0.05)]          # after it
+
+
+@pytest.mark.parametrize("metric", sorted(SPAN_METRICS))
+def test_span_reader(monkeypatch, metric):
+    ctx, _ = _hand_ctx(monkeypatch)
+    ring = _hand_ring(ctx)
     monkeypatch.setattr(obs, "spans", lambda: ring)
     assert _reader(metric)(ctx) == pytest.approx(SPAN_METRICS[metric])
     assert _per_layer()[metric]["source"] == "program_span"
+
+
+@pytest.mark.parametrize("metric", NEEDS_NEW_SPANS)
+def test_span_reader_on_a_program_without_the_stage_spans(monkeypatch,
+                                                         metric):
+    """The parent's ring: no trace, lowering or build span. What needs
+    them reads None; the recovery's sync span is the same on both."""
+    ctx, _ = _hand_ctx(monkeypatch)
+    ring = [s for s in _hand_ring(ctx) if s["name"] not in (
+        "trace", "lower", "build")]
+    monkeypatch.setattr(obs, "spans", lambda: ring)
+    assert _reader(metric)(ctx) is None
+    assert _reader("recover.first_chunk_s")(ctx) == pytest.approx(0.8)
+    # a cell that does not recover has no recovery to split
+    ring[:] = _hand_ring(ctx)
+    ctx["recover"] = None
+    if metric.startswith("recover."):
+        assert _reader(metric)(ctx) is None
+
+
+def test_set_up_and_recovery_metrics_are_listed():
+    cells = [w["name"] for w in json.load(open(
+        os.path.join(ROOT, "BENCHMARK.json")))["workloads"]]
+    for metric in ("compile.trace_s", "compile.lower_s", "setup.build_s",
+                   "setup.unspanned_s", "recover.compile_s",
+                   "recover.first_chunk_s", "recover.unspanned_s"):
+        entry = _per_layer()[metric]
+        assert (entry["unit"], entry["source"]) == ("s", "program_span")
+        if metric.startswith("recover."):
+            assert entry["moves"] == "recover_s"
+            assert entry["workloads"] == [c for c in cells
+                                          if c.endswith(".production")]
+        else:
+            assert (entry["moves"], entry["layer"]) == ("setup_s", "compile")
+            assert entry["workloads"] == cells
 
 
 # ---------------------------------------------------------------------------
@@ -619,10 +695,166 @@ def test_compile_after_the_first_chunk_shows_with_its_step():
             and s["t0"] >= first_chunk["t1"]]
     assert late, [s["path"] for s in ring]
     assert all(s["path"] == "driver/metrics_fn/compile/backend"
-               and s["attrs"] == {"step": 4, "chunk": 1} for s in late)
+               and s["attrs"] == {"step": 4, "chunk": 1, "cached": False,
+                                  "fun": s["attrs"]["fun"]} for s in late)
+    assert "jit(<lambda>)" in {s["attrs"]["fun"] for s in late}
     assert all(s["t1"] - s["t0"] > 0 for s in late)
     n = len([s for s in ring if s["name"] == "compile/backend"])
     assert obs.counter("compile_events_total").value - c0 == n
     assert obs.counter("compile_seconds_total").value - s0 \
         == pytest.approx(sum(s["t1"] - s["t0"] for s in ring
                              if s["name"] == "compile/backend"))
+
+
+def test_outermost_traces_only_and_every_stage_names_its_function():
+    """A toy shell build and a 2-chunk run: jax reports every nested jit
+    and jnp wrapper's trace inside its parent's; the ring keeps one
+    ``compile/trace`` per OUTERMOST trace (found here independently, by
+    containment of jax's own start/end times), none inside another."""
+    import jax.monitoring
+
+    events = []
+
+    def on_span(event, t0, t1, **kw):
+        if event.endswith("jaxpr_trace_duration"):
+            events.append((t0, t1))
+    obs.clear_spans()
+    jax.monitoring.register_event_time_span_listener(on_span)
+    try:
+        integ, state = build_shell_example(n_cells=16, n_lat=8, n_lon=8)
+        HierarchyDriver(integ, RunConfig(dt=1e-4, num_steps=4,
+                                         health_interval=2)).run(state)
+    finally:
+        jax.monitoring.unregister_event_time_span_listener(on_span)
+    ring = obs.spans()
+    outermost = [e for e in events if not any(
+        o is not e and o[0] <= e[0] and e[1] <= o[1] for o in events)]
+    traces = [s for s in ring if s["name"] == "compile/trace"]
+    assert len(events) > 2 * len(traces) > 0       # nested ones folded
+    assert len(traces) == len(outermost)
+    assert not [(a["path"], b["path"]) for a in traces for b in traces
+                if a is not b and b["t0"] <= a["t0"] and a["t1"] <= b["t1"]]
+    # the chunk program's own: traced inside its first dispatch
+    chunk = [s for s in traces if s["attrs"]["fun"] == "chunk"]
+    assert [s["path"] for s in chunk] == ["driver/chunk/dispatch/compile/trace"]
+    by_name = {}
+    for s in ring:
+        by_name.setdefault(s["name"], []).append(s)
+    assert by_name["compile/lower"] and by_name["compile/backend"]
+    for s in by_name["compile/lower"] + by_name["compile/backend"] + traces:
+        assert isinstance(s["attrs"]["fun"], str) and s["attrs"]["fun"]
+    assert all(s["attrs"]["cached"] in (True, False)
+               for s in by_name["compile/backend"])
+    assert "jit(chunk)" in {s["attrs"]["fun"]
+                            for s in by_name["compile/lower"]}
+    # the first dispatch is its program's trace, lowering and compile
+    first = next(s for s in ring if s["name"] == "dispatch"
+                 and s["attrs"]["first_call"])
+    kids = [s for s in ring if s["parent"] == first["id"]]
+    iv = (first["t0"], first["t1"])
+    from perfbench import intervals
+    assert intervals.covered_s(kids, iv) > 0.9 * (iv[1] - iv[0])
+    # set-up's spans are still in the ring after it
+    assert [s["path"] for s in ring if s["name"] == "setup/build"] == [
+        "setup/build"]
+    assert len(ring) < obs.bus.SPAN_RING_SIZE // 8
+
+
+def test_cache_read_names_the_compile_it_served(tmp_path):
+    """A persistent-cache hit: ``compile/cache_read`` closes inside its
+    ``compile/backend``, takes that compile's ``fun`` and marks it
+    ``cached``."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from ibamr_tpu.serve.aot_cache import enable_persistent_cache
+
+    prev_dir = jax.config.jax_compilation_cache_dir
+    prev_min = jax.config.jax_persistent_cache_min_compile_time_secs
+    prev_sz = jax.config.jax_persistent_cache_min_entry_size_bytes
+    compilation_cache.reset_cache()
+    enable_persistent_cache(directory=str(tmp_path), min_compile_secs=0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    try:
+        def cached_fn(x):
+            return jnp.sinh(x) * 3.25 + 1.0
+        x = jnp.arange(11.0, dtype=jnp.float32)
+        jax.jit(cached_fn)(x).block_until_ready()
+        jax.clear_caches()
+        obs.clear_spans()
+        with obs.span("again"):
+            jax.jit(cached_fn)(x).block_until_ready()
+        ring = obs.spans()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev_dir)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          prev_min)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes",
+                          prev_sz)
+        compilation_cache.reset_cache()
+    back = [s for s in ring if s["name"] == "compile/backend"
+            and "cached_fn" in s["attrs"]["fun"]]
+    reads = [s for s in ring if s["name"] == "compile/cache_read"]
+    assert len(back) == 1 and back[0]["attrs"]["cached"] is True
+    assert [r["attrs"]["fun"] for r in reads] == [back[0]["attrs"]["fun"]]
+    assert back[0]["t0"] <= reads[0]["t0"] <= reads[0]["t1"] \
+        <= back[0]["t1"]
+    assert back[0]["path"] == "again/compile/backend"
+
+
+BUILDERS = {
+    # example: (directory, builder, keys that make it a toy)
+    "tgv3d": ("examples/navier_stokes/tgv3d", "build_tgv_example",
+              {"CartesianGeometry": {"n_cells": [16, 16, 16]}}),
+    "cavity3d": ("examples/navier_stokes/cavity3d", "build_cavity_example",
+                 {"CartesianGeometry": {"n_cells": [8, 8, 8]}}),
+    "falling_sphere": ("examples/ConstraintIB/falling_sphere",
+                       "build_falling_sphere_example",
+                       {"CartesianGeometry": {"n_cells": [20, 20, 32]}}),
+}
+
+
+@pytest.mark.parametrize("example", ["shell3d"] + sorted(BUILDERS))
+def test_each_builder_opens_one_build_span(example):
+    if example == "shell3d":
+        def build():
+            return build_shell_example(n_cells=8, n_lat=4, n_lon=4)
+    else:
+        where, name, keys = BUILDERS[example]
+        mod = harness.load_module(os.path.join(ROOT, where, "main.py"),
+                                  example + "_build_span")
+        text = inputfile.set_keys(
+            open(os.path.join(ROOT, where, "input3d")).read(), keys)
+
+        def build():
+            return getattr(mod, name)(parse_input_string(text))
+    obs.clear_spans()
+    integ, state = build()
+    ring = obs.spans()
+    assert [s["path"] for s in ring if s["name"] == "setup/build"] == [
+        "setup/build"]
+    # everything the builder compiled is inside it
+    assert all(s["path"].startswith("setup/build/") for s in ring
+               if s["name"] != "setup/build")
+    assert state is not None and integ is not None
+
+
+def test_timer_counts_chunks_without_a_span_of_its_own():
+    """``HierarchyDriver(timer=...)``: the report keeps one entry per
+    chunk; the ring keeps one span per chunk."""
+    from ibamr_tpu.utils.timers import TimerManager
+
+    integ, state = build_shell_example(n_cells=8, n_lat=4, n_lon=4)
+    tm = TimerManager()
+    obs.clear_spans()
+    HierarchyDriver(integ, RunConfig(dt=1e-4, num_steps=6,
+                                     health_interval=2),
+                    timer=tm, timer_name="IB::advanceHierarchy").run(state)
+    ring = obs.spans()
+    chunks = [s for s in ring if s["name"] == "driver/chunk"]
+    assert [s["path"] for s in chunks] == ["driver/chunk"] * 3
+    assert not [s for s in ring if "IB::" in s["path"]]
+    t = tm.timers["IB::advanceHierarchy"]
+    assert t.count == 3
+    walls = sum(s["t1"] - s["t0"] for s in chunks)
+    assert walls <= t.total < walls + 0.05
+    assert "IB::advanceHierarchy" in tm.report()
