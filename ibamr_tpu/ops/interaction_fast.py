@@ -391,16 +391,13 @@ def _marshalled(rows: bool) -> None:
 
 def bucketed_channel(b: Buckets, F: jnp.ndarray) -> jnp.ndarray:
     """Scatter a per-marker channel (N,) into the bucket-slot layout
-    (B, cap) of ``b`` (shared by the MXU and Pallas spread engines);
-    rows (N, C) go to (B, cap, C) through the SAME one scatter-add, an
-    index per marker. Slots are unique per marker, so a row lands as
-    its scalars would, to every bit; the dump row takes the overflowed
-    markers."""
+    (B, cap) of ``b`` (shared by the MXU and Pallas spread engines and
+    the packed engine's per-component spread); the dump row takes the
+    overflowed markers."""
     _MARKER_SCATTERS.inc()
-    _marshalled(F.ndim > 1)
-    Ff = jnp.zeros((b.wb.size + 1,) + F.shape[1:], dtype=F.dtype)
-    return Ff.at[b.slot_of_marker].add(F)[:-1].reshape(
-        b.wb.shape + F.shape[1:])
+    _marshalled(False)
+    Ff = jnp.zeros(b.wb.size + 1, dtype=F.dtype)
+    return Ff.at[b.slot_of_marker].add(F)[:-1].reshape(b.wb.shape)
 
 
 def spread_overflow_fallbacks(out: jnp.ndarray, b: Buckets,

@@ -44,6 +44,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ibamr_tpu import obs
 from ibamr_tpu.grid import StaggeredGrid
 from ibamr_tpu.ops import interaction
 from ibamr_tpu.ops.delta import Kernel, get_kernel
@@ -295,6 +296,27 @@ def refresh_packed(geom: BucketGeometry, grid: StaggeredGrid,
     return jax.lax.cond(hit, lambda: b._replace(Xb=Xb), repack), hit
 
 
+# bumped once per traced gather of per-marker rows into slot order
+# through ``marker_of_slot`` (the packed spread_vel's way in)
+_SLOT_GATHERS = obs.counter("transfer_slot_gathers_total")
+obs.describe("transfer_slot_gathers_total",
+             "traced gathers of per-marker values into slot order "
+             "through marker_of_slot (one index per slot)")
+
+
+def slot_channel(b: PackedBuckets, F: jnp.ndarray) -> jnp.ndarray:
+    """Per-marker rows F (N, C) in slot order, (Q, c, C): ONE gather
+    through ``marker_of_slot``, an index per slot, an empty slot reading
+    0. It equals ``bucketed_channel``'s scatter-add onto zeros to every
+    bit but the sign of a zero (slots are unique per marker, and an
+    overflowed marker has no slot to be read from), and its result fuses
+    into the ops that read it, as a scatter's does not."""
+    _SLOT_GATHERS.inc()
+    obs.annotate("driver/chunk", transfer_marshal="rows")
+    return jnp.take(F, b.marker_of_slot, axis=0, mode="fill",
+                    fill_value=0).reshape(b.wb.shape + F.shape[1:])
+
+
 def _spread_slots(geom: BucketGeometry, grid: StaggeredGrid,
                   b: PackedBuckets, Ff: jnp.ndarray, centering,
                   kernel: Kernel, precision, compute_dtype) -> jnp.ndarray:
@@ -353,10 +375,10 @@ def _spread_vel_raw(geom: BucketGeometry, grid: StaggeredGrid,
                     b: PackedBuckets, F: jnp.ndarray,
                     X: jnp.ndarray) -> Vel:
     """All components of a velocity-like spread, F (N, dim): the
-    markers' values go to slot order as ROWS, by one scatter-add; each
-    component then spreads from its own slice as ``_spread_raw`` would
-    from its own scatter-add, to every bit."""
-    Ff = bucketed_channel(b, F)                       # (Q, c, dim)
+    markers' values go to slot order as ROWS, by one gather through
+    ``marker_of_slot``; each component then spreads from its own slice
+    as ``_spread_raw`` would from its own scatter-add, to every bit."""
+    Ff = slot_channel(b, F)                           # (Q, c, dim)
     return tuple(
         spread_overflow_fallbacks(
             _spread_slots(geom, grid, b, Ff[..., d], d, kernel,
@@ -511,7 +533,7 @@ _interp_vjp.defvjp(_interp_fwd, _interp_bwd)
 # The cotangent passes are the batched transfers themselves, so they
 # marshal once as the primal does: d(spread_vel) wrt F one row gather
 # (still scatter-free), d(interp_vel) wrt u the primal spread_vel's one
-# row scatter-add; no bucket prep in either.
+# row gather through ``marker_of_slot``; no bucket prep in either.
 
 def _position_cotangent_vel(grid, fields, X, kernel, scale):
     """The components' position cotangents, summed: ``scale`` (N, dim)
@@ -660,7 +682,8 @@ class PackedInteraction:
                    weights: Optional[jnp.ndarray] = None,
                    b: Optional[PackedBuckets] = None) -> Vel:
         """``spread_packed`` of each column of F (N, dim), the columns
-        taken to slot order together, as rows (one scatter-add)."""
+        taken to slot order together, as rows (one gather through
+        ``marker_of_slot``)."""
         if b is None:
             b = self.buckets(X, weights)
         transfer = _spread_vel_vjp if GRAD_TRANSFERS else _spread_vel_raw

@@ -414,18 +414,22 @@ def test_chunk_span_says_what_was_constrained_and_how(traced_chunk):
 
 def test_chunk_says_how_the_marker_values_crossed(traced_chunk):
     """One ``interpolate_vel`` and two ``spread_vel`` a step, each moving
-    its rows between marker order and slot order once (PR 35)."""
+    its rows between marker order and slot order once: the interpolation
+    by a gather over ``slot_of_marker``, each spread by a gather through
+    ``marker_of_slot``."""
     before, after, span, _, _ = traced_chunk
     assert span["attrs"]["transfer_marshal"] == "rows"
     for key, count in (("transfer_marker_gathers_total", 1),
-                       ("transfer_marker_scatters_total", 2)):
-        assert after[key] - before.get(key, 0) == count
+                       ("transfer_marker_scatters_total", 0),
+                       ("transfer_slot_gathers_total", 2)):
+        assert after.get(key, 0) - before.get(key, 0) == count
 
 
 def test_step_marshals_once_per_transfer():
-    """The compiled step holds one gather and two scatters with an index
-    per marker under the transfers' scopes (three and six when every
-    component crossed alone)."""
+    """The compiled step holds one gather with an index per marker under
+    the interpolation's scope (three when every component crossed
+    alone), and two gathers with an index per slot and no scatter with
+    either under the spreads' scope."""
     from ibamr_tpu.analysis.graph_census import indexed_op_counts
 
     method, state = box_method("packed")
@@ -433,10 +437,14 @@ def test_step_marshals_once_per_transfer():
     text = jax.jit(lambda s, c: method.step_carried(s, c, 2e-3)).lower(
         state, ctx).compile().as_text()
     n = state.X.shape[0]
+    slots = ctx.marker_of_slot.shape[0]
+    assert slots != n
     assert indexed_op_counts(text, n, "ib/interp") == \
         {"gather": 1, "scatter": 0}
     assert indexed_op_counts(text, n, "ib/spread") == \
-        {"gather": 0, "scatter": 2}
+        {"gather": 0, "scatter": 0}
+    assert indexed_op_counts(text, slots, "ib/spread") == \
+        {"gather": 2, "scatter": 0}
 
 
 @pytest.mark.parametrize("phase", [

@@ -59,20 +59,21 @@ def test_step_jaxpr_scatter_census():
     # jaxpr-level on purpose: the XLA:CPU scatter expander rewrites
     # scatters before the optimized HLO, so an HLO-text pin on this
     # backend says nothing about the chip (the old HLO-text "zero
-    # scatter" pin was vacuous). The packed step really carries 18
+    # scatter" pin was vacuous). The packed step really carries 17
     # scatter primitives (25 until the refresh stopped rebuilding its
-    # slot->marker inverse; 24 until PR 35 moved the marker values of
-    # a velocity transfer as rows: one scatter-add per spread_vel and
-    # one compact-overflow merge per interpolate_vel, not one per
-    # component): the bucket build and its slot bookkeeping
-    # (interaction_fast/interaction_packed), the overlap-add of packed
-    # tiles, and the overflow fallback through the scatter reference
-    # (ops/interaction.py). A change in this count is a change in what
-    # the chip's serial scatter penalty is charged on.
+    # slot->marker inverse; 24 until the marker values of a velocity
+    # transfer moved as rows: one scatter-add per spread_vel and one
+    # compact-overflow merge per interpolate_vel, not one per
+    # component; 18 until spread_vel took its rows to slot order by a
+    # gather through marker_of_slot): the bucket build and its slot
+    # bookkeeping (interaction_fast/interaction_packed), the
+    # overlap-add of packed tiles, and the overflow fallback through the
+    # scatter reference (ops/interaction.py). A change in this count is
+    # a change in what the chip's serial scatter penalty is charged on.
     integ, st = _build(n=16)
     jaxpr = jax.make_jaxpr(lambda s: integ.step(s, 1e-3))(st)
     census = scatter_gather_census(jaxpr.jaxpr)
-    assert census["scatter_prims"] == 18, census
+    assert census["scatter_prims"] == 17, census
 
 
 def test_bf16_step_same_fft_budget():
