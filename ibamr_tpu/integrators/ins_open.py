@@ -65,6 +65,9 @@ class INSOpenIntegrator:
                 f"unknown convective_op_type {convective_op_type!r}")
         self.convective_op_type = convective_op_type
         self.stab_band = int(stab_band)
+        # ``tol`` is relative to the right-hand side and has to be
+        # reachable in ``dtype``: an f32 solve asked for the default 1e-8
+        # runs every restart
         self.solver = StaggeredStokesSolver(
             n, dx, bc, alpha=self.alpha, mu=self.mu, tol=tol,
             dtype=dtype)
@@ -276,6 +279,20 @@ class INSOpenIntegrator:
         dynamically, so the CFL-adaptive ``hierarchy_driver`` loop
         drives this integrator without recompilation (VERDICT round 4
         item 6 — dt is no longer baked into the factorization)."""
+        return self.step_with_stats(state, dt, f)[0]
+
+    def step_with_stats(self, state: OpenINSState, dt=None,
+                        f: Optional[Vel] = None):
+        """:meth:`step` and the saddle solve's int32 counts:
+        ``restarts`` (FGMRES restart cycles), ``iters`` (Arnoldi
+        iterations: ``m`` a restart) and ``unconverged`` (1 where the
+        solve ended above its tolerance). The phases (``jax.named_scope``)
+        are ``fluid``, and under it ``convect`` and the solve's
+        ``krylov``."""
+        with jax.named_scope("fluid"):
+            return self._step(state, dt, f)
+
+    def _step(self, state, dt, f):
         s = self.solver
         if dt is None:
             dt, alpha = self.dt, None
@@ -283,10 +300,11 @@ class INSOpenIntegrator:
         else:
             alpha = self.rho / dt
             a_expl = alpha
-        if self.convective_op_type == "stabilized_ppm":
-            N = self._advect_stabilized(state.u)
-        else:
-            N = self._advect(state.u)
+        with jax.named_scope("convect"):
+            if self.convective_op_type == "stabilized_ppm":
+                N = self._advect_stabilized(state.u)
+            else:
+                N = self._advect(state.u)
         f_u = []
         for d in range(len(s.n)):
             r = a_expl * state.u[d] - self.rho * N[d]
@@ -295,7 +313,11 @@ class INSOpenIntegrator:
             f_u.append(r)
         rhs = s.make_rhs(f_u=tuple(f_u), bdry=self.bdry)
         sol = s.solve(rhs, x0=(state.u, state.p), alpha=alpha)
-        return OpenINSState(u=sol.u, p=sol.p, t=state.t + dt)
+        restarts = jnp.asarray(sol.iters, jnp.int32)
+        stats = {"restarts": restarts, "iters": restarts * s.m,
+                 "unconverged": jnp.logical_not(sol.converged).astype(
+                     jnp.int32)}
+        return OpenINSState(u=sol.u, p=sol.p, t=state.t + dt), stats
 
     def cfl_dt(self, state: OpenINSState, cfl: float = 0.5) -> float:
         """Largest stable dt by the advective CFL condition (host-side

@@ -77,7 +77,9 @@ PHASES = (("ib/prep",), ("ib/interp",), ("ib/refresh",),
           ("ib/refresh", "repack"), ("ib/force",), ("ib/spread",),
           ("fluid",), ("fluid", "transforms"), ("fluid", "convect"),
           ("fluid", "rhs"), ("fluid", "reproject"),
-          ("constraint/rigid",), ("constraint/impose",))
+          ("constraint/rigid",), ("constraint/impose",),
+          ("fluid", "krylov"), ("fluid", "krylov", "operator"),
+          ("fluid", "krylov", "precond"), ("fluid", "krylov", "arnoldi"))
 # host annotations that are the program's own spans (obs.span paths)
 PROGRAM_SPAN_RE = re.compile(r"(^|/)(driver|checkpoint|setup|compile)/")
 _DEVICE_PLANE_RE = re.compile(r"^/device:(TPU|GPU):\d+$")

@@ -204,14 +204,36 @@ def _ravel(pytree):
     return flat, unravel
 
 
+# the Arnoldi products' stated precision: a float32 product with none is
+# ONE bfloat16 pass on the TPU's matrix unit, which rounds the basis and
+# its coefficients to an 8-bit mantissa where the solve promises float32;
+# on the CPU the statement changes nothing
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
 def _fgmres_flat(Aop, b, x0, Mop, m, tol, atol, restarts):
     """Flexible right-preconditioned GMRES(m) on flat vectors.
 
     TPU-first formulation: the Krylov basis is one (m+1, n) matrix, so
     orthogonalization is two matmuls per Arnoldi step (all candidate
     dots at once + rank-1 basis combination) instead of a data-dependent
-    inner loop — MXU-friendly and fully lax-traceable.
+    inner loop — MXU-friendly and fully lax-traceable. Every restart
+    runs all ``m`` Arnoldi iterations, so a solve makes ``m`` times its
+    restart count of them.
+
+    Phase names (``jax.named_scope``, metadata only): the whole solve is
+    ``krylov``; inside it ``operator`` (the applications of ``Aop``),
+    ``precond`` (of ``Mop``) and ``arnoldi`` (CGS2, the norms and the
+    basis writes); the small least-squares solve and the update of ``x``
+    sit under ``krylov`` alone.
     """
+    with jax.named_scope("krylov"):
+        return _fgmres_scoped(Aop, b, x0, Mop, m, tol, atol, restarts)
+
+
+def _fgmres_scoped(Aop, b, x0, Mop, m, tol, atol, restarts):
+    """:func:`_fgmres_flat` under its ``krylov`` scope."""
+    scope = jax.named_scope
     n = b.shape[0]
     dtype = b.dtype
     bnorm = _gnorm(b)
@@ -219,31 +241,36 @@ def _fgmres_flat(Aop, b, x0, Mop, m, tol, atol, restarts):
 
     def restart_body(carry):
         x, _, it = carry
-        r = b - Aop(x)
-        beta = _gnorm(r)
-        beta_safe = jnp.where(beta == 0, 1.0, beta)
-        V0 = jnp.zeros((m + 1, n), dtype=dtype).at[0].set(r / beta_safe)
+        with scope("operator"):
+            r = b - Aop(x)
+        with scope("arnoldi"):
+            beta = _gnorm(r)
+            beta_safe = jnp.where(beta == 0, 1.0, beta)
+            V0 = jnp.zeros((m + 1, n), dtype=dtype).at[0].set(r / beta_safe)
         Z0 = jnp.zeros((m, n), dtype=dtype)
         H0 = jnp.zeros((m + 1, m), dtype=dtype)
 
         def arnoldi(j, st):
             V, Z, H = st
             v = V[j]
-            z = Mop(v)
-            w = Aop(z)
+            with scope("precond"):
+                z = Mop(v)
+            with scope("operator"):
+                w = Aop(z)
             # classical Gram-Schmidt with reorthogonalization (CGS2):
             # two batched-dot + rank-k-update rounds keep the basis
             # orthogonal to working precision (important in f32) while
             # staying all-matmul for the MXU
-            mask = (jnp.arange(m + 1) <= j).astype(dtype)
-            dots = (V @ w) * mask
-            w = w - V.T @ dots
-            dots2 = (V @ w) * mask
-            w = w - V.T @ dots2
-            wnorm = _gnorm(w)
-            H = H.at[:, j].set(dots + dots2).at[j + 1, j].set(wnorm)
-            V = V.at[j + 1].set(w / jnp.where(wnorm == 0, 1.0, wnorm))
-            Z = Z.at[j].set(z)
+            with scope("arnoldi"):
+                mask = (jnp.arange(m + 1) <= j).astype(dtype)
+                dots = jnp.dot(V, w, precision=_HIGHEST) * mask
+                w = w - jnp.dot(V.T, dots, precision=_HIGHEST)
+                dots2 = jnp.dot(V, w, precision=_HIGHEST) * mask
+                w = w - jnp.dot(V.T, dots2, precision=_HIGHEST)
+                wnorm = _gnorm(w)
+                H = H.at[:, j].set(dots + dots2).at[j + 1, j].set(wnorm)
+                V = V.at[j + 1].set(w / jnp.where(wnorm == 0, 1.0, wnorm))
+                Z = Z.at[j].set(z)
             return V, Z, H
 
         V, Z, H = jax.lax.fori_loop(0, m, arnoldi, (V0, Z0, H0))
@@ -262,8 +289,11 @@ def _fgmres_flat(Aop, b, x0, Mop, m, tol, atol, restarts):
         # non-finite y entry carries no descent information — drop it
         # (keeping x unchanged along that direction is exact).
         y = jnp.where(jnp.isfinite(y), y, jnp.zeros_like(y))
-        x = x + Z.T @ y
-        rn = _gnorm(b - Aop(x))
+        x = x + jnp.dot(Z.T, y, precision=_HIGHEST)
+        with scope("operator"):
+            r = b - Aop(x)
+        with scope("arnoldi"):
+            rn = _gnorm(r)
         return x, rn, it + 1
 
     def cond(carry):
@@ -281,7 +311,9 @@ def fgmres(A: Operator, b: Pytree, x0: Optional[Pytree] = None,
            tol: float = 1e-6, atol: float = 0.0,
            restarts: int = 10) -> SolveResult:
     """Flexible GMRES(m) over pytrees (general nonsymmetric systems;
-    the preconditioner may itself be an inner iteration)."""
+    the preconditioner may itself be an inner iteration). ``iters`` of
+    the result counts restart cycles, each of ``m`` Arnoldi
+    iterations."""
     bflat, unravel = _ravel(b)
     if x0 is None:
         x0flat = jnp.zeros_like(bflat)

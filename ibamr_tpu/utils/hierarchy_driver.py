@@ -56,6 +56,26 @@ _FALLS_TOTAL = _obs.counter("transfer_repack_falls_total")
 _obs.describe("transfer_repack_falls_total",
               "Refreshes of a carried marker layout that fell back to a "
               "full re-pack (a drift bound was broken).")
+_KRYLOV_ITERS_TOTAL = _obs.counter("stokes_krylov_iterations_total")
+_obs.describe("stokes_krylov_iterations_total",
+              "Arnoldi iterations of the coupled saddle solves (FGMRES) "
+              "of the chunks run.")
+_UNCONVERGED_TOTAL = _obs.counter("stokes_unconverged_solves_total")
+_obs.describe("stokes_unconverged_solves_total",
+              "Saddle solves that ended above their tolerance.")
+# the counter each count of a carried chunk's tally lands on
+_TALLY_COUNTERS = {"refreshes": _REFRESHES_TOTAL, "falls": _FALLS_TOTAL,
+                   "iters": _KRYLOV_ITERS_TOTAL,
+                   "unconverged": _UNCONVERGED_TOTAL}
+# a carried chunk's tally: the span that reports it after the chunk's sync
+# and its int32 counts, in order. An integrator names its own with a
+# ``chunk_tally`` attribute; the default is the carried marker layout's
+REFRESH_TALLY = ("refresh", ("refreshes", "falls"))
+
+
+def chunk_tally(integ):
+    """``(span name, count keys)`` of ``integ``'s carried chunk."""
+    return getattr(integ, "chunk_tally", REFRESH_TALLY)
 
 
 class SimulationDiverged(RuntimeError):
@@ -211,16 +231,17 @@ def scan_steps(step, state, dt, n: int, carried=None):
         out, _ = jax.lax.scan(body, state, None, length=n)
         return out, None
 
+    keys = chunk_tally(carried)[1]
+
     def body(c, _):
         s, ctx, tally = c
         s, ctx, stats = carried.step_carried(s, ctx, dt)
         return (s, ctx, tally + jnp.stack(
-            [jnp.asarray(stats[k], jnp.int32)
-             for k in ("refreshes", "falls")])), None
+            [jnp.asarray(stats[k], jnp.int32) for k in keys])), None
 
     (out, _, tally), _ = jax.lax.scan(
         body, (state, carried.init_carry(state),
-               jnp.zeros((2,), jnp.int32)), None, length=n)
+               jnp.zeros((len(keys),), jnp.int32)), None, length=n)
     return out, tally
 
 
@@ -294,10 +315,15 @@ class HierarchyDriver:
     context threaded through the chunk's scan — one marker-layout pack
     per chunk, not one per step — unless the caller chose the step
     itself (``step_fn``), lanes or remat: those chunks scan ``step``.
-    The carried chunk's refresh and fall counts leave with the health
-    value in the one sync per chunk and land on
-    ``transfer_refreshes_total`` / ``transfer_repack_falls_total`` and
-    a ``driver/chunk/refresh`` span. Callbacks (all optional), in the
+    The carried chunk's counts leave with the health value in the one
+    sync per chunk and close a span under ``driver/chunk`` that carries
+    them as attributes (:func:`chunk_tally`): ``refresh`` with
+    ``refreshes`` and ``falls`` (on ``transfer_refreshes_total`` /
+    ``transfer_repack_falls_total``) for a carried marker layout,
+    ``krylov`` with ``iters``, ``restarts`` and ``unconverged`` (on
+    ``stokes_krylov_iterations_total`` /
+    ``stokes_unconverged_solves_total``) for the open-boundary IB
+    step's saddle solves. Callbacks (all optional), in the
     order they run after chunk k's sync and health check:
 
     - ``metrics_fn(state, step) -> dict`` after every chunk, always
@@ -714,12 +740,15 @@ class HierarchyDriver:
                     if self._carried:
                         # the carried chunk's tally, off the tail of what
                         # the sync brought: counters and an event span
-                        refreshes, falls = (int(v) for v in health[-2:])
-                        health = health[:-2]
-                        _REFRESHES_TOTAL.inc(refreshes)
-                        _FALLS_TOTAL.inc(falls)
-                        with _obs.span("refresh", step=step, chunk=ordinal,
-                                       refreshes=refreshes, falls=falls):
+                        name, keys = chunk_tally(self.integ)
+                        counts = dict(zip(keys, (
+                            int(v) for v in health[-len(keys):])))
+                        health = health[:-len(keys)]
+                        for key, v in counts.items():
+                            if key in _TALLY_COUNTERS:
+                                _TALLY_COUNTERS[key].inc(v)
+                        with _obs.span(name, step=step, chunk=ordinal,
+                                       **counts):
                             pass
                 self.last_chunk_wall_s = time.perf_counter() - t0
                 if self.timer is not None:

@@ -288,3 +288,38 @@ def test_constraint_ib_chunk_lowers_at_the_tank_size(one_chip, monkeypatch):
     assert len(products) == 39, len(products)
     for ln in products:
         assert "precision = [HIGHEST, HIGHEST]" in ln, ln
+
+
+def test_open_ib_chunk_lowers_at_the_duct_size(one_chip):
+    # dfg3d_2z's program: the driver's carried scan chunk of the
+    # target-point cylinder over the open-boundary coupled solve at the
+    # configuration's own 84 x 84 x 512 with its 20,511 markers, LOWERED
+    # for the TPU platform (4 s; the compile of the 2-step chunk takes
+    # 182 s and 7.6 GB of temporaries, made by hand: 20 products, all
+    # operand_precision={highest,highest}). What a CPU run cannot see: a
+    # float32 product with no stated precision is ONE bfloat16 pass on
+    # this chip, and CGS2 in bfloat16 loses the Krylov basis'
+    # orthogonality, so every product of the solve (the Arnoldi
+    # iteration's four, the update of the iterate, the small
+    # least-squares solve's) has to state the highest
+    import re
+
+    from ibamr_tpu.utils import parse_input_file
+    from ibamr_tpu.utils.hierarchy_driver import HierarchyDriver, RunConfig
+    from perfbench import harness
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    mod = harness.load_module(os.path.join(
+        root, "examples", "IB", "explicit", "dfg3d", "main.py"),
+        "dfg3d_for_the_chip")
+    integ, state = mod.build_dfg3d_example(parse_input_file(
+        os.path.join(root, "perfbench", "configs", "dfg3d_2z.input3d")))
+    assert integ.grid.n == (84, 84, 512) and state.X.shape == (20511, 3)
+    drv = HierarchyDriver(integ, RunConfig(dt=1e-3, num_steps=2,
+                                           health_interval=2))
+    assert drv._carried
+    text = drv._chunk(2).lower(_on(one_chip, state), 1e-3).as_text()
+    products = re.findall(r"stablehlo\.dot_general.*", text)
+    assert len(products) >= 5, len(products)
+    for ln in products:
+        assert "precision = [HIGHEST, HIGHEST]" in ln, ln
